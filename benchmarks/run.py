@@ -18,6 +18,7 @@ import traceback
 from benchmarks import (algo_suite, breakdown, cc_partitioner_exec,
                         kernel_roofline, partitioner_metrics, sssp_variants,
                         strong_scaling, trillion_dryrun, weak_scaling)
+from repro.caches import enable_compile_cache
 
 SUITES = [
     ("partitioner_metrics", partitioner_metrics.run),
@@ -37,6 +38,7 @@ def main():
     ap.add_argument("--scale", default="small", choices=["small", "large"])
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     failures = []
     for name, fn in SUITES:
         if args.only and name != args.only:

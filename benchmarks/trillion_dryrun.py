@@ -18,7 +18,10 @@ def run(scale: str = "small"):
         subprocess.run(
             [sys.executable, "-m", "repro.launch.dryrun_graph", "--scale",
              "trillion", "--algo", "cc", "--mesh", "multipod"],
-            check=False, env=dict(os.environ, PYTHONPATH="src"))
+            check=False,
+            # the parent has touched jax (and may hold the accelerator): the
+            # compile-only child runs on the CPU's fake devices
+            env=dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu"))
     if not os.path.exists(path):
         print("trillion dry-run artifact missing (run dryrun_graph)")
         return None

@@ -23,16 +23,15 @@ every cache-hit launch — an AOT-compiled runner re-entering the tracer is
 always a bug — and ``debug_sanitize="warn"`` downgrades it to a warning.
 
 The counter is ``jax._src.test_util.count_jit_tracing_cache_miss`` (private
-but stable across the pinned jax line). When unavailable the guard degrades
-gracefully: ``guard.traces`` is ``None`` and only session-counter checks
-run.
+but stable across the pinned jax line): a context manager that yields a
+callable returning the misses counted since entry.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 import warnings
-from typing import Iterator, Optional
+from typing import Iterator
 
 __all__ = ["RetraceError", "RetraceWarning", "RetraceGuard",
            "retrace_guard"]
@@ -46,21 +45,11 @@ class RetraceWarning(UserWarning):
     """Warning twin of :class:`RetraceError` (``action="warn"``)."""
 
 
-def _tracing_counter():
-    """The jax tracing-cache-miss counter context manager, or None."""
-    try:
-        import jax._src.test_util as jtu
-        return jtu.count_jit_tracing_cache_miss
-    except (ImportError, AttributeError):  # pragma: no cover - old jax
-        return None
-
-
 @dataclasses.dataclass
 class RetraceGuard:
     """What the guarded region did; populated when the ``with`` exits.
 
-    traces             jit tracing-cache misses observed (None when the
-                       jax counter is unavailable)
+    traces             jit tracing-cache misses observed
     expected_compiles  runner compiles the passed sessions recorded —
                        these excuse their traces
     allow              extra traces tolerated (constructor arg)
@@ -68,14 +57,14 @@ class RetraceGuard:
                        raised or warned, for ``action="warn"`` callers)
     """
 
-    traces: Optional[int] = None
+    traces: int = 0
     expected_compiles: int = 0
     allow: int = 0
     triggered: bool = False
 
     @property
     def unexpected(self) -> int:
-        if self.traces is None or self.expected_compiles > 0:
+        if self.expected_compiles > 0:
             return 0
         return max(0, self.traces - self.allow)
 
@@ -101,15 +90,10 @@ def retrace_guard(*sessions, allow: int = 0, action: str = "raise",
                          f"got {action!r}")
     guard = RetraceGuard(allow=allow)
     before = [s.stats.cache_misses for s in sessions]
-    counter = _tracing_counter()
-    if counter is None:                       # pragma: no cover - old jax
+    import jax._src.test_util as jtu
+    with jtu.count_jit_tracing_cache_miss() as traced:
         yield guard
-        guard.expected_compiles = sum(
-            s.stats.cache_misses - b for s, b in zip(sessions, before))
-        return
-    with counter() as tracked:
-        yield guard
-    guard.traces = int(tracked[0])
+    guard.traces = int(traced())
     guard.expected_compiles = sum(
         s.stats.cache_misses - b for s, b in zip(sessions, before))
     if guard.unexpected:

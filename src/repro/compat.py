@@ -1,11 +1,10 @@
-"""Version-compat shims for jax APIs that moved between releases.
+"""Thin wrappers that pin the jax mesh/shard_map calling conventions the
+engine relies on, so call sites never repeat them.
 
-The repo targets current jax (``jax.shard_map``, ``jax.sharding.AxisType``,
-``jax.sharding.get_abstract_mesh``) but must also run on 0.4.x images where
-those still live under ``jax.experimental`` / don't exist. Everything here is
-a thin alias — no behaviour differences beyond disabling the replication
-check (``check_vma``/``check_rep``), which the engine's collectives violate
-intentionally (per-shard scalars are returned unreplicated).
+The only behaviour beyond the plain jax API: ``shard_map`` disables the
+varying-manual-axes check (``check_vma``), which the engine's collectives
+violate intentionally (per-shard scalars are returned unreplicated), and
+``make_mesh`` builds every axis as ``AxisType.Auto``.
 """
 from __future__ import annotations
 
@@ -15,33 +14,16 @@ __all__ = ["make_mesh", "set_mesh", "shard_map"]
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` that tolerates jax versions without ``axis_types``
-    (explicit-sharding AxisType only exists on newer jax; Auto is the
-    default behaviour on older releases anyway)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def set_mesh(mesh):
-    """``with set_mesh(mesh):`` — ambient-mesh context on any jax version
-    (``jax.set_mesh`` on new releases; Mesh is itself the context manager on
-    0.4.x, where it sets the thread-local resource env)."""
-    fn = getattr(jax, "set_mesh", None)
-    if fn is not None:
-        return fn(mesh)
-    return mesh
+    """``with set_mesh(mesh):`` — the ambient-mesh context."""
+    return jax.set_mesh(mesh)
 
 
-if hasattr(jax, "shard_map"):
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
+def shard_map(f, *, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

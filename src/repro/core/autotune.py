@@ -33,7 +33,8 @@ streaming growth can never flip a partition's backend mid-session
 (zero-retrace contract, docs/API.md "Caching rules").
 
 Cache location: ``$DRONE_AUTOTUNE_DIR`` when set, else
-``~/.cache/drone/``, one JSON per (platform, schema version). Delete the
+``<checkout>/.cache/autotune/`` (``repro.caches``), one JSON per (device
+kind, schema version) — a v5e and a v4 calibrate separately. Delete the
 file (or bump ``SCHEMA_VERSION``) to force recalibration; a corrupt or
 stale-schema file is recalibrated, never trusted.
 """
@@ -42,6 +43,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import re
 from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
@@ -203,6 +205,7 @@ def _measured_costs(units: dict, src: np.ndarray,
     wl = ops.window_align_edges(dst, n_rows=nv,
                                 block_edges=DEFAULT_BLOCK_EDGES)
     v = jnp.asarray(vals)
+    v2 = v[:, None]                              # the layouts take [n, K]
     s, d, ew = jnp.asarray(src), jnp.asarray(dst), jnp.asarray(w)
 
     def coo_fn(v_, s_, d_, ew_):
@@ -211,8 +214,9 @@ def _measured_costs(units: dict, src: np.ndarray,
 
     coo_jit = jax.jit(coo_fn)
     return {"coo": timed(lambda: coo_jit(v, s, d, ew)),
-            "pallas_tiles": timed(lambda: tl(v)),
-            "pallas_windows": timed(lambda: wl(v[s] + ew))}
+            "pallas_tiles": timed(lambda: tl(v2)),
+            "pallas_windows": timed(lambda: wl(v2[s] + ew[:, None],
+                                               combiner="min"))}
 
 
 def _fit_unit_costs(points: Sequence[dict]) -> Dict[str, float]:
@@ -241,15 +245,19 @@ def _fit_unit_costs(points: Sequence[dict]) -> Dict[str, float]:
 
 
 def _platform() -> str:
+    """The table key: jax's ``device_kind`` of the first device (e.g.
+    ``"TPU v5 lite"``; ``"cpu"`` off-accelerator)."""
     import jax
-    return jax.default_backend()
+    return jax.devices()[0].device_kind
 
 
 def calibrate(platform: Optional[str] = None) -> CalibrationTable:
-    """Run the calibration sweep for ``platform`` (default: the current jax
-    backend). Pure host work off-TPU — safe to call at import-ish time."""
+    """Run the calibration sweep for ``platform`` (a device kind; default:
+    the current device's). Timed on the device when that is the attached
+    TPU; modeled otherwise — pure host work, safe at import-ish time."""
+    import jax
     platform = platform or _platform()
-    measured = platform == "tpu"
+    measured = jax.default_backend() == "tpu" and platform == _platform()
     points = []
     for i, nv in enumerate(GRID_NV):
         for j, density in enumerate(GRID_DENSITY):
@@ -271,14 +279,14 @@ def calibrate(platform: Optional[str] = None) -> CalibrationTable:
 # disk cache
 # --------------------------------------------------------------------------- #
 def cache_dir() -> str:
+    from repro.caches import CACHE_ROOT
     return os.environ.get("DRONE_AUTOTUNE_DIR") or os.path.join(
-        os.path.expanduser("~"), ".cache", "drone")
+        CACHE_ROOT, "autotune")
 
 
 def table_path(platform: Optional[str] = None) -> str:
-    return os.path.join(cache_dir(),
-                        f"autotune_{platform or _platform()}"
-                        f"_v{SCHEMA_VERSION}.json")
+    kind = re.sub(r"[^0-9a-z]+", "_", (platform or _platform()).lower())
+    return os.path.join(cache_dir(), f"autotune_{kind}_v{SCHEMA_VERSION}.json")
 
 
 def load_table(platform: Optional[str] = None) -> Optional[CalibrationTable]:

@@ -57,12 +57,12 @@ import dataclasses
 import os
 import time
 from functools import partial
-from typing import Any, Optional, Sequence
+from typing import Any, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.compat import shard_map
 from repro.core import sbs
@@ -76,7 +76,8 @@ from repro.kernels.segment_combine import W, segment_combine_windowed
 
 __all__ = ["EngineConfig", "EdgeCombine", "run", "run_sim", "run_shard_map",
            "make_sim_runner", "make_bsp_runner", "resolve_edge_backend",
-           "normalize_edge_backend", "resolve_partition_backends"]
+           "normalize_edge_backend", "resolve_partition_backends",
+           "ShardSpecs", "shard_specs", "shard_placement"]
 
 
 # --------------------------------------------------------------------------- #
@@ -176,21 +177,67 @@ class EngineConfig:
 
 
 # --------------------------------------------------------------------------- #
-def _device_subgraph(pg: PartitionedGraph) -> DeviceSubgraph:
-    """Stacked [P, ...] DeviceSubgraph pytree."""
+class ShardSpecs(NamedTuple):
+    """Where each shard_map runner input lives on the mesh — the runner's
+    ``in_specs`` (``make_bsp_runner``) and the placement of the resident
+    inputs (``shard_placement``) come from this one definition, so a placed
+    input never has to move at launch."""
+    graph: DeviceSubgraph     # stacked [P, ...] graph arrays
+    tiles: TileBlock          # pallas_tiles layout
+    windows: WindowBlock      # pallas_windows layout
+    part: Any                 # [P] per-partition vectors ('auto' backend ids)
+    warm: Any                 # [P, v_max, K] warm-state blocks
+
+
+def shard_specs(cfg: EngineConfig, has_vlabel: bool = False) -> ShardSpecs:
+    """PartitionSpecs of the shard_map runner's inputs: partitions over
+    ``cfg.subgraph_axes``; edge arrays and tile/window lists additionally
+    over ``cfg.edge_axes`` (each edge shard's slice is a standalone
+    per-shard list, ``EdgeLayouts._sharded_geometry``)."""
+    sub_axes = tuple(cfg.subgraph_axes)
+    e_ax = tuple(cfg.edge_axes) or None
+    edge_spec = P(sub_axes, e_ax)
+    vert_spec = P(sub_axes, None)
+    return ShardSpecs(
+        graph=DeviceSubgraph(
+            esrc=edge_spec, edst=edge_spec, ew=edge_spec, emask=edge_spec,
+            slot=vert_spec, vmask=vert_spec, vid32=vert_spec,
+            is_frontier=vert_spec, out_deg=vert_spec, in_deg=vert_spec,
+            is_master=vert_spec, vlabel=vert_spec if has_vlabel else None),
+        tiles=TileBlock(tiles=P(sub_axes, e_ax, None, None),
+                        tile_dst=P(sub_axes, e_ax),
+                        tile_src=P(sub_axes, e_ax)),
+        windows=WindowBlock(eslot=edge_spec, ldst=P(sub_axes, e_ax),
+                            bwin=P(sub_axes, e_ax)),
+        part=P(sub_axes),
+        warm=P(sub_axes, None, None))
+
+
+def shard_placement(mesh: Mesh, cfg: EngineConfig,
+                    has_vlabel: bool = False) -> ShardSpecs:
+    """``shard_specs`` as ``NamedSharding``s on ``mesh`` — what the
+    resident graph, layouts and warm blocks are ``device_put`` with."""
+    return jax.tree.map(lambda s: NamedSharding(mesh, s),
+                        shard_specs(cfg, has_vlabel),
+                        is_leaf=lambda x: isinstance(x, P))
+
+
+def _device_subgraph(pg: PartitionedGraph,
+                     placement: Optional[ShardSpecs] = None) -> DeviceSubgraph:
+    """Stacked [P, ...] DeviceSubgraph pytree: on the default device, or —
+    given a ``shard_placement`` — straight from host memory into each
+    device's share of the mesh."""
     assert pg.n_vertices < 2**31
     vid32 = pg.gvid.astype(np.int64).copy()
     vid32[~pg.vmask] = np.iinfo(np.int32).max
-    return DeviceSubgraph(
-        esrc=jnp.asarray(pg.esrc), edst=jnp.asarray(pg.edst),
-        ew=jnp.asarray(pg.ew), emask=jnp.asarray(pg.emask),
-        slot=jnp.asarray(pg.slot), vmask=jnp.asarray(pg.vmask),
-        vid32=jnp.asarray(vid32.astype(np.int32)),
-        is_frontier=jnp.asarray(pg.is_frontier),
-        out_deg=jnp.asarray(pg.out_deg), in_deg=jnp.asarray(pg.in_deg),
-        is_master=jnp.asarray(pg.is_master),
-        vlabel=None if pg.vlabel is None else jnp.asarray(pg.vlabel),
-    )
+    host = DeviceSubgraph(
+        esrc=pg.esrc, edst=pg.edst, ew=pg.ew, emask=pg.emask, slot=pg.slot,
+        vmask=pg.vmask, vid32=vid32.astype(np.int32),
+        is_frontier=pg.is_frontier, out_deg=pg.out_deg, in_deg=pg.in_deg,
+        is_master=pg.is_master, vlabel=pg.vlabel)
+    if placement is None:
+        return jax.tree.map(jnp.asarray, host)
+    return jax.device_put(host, placement.graph)
 
 
 # --------------------------------------------------------------------------- #
@@ -328,30 +375,25 @@ def _edge_messages(spec: SemiringSweep, vals, esrc, ew):
 
 def _window_product(blk: WindowBlock, vals, spec: SemiringSweep, v_max: int,
                     esrc, ew):
-    """Semiring product via segment_combine_windowed; same one-partition /
-    stacked duality (window ids offset by ``p * n_windows``)."""
+    """Semiring product via segment_combine_windowed for one partition
+    ([v_max, K] vals) or the stacked graph ([P, v_max, K]), which runs one
+    launch per partition under ``lax.map``: one compiled kernel, and each
+    launch's prefetched block -> window map stays one partition long."""
+    if vals.ndim == 3:
+        return jax.lax.map(
+            lambda a: _window_product(a[0], a[1], spec, v_max, a[2], a[3]),
+            (blk, vals, esrc, ew))
     ident = combine_identity(spec.combiner, vals.dtype)
     nw = max(-(-v_max // W), 1)
     msgs = _edge_messages(spec, vals, esrc, ew)
-    if vals.ndim == 2:
-        K = vals.shape[-1]
-        n_buf = blk.ldst.shape[-1]
-        slot = jnp.where(blk.eslot >= 0, blk.eslot, n_buf)   # pad -> dropped
-        buf = jnp.full((n_buf, K), ident, vals.dtype)
-        buf = buf.at[slot].set(msgs, mode="drop")
-        out = segment_combine_windowed(buf, blk.ldst, blk.bwin, n_windows=nw,
-                                       combiner=spec.combiner)
-        return out.reshape(nw * W, K)[:v_max]
-    P, _, K = vals.shape
+    K = vals.shape[-1]
     n_buf = blk.ldst.shape[-1]
-    offs = jnp.arange(P, dtype=jnp.int32)[:, None]
-    slot = jnp.where(blk.eslot >= 0, blk.eslot + offs * n_buf, P * n_buf)
-    buf = jnp.full((P * n_buf, K), ident, vals.dtype)
-    buf = buf.at[slot.reshape(-1)].set(msgs.reshape(-1, K), mode="drop")
-    out = segment_combine_windowed(
-        buf, blk.ldst.reshape(-1), (blk.bwin + offs * nw).reshape(-1),
-        n_windows=P * nw, combiner=spec.combiner)
-    return out.reshape(P, nw * W, K)[:, :v_max]
+    slot = jnp.where(blk.eslot >= 0, blk.eslot, n_buf)       # pad -> dropped
+    buf = jnp.full((n_buf, K), ident, vals.dtype)
+    buf = buf.at[slot].set(msgs, mode="drop")
+    out = segment_combine_windowed(buf, blk.ldst, blk.bwin, n_windows=nw,
+                                   combiner=spec.combiner)
+    return out.reshape(nw * W, K)[:v_max]
 
 
 def _make_pallas_sweep(program: VertexProgram, edge_backend: str):
@@ -380,12 +422,16 @@ def _make_pallas_sweep(program: VertexProgram, edge_backend: str):
 
 def _layout_block_from(lay: EdgeLayouts, pg: PartitionedGraph,
                        program: VertexProgram, edge_backend: str,
-                       n_shards: int = 1):
+                       n_shards: int = 1,
+                       placement: Optional[ShardSpecs] = None):
     """Device layout pytree a Pallas runner takes as an explicit input
     (never closed over: the arrays change under streaming, the compiled
     runner must not bake them in). ``n_shards > 1`` returns the
-    edge-axis-sharded variant (per-shard tile/window lists)."""
+    edge-axis-sharded variant (per-shard tile/window lists); a
+    ``placement`` puts it on the mesh."""
     spec = program.sweep_spec
+    t_pl = None if placement is None else placement.tiles
+    w_pl = None if placement is None else placement.windows
     if edge_backend == "pallas_tiles":
         if not jnp.issubdtype(jnp.dtype(program.dtype), jnp.floating):
             assert pg.n_vertices < 2**30, \
@@ -395,12 +441,12 @@ def _layout_block_from(lay: EdgeLayouts, pg: PartitionedGraph,
         if n_shards > 1:
             return lay.device_tiles_sharded(pg, spec.semiring,
                                             spec.edge_values, program.dtype,
-                                            n_shards)
+                                            n_shards, shardings=t_pl)
         return lay.device_tiles(pg, spec.semiring, spec.edge_values,
-                                program.dtype)
+                                program.dtype, shardings=t_pl)
     if n_shards > 1:
-        return lay.device_windows_sharded(pg, n_shards)
-    return lay.device_windows()
+        return lay.device_windows_sharded(pg, n_shards, shardings=w_pl)
+    return lay.device_windows(shardings=w_pl)
 
 
 def _assignment_groups(assignment) -> tuple:
@@ -417,7 +463,8 @@ def _assignment_groups(assignment) -> tuple:
 
 def _auto_layout_blocks(lay: EdgeLayouts, pg: PartitionedGraph,
                         program: VertexProgram, assignment,
-                        mixed_shard: bool = False, n_shards: int = 1):
+                        mixed_shard: bool = False, n_shards: int = 1,
+                        placement: Optional[ShardSpecs] = None):
     """Layout input of an ``'auto'`` runner.
 
     Simulator (``mixed_shard=False``): ``(tiles, windows)`` with each block
@@ -429,14 +476,17 @@ def _auto_layout_blocks(lay: EdgeLayouts, pg: PartitionedGraph,
     shard_map (``mixed_shard=True``): ``(tiles, windows, backend_ids)``
     with *full* (possibly edge-axis-sharded) blocks — every device gets
     same-shaped slices and a ``lax.switch`` on its partition's backend id
-    picks the path, so one executable serves any assignment shape."""
+    picks the path, so one executable serves any assignment shape;
+    ``placement`` puts all three on the mesh."""
     spec = program.sweep_spec
     if mixed_shard:
-        ids = jnp.asarray([_BACKEND_IDS[b] for b in assignment], jnp.int32)
+        ids = np.asarray([_BACKEND_IDS[b] for b in assignment], np.int32)
+        ids = jnp.asarray(ids) if placement is None \
+            else jax.device_put(ids, placement.part)
         return (_layout_block_from(lay, pg, program, "pallas_tiles",
-                                   n_shards),
+                                   n_shards, placement),
                 _layout_block_from(lay, pg, program, "pallas_windows",
-                                   n_shards), ids)
+                                   n_shards, placement), ids)
     t_idx = tuple(p for p, b in enumerate(assignment)
                   if b == "pallas_tiles")
     w_idx = tuple(p for p, b in enumerate(assignment)
@@ -968,15 +1018,8 @@ def make_bsp_runner(program: VertexProgram, mesh: Mesh,
     ex = sbs.ShardExchange(sub_axes)
     edge_backend = resolve_edge_backend(program, cfg)
 
-    edge_spec = P(sub_axes, edge_axes if edge_axes else None)
+    specs = shard_specs(cfg, has_vlabel)
     vert_spec = P(sub_axes, None)
-    sg_specs = DeviceSubgraph(
-        esrc=edge_spec, edst=edge_spec, ew=edge_spec, emask=edge_spec,
-        slot=vert_spec, vmask=vert_spec, vid32=vert_spec,
-        is_frontier=vert_spec, out_deg=vert_spec, in_deg=vert_spec,
-        is_master=vert_spec,
-        vlabel=vert_spec if has_vlabel else None,
-    )
 
     def _squeeze(x):
         return None if x is None else x.reshape(x.shape[1:])
@@ -986,30 +1029,23 @@ def make_bsp_runner(program: VertexProgram, mesh: Mesh,
     shard_slots = cfg.shard_slots and n_edge_shards > 1
     n_loc = -(-(n_slots + 1) // n_edge_shards) if shard_slots else n_slots + 1
 
-    # Pallas layout specs: tile/window lists shard over the edge axes like
-    # the edge arrays themselves — each edge shard's slice is a standalone
-    # per-shard tile/window list (EdgeLayouts._sharded_geometry), and the
+    # Pallas layout specs (specs.tiles / specs.windows): tile/window lists
+    # shard over the edge axes like the edge arrays themselves, and the
     # EdgeCombine epilogue inside the generated sweep merges the partial
     # aggregates across shards. With no edge axes these reduce to the
     # replicated-within-partition specs of the unsharded launch.
-    e_ax = edge_axes if edge_axes else None
-    tile_specs = TileBlock(tiles=P(sub_axes, e_ax, None, None),
-                           tile_dst=P(sub_axes, e_ax),
-                           tile_src=P(sub_axes, e_ax))
-    window_specs = WindowBlock(eslot=edge_spec, ldst=P(sub_axes, e_ax),
-                               bwin=P(sub_axes, e_ax))
     lay_specs = None
     if edge_backend == "auto":
         if partition_backends is None:
             raise ValueError("edge_backend='auto' runners need the resolved "
                              "partition_backends assignment "
                              "(resolve_partition_backends)")
-        lay_specs = (tile_specs, window_specs, P(sub_axes))
+        lay_specs = (specs.tiles, specs.windows, specs.part)
         tiles_sweep = _make_pallas_sweep(program, "pallas_tiles")
         windows_sweep = _make_pallas_sweep(program, "pallas_windows")
     elif edge_backend != "coo":
-        lay_specs = tile_specs if edge_backend == "pallas_tiles" \
-            else window_specs
+        lay_specs = specs.tiles if edge_backend == "pallas_tiles" \
+            else specs.windows
         pallas_sweep = _make_pallas_sweep(program, edge_backend)
 
     def _body(sg_block, lay_block, warm_block, params):
@@ -1118,14 +1154,13 @@ def make_bsp_runner(program: VertexProgram, mesh: Mesh,
         res = program.result(sg, params, state)
         return res[None], steps, tm, tsw[None]
 
-    out_specs = (vert_spec, P(), P(), P(sub_axes))
-    warm_spec = P(sub_axes, None, None)
+    out_specs = (vert_spec, P(), P(), specs.part)
     # positional protocol (in this order): sgs [, layout][, warm][, params]
-    in_specs = [sg_specs]
+    in_specs = [specs.graph]
     if lay_specs is not None:
         in_specs.append(lay_specs)
     if warm_start:
-        in_specs.append(warm_spec)
+        in_specs.append(specs.warm)
     if params_as_input:
         in_specs.append(jax.tree.map(lambda _: P(), params))
 
@@ -1177,7 +1212,8 @@ def run_shard_map(program: VertexProgram, pg: PartitionedGraph, mesh: Mesh,
 
     n_slots, K = pg.n_slots, program.payload
     warm = init_state is not None and program.monotone
-    sgs = _device_subgraph(pg)
+    placement = shard_placement(mesh, cfg, pg.vlabel is not None)
+    sgs = _device_subgraph(pg, placement)
     edge_backend = resolve_edge_backend(program, cfg)
     lay = assignment = None
     args = (sgs,)
@@ -1185,11 +1221,12 @@ def run_shard_map(program: VertexProgram, pg: PartitionedGraph, mesh: Mesh,
         lay = pg.ensure_edge_layouts()
         assignment = resolve_partition_backends(program, cfg, pg, lay=lay)
         args += (_auto_layout_blocks(lay, pg, program, assignment,
-                                     mixed_shard=True, n_shards=n_edge),)
+                                     mixed_shard=True, n_shards=n_edge,
+                                     placement=placement),)
     elif edge_backend != "coo":
         lay = pg.ensure_edge_layouts()
         args += (_layout_block_from(lay, pg, program, edge_backend,
-                                    n_shards=n_edge),)
+                                    n_shards=n_edge, placement=placement),)
     go = make_bsp_runner(program, mesh, cfg, n_slots, params=params,
                          has_vlabel=pg.vlabel is not None, warm_start=warm,
                          partition_backends=assignment)
@@ -1197,7 +1234,8 @@ def run_shard_map(program: VertexProgram, pg: PartitionedGraph, mesh: Mesh,
     t0 = time.perf_counter()
     with mesh:
         if warm:
-            args += (jnp.asarray(_warm_block(program, pg, init_state)),)
+            args += (jax.device_put(_warm_block(program, pg, init_state),
+                                    placement.warm),)
         res, steps, tot_msgs, sweeps_per_part = go(*args)
     res = np.asarray(res)
     sweeps_per_part = np.asarray(sweeps_per_part, dtype=np.int64)

@@ -63,6 +63,17 @@ class WindowBlock(NamedTuple):
     bwin: object       # [P, b_max] int32 window id per block (ascending)
 
 
+def _to_device(host: NamedTuple, shardings=None):
+    """A host layout tuple as device arrays: on the default device, or
+    each leaf ``device_put`` with its entry of ``shardings`` (a tuple of
+    the same type holding ``NamedSharding``s)."""
+    import jax
+    import jax.numpy as jnp
+    if shardings is None:
+        return type(host)(*[jnp.asarray(x) for x in host])
+    return type(host)(*jax.device_put(tuple(host), tuple(shardings)))
+
+
 def _edge_values(kind: str, ew: np.ndarray, dtype) -> np.ndarray:
     """The declarative edge-value map of a ``SemiringSweep``: what each edge
     contributes to the semiring product (SSSP relaxes by the weight, CC
@@ -205,8 +216,6 @@ class EdgeLayouts:
             tiles = np.full((self.n_parts, self.t_max, TM, TN), ident, dtype)
             parts = range(self.n_parts)
             self._tiles[key] = tiles
-            self._filled[key] = np.zeros(self.n_parts, np.int64)
-        filled = self._filled[key]
         for p in parts:
             tiles[p] = ident
             valid = self.edge_tile[p] >= 0
@@ -217,12 +226,39 @@ class EdgeLayouts:
                 np.add.at(tiles[p], idx, vals)
             else:
                 np.minimum.at(tiles[p], idx, vals)
-            # per-partition count, so an incremental rebuild never scans the
-            # untouched partitions' tile bytes just to refresh the density
-            filled[p] = int((tiles[p] != ident).sum())
+        return tiles
+
+    def _count_filled(self, pg, key, parts: Optional[Iterable[int]] = None):
+        """Per-partition non-identity tile entries of the ``key``
+        realization, counted from the edges themselves: a sparse graph's
+        dense tiles (64 KiB each, about one per edge) need not fit in host
+        memory for its density to be known. Per partition, so an incremental
+        rebuild recounts only the partitions it touched."""
+        semiring, kind, dtype_str = key
+        dtype = np.dtype(dtype_str)
+        ident = tile_pad_identity(semiring, dtype)
+        reduce = np.add.reduceat if semiring == "plus_times" \
+            else np.minimum.reduceat
+        filled = self._filled.get(key)
+        if filled is None or parts is None:
+            filled = np.zeros(self.n_parts, np.int64)
+            parts = range(self.n_parts)
+            self._filled[key] = filled
+        for p in parts:
+            valid = self.edge_tile[p] >= 0
+            cell = ((self.edge_tile[p][valid].astype(np.int64) * TM
+                     + self.edge_r[p][valid]) * TN + self.edge_c[p][valid])
+            order = np.argsort(cell, kind="stable")
+            cell = cell[order]
+            vals = _edge_values(kind, pg.ew[p][valid], dtype)[order]
+            first = np.flatnonzero(np.r_[True, cell[1:] != cell[:-1]]) \
+                if cell.size else np.zeros(0, np.int64)
+            # one value per tile entry, combined as the dense realization
+            # combines it (the identity pad never wins a min, adds 0 to a sum)
+            entry = reduce(vals, first) if first.size else vals
+            filled[p] = int((entry != ident).sum())
         self._density[key] = int(filled.sum()) / max(
             int(self.n_tiles.sum()) * TM * TN, 1)
-        return tiles
 
     def tile_values(self, pg, semiring: str, kind: str, dtype) -> np.ndarray:
         key = (semiring, kind, np.dtype(dtype).str)
@@ -236,7 +272,7 @@ class EdgeLayouts:
         means ``pallas_windows`` (or COO) is the better backend."""
         key = (semiring, kind, np.dtype(dtype).str)
         if key not in self._density:
-            self._realize_tiles(pg, key)
+            self._count_filled(pg, key)
         return self._density[key]
 
     def partition_density(self, pg, semiring: str, kind: str,
@@ -246,33 +282,34 @@ class EdgeLayouts:
         policy, surfaced per partition in ``ExecutionStats``."""
         key = (semiring, kind, np.dtype(dtype).str)
         if key not in self._filled:
-            self._realize_tiles(pg, key)
+            self._count_filled(pg, key)
         denom = np.maximum(self.n_tiles * (TM * TN), 1).astype(np.float64)
         return self._filled[key].astype(np.float64) / denom
 
     # ------------------------------------------------------------------ #
-    # device pytrees (cached; invalidated by any rebuild)
+    # device pytrees (cached per placement; invalidated by any rebuild).
+    # ``shardings`` (a TileBlock / WindowBlock of NamedShardings) places
+    # each array on a mesh; None leaves it on the default device.
     # ------------------------------------------------------------------ #
-    def device_tiles(self, pg, semiring: str, kind: str, dtype) -> TileBlock:
-        import jax.numpy as jnp
-        key = ("tiles", semiring, kind, np.dtype(dtype).str)
+    def device_tiles(self, pg, semiring: str, kind: str, dtype, *,
+                     shardings: Optional[TileBlock] = None) -> TileBlock:
+        key = ("tiles", semiring, kind, np.dtype(dtype).str, shardings)
         blk = self._device.get(key)
         if blk is None:
             vals = self.tile_values(pg, semiring, kind, dtype)
-            blk = TileBlock(tiles=jnp.asarray(vals),
-                            tile_dst=jnp.asarray(self.tile_dst),
-                            tile_src=jnp.asarray(self.tile_src))
+            blk = _to_device(TileBlock(vals, self.tile_dst, self.tile_src),
+                             shardings)
             self._device[key] = blk
         return blk
 
-    def device_windows(self) -> WindowBlock:
-        import jax.numpy as jnp
-        blk = self._device.get(("windows",))
+    def device_windows(self, *, shardings: Optional[WindowBlock] = None
+                       ) -> WindowBlock:
+        key = ("windows", shardings)
+        blk = self._device.get(key)
         if blk is None:
-            blk = WindowBlock(eslot=jnp.asarray(self.eslot),
-                              ldst=jnp.asarray(self.ldst),
-                              bwin=jnp.asarray(self.bwin))
-            self._device[("windows",)] = blk
+            blk = _to_device(WindowBlock(self.eslot, self.ldst, self.bwin),
+                             shardings)
+            self._device[key] = blk
         return blk
 
     # ------------------------------------------------------------------ #
@@ -359,10 +396,12 @@ class EdgeLayouts:
         return geom
 
     def device_tiles_sharded(self, pg, semiring: str, kind: str, dtype,
-                             n_shards: int) -> TileBlock:
-        import jax.numpy as jnp
+                             n_shards: int, *,
+                             shardings: Optional[TileBlock] = None
+                             ) -> TileBlock:
         S = int(n_shards)
-        key = ("tiles_sharded", S, semiring, kind, np.dtype(dtype).str)
+        key = ("tiles_sharded", S, semiring, kind, np.dtype(dtype).str,
+               shardings)
         blk = self._device.get(key)
         if blk is None:
             g = self._sharded_geometry(pg, S)
@@ -379,22 +418,21 @@ class EdgeLayouts:
                     np.add.at(tiles[p], idx, vals)
                 else:
                     np.minimum.at(tiles[p], idx, vals)
-            blk = TileBlock(tiles=jnp.asarray(tiles),
-                            tile_dst=jnp.asarray(g["tile_dst"]),
-                            tile_src=jnp.asarray(g["tile_src"]))
+            blk = _to_device(TileBlock(tiles, g["tile_dst"], g["tile_src"]),
+                             shardings)
             self._device[key] = blk
         return blk
 
-    def device_windows_sharded(self, pg, n_shards: int) -> WindowBlock:
-        import jax.numpy as jnp
+    def device_windows_sharded(self, pg, n_shards: int, *,
+                               shardings: Optional[WindowBlock] = None
+                               ) -> WindowBlock:
         S = int(n_shards)
-        key = ("windows_sharded", S)
+        key = ("windows_sharded", S, shardings)
         blk = self._device.get(key)
         if blk is None:
             g = self._sharded_geometry(pg, S)
-            blk = WindowBlock(eslot=jnp.asarray(g["eslot"]),
-                              ldst=jnp.asarray(g["ldst"]),
-                              bwin=jnp.asarray(g["bwin"]))
+            blk = _to_device(WindowBlock(g["eslot"], g["ldst"], g["bwin"]),
+                             shardings)
             self._device[key] = blk
         return blk
 
@@ -518,6 +556,8 @@ class EdgeLayouts:
             self._build_partition(pg, p)
         for key in self._tiles:
             self._realize_tiles(pg, key, parts)
+        for key in self._filled:
+            self._count_filled(pg, key, parts)
         self._device.clear()
         self._shard_geom.clear()    # caps persist (grow-only) in _shard_caps
 

@@ -1,6 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = os.environ.get("DRYRUN_XLA_FLAGS",
                                          "--xla_force_host_platform_device_count=512")
+# compile-only on fake host devices: never take an accelerator, which a
+# parent process may hold
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede any jax import: jax locks the device count on first init.
 
 import argparse      # noqa: E402

@@ -1,6 +1,7 @@
 import os
 os.environ["XLA_FLAGS"] = os.environ.get("DRYRUN_XLA_FLAGS",
                                          "--xla_force_host_platform_device_count=512")
+os.environ["JAX_PLATFORMS"] = "cpu"   # compile-only: never take an accelerator
 # ^ MUST precede any jax import (see dryrun.py).
 
 import argparse      # noqa: E402

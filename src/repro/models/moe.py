@@ -54,9 +54,8 @@ def moe_specs(cfg):
 def _dp_groups(total_tokens: int) -> int:
     """Number of DP shards in the ambient mesh that divide the token count
     (hierarchical dispatch group count; 1 when unsharded/CPU)."""
-    from repro.sharding.rules import get_abstract_mesh
-    am = get_abstract_mesh()
-    if am is None or getattr(am, "empty", True):
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty:
         return 1
     g = 1
     for ax in ("pod", "data"):

@@ -98,7 +98,8 @@ from repro.core.engine import (EngineConfig, _auto_layout_blocks,
                                _layout_block_from, _warm_block,
                                make_bsp_runner, make_sim_runner,
                                normalize_edge_backend,
-                               resolve_partition_backends, run_sim)
+                               resolve_partition_backends, run_sim,
+                               shard_placement)
 from repro.core.api import VertexProgram
 from repro.core.graph import Graph
 from repro.core.metrics import ExecutionStats
@@ -511,12 +512,24 @@ class GraphSession:
         if self._closed:
             raise RuntimeError("GraphSession is closed")
 
+    @property
+    def _placement(self):
+        """With a mesh, where every resident input (graph, layouts, warm
+        blocks) goes: the runner's own in_specs, so a launch moves
+        nothing."""
+        if self.mesh is None:
+            return None
+        return shard_placement(self.mesh, self.cfg,
+                               self.pg.vlabel is not None)
+
     def device_graph(self):
         """The resident stacked [P, ...] DeviceSubgraph pytree, re-uploaded
-        only when the host graph changed since the last upload."""
+        only when the host graph changed since the last upload. With a mesh
+        each device holds its own partitions' share."""
         self._check_open()
         if self._device is None or self._device_version != self._host_version:
-            self._device = _device_subgraph(self.pg)
+            self._device = None        # free the old copy before uploading
+            self._device = _device_subgraph(self.pg, self._placement)
             self._device_version = self._host_version
             self.stats.uploads += 1
         return self._device
@@ -843,9 +856,11 @@ class GraphSession:
             asg = self._resolve_assignment(program, cfg)
             if cfg.backend == "shard_map":
                 return _auto_layout_blocks(lay, self.pg, program, asg,
-                                           mixed_shard=True, n_shards=ns)
+                                           mixed_shard=True, n_shards=ns,
+                                           placement=self._placement)
             return _auto_layout_blocks(lay, self.pg, program, asg)
-        return _layout_block_from(lay, self.pg, program, eb, n_shards=ns)
+        return _layout_block_from(lay, self.pg, program, eb, n_shards=ns,
+                                  placement=self._placement)
 
     def _layout_key(self, program, eb, cfg):
         if eb == "coo":
@@ -910,15 +925,24 @@ class GraphSession:
                     float(program.identity))
             blk = self._identity_blocks.get(ikey)
             if blk is None:
-                blk = jnp.full((pg.n_parts, pg.v_max, K), program.identity,
-                               dtype=program.dtype)
+                blk = self._upload_warm(np.full(
+                    (pg.n_parts, pg.v_max, K), program.identity,
+                    dtype=program.dtype))
                 self._identity_blocks[ikey] = blk
             return blk
         self._sync_warm_entry(entry)
         blk = entry.device_block
         if blk is not None and blk.shape == (pg.n_parts, pg.v_max, K):
+            return self._upload_warm(blk)
+        return self._upload_warm(
+            _warm_block(program, pg, entry.global_values))
+
+    def _upload_warm(self, blk: np.ndarray):
+        """A host [P, v_max, K] block on the device (each device's own
+        partitions' rows under a mesh)."""
+        if self._placement is None:
             return jnp.asarray(blk)
-        return jnp.asarray(_warm_block(program, pg, entry.global_values))
+        return jax.device_put(blk, self._placement.warm)
 
     def _launch(self, compiled, args, compile_time):
         """Execute an AOT runner. With ``debug_sanitize`` armed, a *cache

@@ -15,33 +15,8 @@ partial-KV attention; XLA inserts the softmax partial reductions).
 """
 from __future__ import annotations
 
-import logging
-
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-log = logging.getLogger(__name__)
-
-
-def get_abstract_mesh():
-    """Ambient-mesh lookup that works on both new and old jax.
-
-    Newer jax exposes ``jax.sharding.get_abstract_mesh()``; on older releases
-    (0.4.x) the equivalent ambient state set by ``with mesh:`` lives in the
-    thread-local resource env. Returns an object with ``empty``/``axis_names``
-    /``shape`` or None when no mesh is active.
-    """
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    try:
-        from jax._src import mesh as _mesh_lib
-        return _mesh_lib.thread_resources.env.physical_mesh
-    except (ImportError, AttributeError) as e:
-        # private-module layout moved on this jax version: behave as if no
-        # ambient mesh is active, but leave a trace for debugging
-        log.debug("ambient mesh lookup unavailable: %r", e)
-        return None
 
 LOGICAL_RULES = {
     "vocab": "model",
@@ -142,8 +117,8 @@ def constrain_gathered(params_tree, logical_tree):
     """with_sharding_constraint that keeps tensor-parallel axes but drops the
     FSDP ('embed') mapping — materializes the per-layer weight all-gather
     (the FSDP dataflow) instead of GSPMD's activation-partial all-reduces."""
-    am = get_abstract_mesh()
-    if am is None or getattr(am, "empty", True):
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty:
         return params_tree
     rules = dict(MULTIPOD_RULES if "pod" in am.axis_names else LOGICAL_RULES)
     rules["embed"] = None
@@ -173,8 +148,8 @@ def maybe_constrain(x, *mesh_axes):
 
     mesh_axes: one mesh-axis name (or tuple of names, or None) per dim.
     """
-    am = get_abstract_mesh()
-    if am is None or getattr(am, "empty", True):
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty:
         return x
     spec = []
     for dim, ax in zip(x.shape, mesh_axes):

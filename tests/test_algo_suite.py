@@ -14,7 +14,7 @@ import sys
 
 import numpy as np
 import pytest
-from _hypcompat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import harness
 from harness import (ALGOS, AlgoCase, case_by_name, canonicalize,
@@ -33,7 +33,7 @@ ZOO = pathological_graphs()
 # --------------------------------------------------------------------------- #
 # oracle parity: power-law draws + the pathological zoo
 # --------------------------------------------------------------------------- #
-@settings(max_examples=harness.MAX_EXAMPLES)
+@settings(max_examples=harness.MAX_EXAMPLES, deadline=None)
 @given(st.integers(0, 10_000))
 def test_oracle_powerlaw(seed):
     g = harness_powerlaw(160, seed)
@@ -71,7 +71,7 @@ def test_backend_equivalence(name):
 # --------------------------------------------------------------------------- #
 # fresh vs incremental over randomized delta schedules
 # --------------------------------------------------------------------------- #
-@settings(max_examples=1 if harness.FAST else 2)
+@settings(max_examples=1 if harness.FAST else 2, deadline=None)
 @given(st.integers(0, 10_000))
 def test_fresh_vs_incremental(seed):
     g = harness_powerlaw(160, 2)

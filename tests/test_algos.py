@@ -3,7 +3,7 @@
 import numpy as np
 import networkx as nx
 import pytest
-from _hypcompat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import EngineConfig, partition_and_build, run_sim
 from repro.algos import ConnectedComponents, PageRank, SSSP

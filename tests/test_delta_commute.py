@@ -15,7 +15,7 @@ Two layers, extending the chunk-invariance pins of test_ebv_router.py:
 """
 import numpy as np
 import pytest
-from _hypcompat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import harness
 from harness import canonicalize, harness_powerlaw
@@ -107,7 +107,7 @@ def _apply(target, ops, cuts):
 
 
 # --------------------------------------------------------------------------- #
-@settings(max_examples=3)
+@settings(max_examples=3, deadline=None)
 @given(st.integers(0, 10_000))
 def test_buffer_order_and_chunking_invariance(seed):
     rng = np.random.default_rng(seed)
@@ -138,7 +138,7 @@ def test_buffer_order_and_chunking_invariance(seed):
 
 
 # --------------------------------------------------------------------------- #
-@settings(max_examples=1 if harness.FAST else 2)
+@settings(max_examples=1 if harness.FAST else 2, deadline=None)
 @given(st.integers(0, 10_000))
 def test_incremental_queries_commute(seed):
     """Same net delta, different op interleavings and flush chunkings:

@@ -18,6 +18,7 @@ from repro.analysis.sanitizer import retrace_guard
 from repro.core import (EngineConfig, partition_and_build,
                         resolve_edge_backend, run_sim)
 from repro.core.layouts import build_edge_layouts
+from repro.core.subgraph import ShapePolicy
 from repro.graphgen import powerlaw_graph
 from repro.session import GraphSession
 
@@ -68,6 +69,26 @@ def test_sim_parity_all_algos(pg, graph, coo_sim, eb):
         assert st.backend_flops > 0
         if eb == "pallas_tiles":
             assert 0.0 < st.tile_density <= 1.0
+
+
+@pytest.mark.parametrize("semiring,kind,dtype", [
+    ("min_plus", "weight", np.float32), ("min_plus", "zero", np.int32),
+    ("plus_times", "one", np.float32)])
+def test_tile_density_counted_without_dense_tiles(pg, semiring, kind, dtype):
+    """The density stats count filled tile entries from the edges; they
+    must agree with the dense realization they no longer build."""
+    from repro.core.layouts import TM, TN
+    from repro.kernels.ref import tile_pad_identity
+    lay = build_edge_layouts(pg, ShapePolicy.exact())
+    dens = lay.partition_density(pg, semiring, kind, dtype)
+    assert not lay._tiles, "density must not realize dense tiles"
+    tiles = lay.tile_values(pg, semiring, kind, dtype)
+    filled = (tiles != tile_pad_identity(semiring, np.dtype(dtype))).sum(
+        axis=(1, 2, 3))
+    want = filled / np.maximum(lay.n_tiles * TM * TN, 1)
+    np.testing.assert_allclose(dens, want, rtol=0, atol=0)
+    assert lay.density(pg, semiring, kind, dtype) == pytest.approx(
+        filled.sum() / (lay.n_tiles.sum() * TM * TN))
 
 
 def test_cc_stays_int32_on_tiles(pg, coo_sim):

@@ -3,7 +3,7 @@ TPU is the target per DESIGN.md §5)."""
 import numpy as np
 import jax.numpy as jnp
 import pytest
-from _hypcompat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.graphgen import powerlaw_graph, random_graph
 from repro.kernels import bsp_spmv, ops, ref

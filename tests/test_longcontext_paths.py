@@ -7,7 +7,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hypcompat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_smoke_config
 from repro.models import moe as moe_lib

@@ -1,7 +1,7 @@
 """Partitioner + subgraph-builder invariants (paper §4.1 Eq. 2-3, §6)."""
 import numpy as np
 import pytest
-from _hypcompat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (PARTITIONERS, Graph, build_partitioned_graph,
                         partition_metrics)
