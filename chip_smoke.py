@@ -8,8 +8,9 @@ plain scipy / numpy reference that does not use the engine.
     JAX_PLATFORMS=cpu python chip_smoke.py --scale 10   # CPU rehearsal
 
 One chip: connected components, SSSP from two sources and BFS on the
-``coo``, ``pallas_windows`` and ``auto`` edge backends over the full graph;
-one repeated query that must hit the compiled-runner cache; one small
+``coo`` and ``pallas_windows`` edge backends over the full graph, and on
+``auto`` (only BFS when ``auto`` puts every partition on one of those
+two); one repeated query that must hit the compiled-runner cache; one small
 ``update`` + ``flush`` followed by a warm query. Then, on the largest
 graph of scale <= 16 whose dense tiles fit: PageRank on those three
 backends and every query on ``pallas_tiles``. ``--chips 4``: a session over
@@ -17,9 +18,11 @@ a 4-chip mesh (axis ``sub``, one partition per chip — the shard_map backend
 maps partitions to devices one to one), CC and SSSP on ``coo`` and
 ``pallas_windows``, and each device's share of the graph.
 
-The default scale keeps a run inside 20 minutes on a v5e: at scale 20 one
-COO query takes 14-21 s and PageRank 478 s, so scale 22 (4x the edges)
-and full-scale PageRank do not fit.
+The default scale keeps a run inside 20 minutes on a v5e. At scale 22 the
+run passed every query it reached but was stopped at 1300 s, two phases
+short: host set-up took 295 s and each query 42-105 s. Full-scale
+PageRank does not fit at scale 20 either (478 s on ``coo``). PERF.md has
+the per-phase times.
 
 Times printed are information, not metrics. The last line of stdout is
 ``{"ok": true, "device": {...}}`` only when every check passed on a TPU;
@@ -29,6 +32,7 @@ exits non-zero, and without ``--scale`` it stops before building anything.
 from __future__ import annotations
 
 import argparse
+import collections
 import json
 import os
 import sys
@@ -55,7 +59,12 @@ from repro.session import GraphSession  # noqa: E402
 DEFAULT_SCALE = 20
 SMALL_SCALE = 16
 N_PARTS = 16
+SEED = 0
 ALPHA = 0.85
+#: PageRank limit, as a share of the largest rank: good f32 runs on a v5e
+#: miss by 7.5e-5 of it, a tile product at the MXU's single bf16 pass by
+#: 1.1e-3 (PERF.md)
+PR_TOL = 2e-4
 TILE_BYTES = TM * TN * 4
 T0 = time.perf_counter()
 
@@ -147,8 +156,8 @@ def compare(kind: str, got, want, held, degree) -> tuple:
         g, w = got[held].astype(np.float64), want[held]
         err = float(np.abs(g - w).max()) if held.any() else 0.0
         scale = float(w.max()) if held.any() else 1.0
-        ok = np.allclose(g, w, rtol=1e-3, atol=1e-3 * pr_seed(len(got)))
-        return ok, f"max abs err {err:.3e} (max rank {scale:.3e})"
+        return err <= PR_TOL * scale, \
+            f"max abs err {err:.3e} (max rank {scale:.3e})"
     g, w = got[held].astype(np.float64), want[held]
     fin = np.isfinite(w)
     ok = np.array_equal(np.isfinite(g), fin)
@@ -217,9 +226,9 @@ def run_queries(sess, queries, backend: str, check: Checks, on_tpu: bool,
 # --------------------------------------------------------------------------- #
 # phases
 # --------------------------------------------------------------------------- #
-def build(scale: int, seed: int, n_parts: int, **kw):
+def build(scale: int, n_parts: int, **kw):
     t = time.perf_counter()
-    g = kronecker_graph(scale, seed=seed, weighted=True)
+    g = kronecker_graph(scale, seed=SEED, weighted=True)
     log(f"kronecker scale {scale}: {g.n_vertices} vertices, {g.n_edges} "
         f"directed edges ({time.perf_counter() - t:.1f}s)")
     t = time.perf_counter()
@@ -247,29 +256,18 @@ def tile_bytes(sess) -> tuple:
 
 
 def one_chip(args, check: Checks, on_tpu: bool) -> None:
-    g, sess = build(args.scale, args.seed, N_PARTS)
+    g, sess = build(args.scale, N_PARTS)
     ref, queries = references(g, pick_sources(g), ("cc", "sssp", "bfs"))
-    for backend in ("coo", "pallas_windows", "auto"):
-        if backend == "auto":
-            t = time.perf_counter()
-            table = autotune.get_table()
-            log(f"autotune table for {table.platform!r}: {table.source} "
-                f"({time.perf_counter() - t:.1f}s) "
-                f"unit costs {json.dumps(table.unit_costs)}")
-            check("auto calibration measured on a TPU, modeled elsewhere",
-                  table.source == ("measured" if on_tpu else "modeled"))
+    checked = ("coo", "pallas_windows")
+    for backend in checked:
         run_queries(sess, queries, backend, check, on_tpu, ref)
-        if backend == "auto":
-            asg = sess._resolve_assignment(ConnectedComponents(),
-                                           EngineConfig(edge_backend="auto"))
-            log(f"auto assignment: "
-                f"{ {b: asg.count(b) for b in sorted(set(asg))} }")
+    run_auto(sess, queries, check, on_tpu, ref, checked)
     n_tiles, nbytes = tile_bytes(sess)
     budget = device_limit() // 8
     log(f"pallas_tiles at scale {args.scale}: {n_tiles} tiles, "
         f"{nbytes / 2**30:.2f} GiB padded; budget {budget / 2**30:.2f} GiB "
         f"(1/8 of device memory)")
-    served(sess, g, args.seed, check)
+    served(sess, g, queries[-1], check)
     sess.close()
     del sess, ref, queries
 
@@ -278,7 +276,7 @@ def one_chip(args, check: Checks, on_tpu: bool) -> None:
     # block: both run on the largest smaller graph whose tiles fit
     scale = min(args.scale, SMALL_SCALE)
     while True:
-        g, sess = build(scale, args.seed, N_PARTS)
+        g, sess = build(scale, N_PARTS)
         n_tiles, nbytes = tile_bytes(sess)
         log(f"pallas_tiles at scale {scale}: {n_tiles} tiles, "
             f"{nbytes / 2**20:.1f} MiB padded")
@@ -296,13 +294,35 @@ def one_chip(args, check: Checks, on_tpu: bool) -> None:
     sess.close()
 
 
-def served(sess, g, seed: int, check: Checks) -> None:
-    """A repeated query hits the runner cache; a small insert-only update
-    is answered warm, and right."""
-    _, st = sess.query(ConnectedComponents(), cfg=EngineConfig(), warm=False)
-    check("repeat query is a runner-cache hit", st.compile_time == 0.0,
-          f"compile_time={st.compile_time}")
-    rng = np.random.default_rng(seed + 7)
+def run_auto(sess, queries, check: Checks, on_tpu: bool, ref: Reference,
+             checked) -> None:
+    """``'auto'`` under its calibration (measured on a TPU). An assignment
+    that puts every partition on one backend already ``checked`` on every
+    query computes what that backend computed: one query (BFS, the
+    cheapest) shows it runs."""
+    t = time.perf_counter()
+    table = autotune.get_table()
+    log(f"autotune table for {table.platform!r}: {table.source} "
+        f"({time.perf_counter() - t:.1f}s) "
+        f"unit costs {json.dumps(table.unit_costs)}")
+    check("auto calibration measured on a TPU, modeled elsewhere",
+          table.source == ("measured" if on_tpu else "modeled"))
+    asg = sess._resolve_assignment(queries[0][1],
+                                   EngineConfig(edge_backend="auto"))
+    log(f"auto assignment: {dict(collections.Counter(asg))}")
+    if len(set(asg)) == 1 and asg[0] in checked:
+        queries = queries[-1:]
+    run_queries(sess, queries, "auto", check, on_tpu, ref)
+
+
+def served(sess, g, repeat, check: Checks) -> None:
+    """Query ``repeat`` (run on ``coo`` before) again: a runner-cache hit.
+    Then a small insert-only update is answered warm, and right."""
+    name, prog, params, _, _ = repeat
+    _, st = sess.query(prog, params, cfg=EngineConfig(), warm=False)
+    check(f"repeat coo/{name} is a runner-cache hit", st.compile_time == 0.0,
+          f"compile_time={st.compile_time} wall={st.wall_time:.3f}s")
+    rng = np.random.default_rng(SEED + 7)
     u = rng.integers(0, g.n_vertices, 4)
     v = rng.integers(0, g.n_vertices, 4)
     u, v = u[u != v], v[u != v]
@@ -330,7 +350,7 @@ def four_chips(args, check: Checks, on_tpu: bool) -> None:
         check("four devices", False, f"found {n}")
         return
     mesh = make_mesh((4,), ("sub",))
-    g, sess = build(args.scale, args.seed, 4, mesh=mesh)
+    g, sess = build(args.scale, 4, mesh=mesh)
     sources = pick_sources(g, 1)
     ref, queries = references(g, sources, ("cc", "sssp"))
     for backend in ("coo", "pallas_windows"):
@@ -360,7 +380,6 @@ def main() -> int:
     ap.add_argument("--scale", type=int, default=None,
                     help=f"Kronecker scale (default {DEFAULT_SCALE}); off "
                          "the TPU the phases run only when it is given")
-    ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
     d0 = jax.devices()[0]

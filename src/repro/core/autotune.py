@@ -56,7 +56,7 @@ __all__ = ["CalibrationTable", "calibrate", "get_table", "load_table",
            "save_table", "table_path", "pick_backends", "BACKEND_ORDER",
            "SCHEMA_VERSION"]
 
-SCHEMA_VERSION = 1
+SCHEMA_VERSION = 2
 
 #: argmin tie-break order — fixed so replayed tables pick identically.
 BACKEND_ORDER: Tuple[str, ...] = ("coo", "pallas_windows", "pallas_tiles")
@@ -204,19 +204,41 @@ def _measured_costs(units: dict, src: np.ndarray,
                          semiring="min_plus", dtype=np.float32)
     wl = ops.window_align_edges(dst, n_rows=nv,
                                 block_edges=DEFAULT_BLOCK_EDGES)
-    v = jnp.asarray(vals)
-    v2 = v[:, None]                              # the layouts take [n, K]
-    s, d, ew = jnp.asarray(src), jnp.asarray(dst), jnp.asarray(w)
+    # every path is one jitted call over arrays uploaded here, once: the
+    # layouts' eager __call__ would time a host->device copy per launch
+    tl_dev = jax.device_put({n: getattr(tl, n)
+                             for n in ("tiles", "tile_dst", "tile_src")})
+    wl_dev = jax.device_put({n: getattr(wl, n) for n in
+                             ("order", "edge_slot", "local_dst",
+                              "block_window")})
+    v, v2, s, d, ew = jax.device_put(
+        (vals, vals[:, None], src, dst, w))      # the layouts take [n, K]
 
     def coo_fn(v_, s_, d_, ew_):
         agg = jnp.full((nv,), jnp.inf, jnp.float32)
         return agg.at[d_].min(v_[s_] + ew_)
 
-    coo_jit = jax.jit(coo_fn)
+    def tiles_fn(v_, arrays):
+        return _with_arrays(tl, arrays)(v_)
+
+    def windows_fn(v_, s_, ew_, arrays):
+        return _with_arrays(wl, arrays)(v_[s_] + ew_[:, None],
+                                        combiner="min")
+
+    coo_jit, tiles_jit, windows_jit = map(jax.jit,
+                                          (coo_fn, tiles_fn, windows_fn))
     return {"coo": timed(lambda: coo_jit(v, s, d, ew)),
-            "pallas_tiles": timed(lambda: tl(v2)),
-            "pallas_windows": timed(lambda: wl(v2[s] + ew[:, None],
-                                               combiner="min"))}
+            "pallas_tiles": timed(lambda: tiles_jit(v2, tl_dev)),
+            "pallas_windows": timed(lambda: windows_jit(v2, s, ew, wl_dev))}
+
+
+def _with_arrays(layout, arrays: dict):
+    """A shallow copy of a kernel layout holding ``arrays`` (device arrays,
+    or tracers under jit) in place of its host arrays."""
+    import copy
+    lay = copy.copy(layout)
+    vars(lay).update(arrays)
+    return lay
 
 
 def _fit_unit_costs(points: Sequence[dict]) -> Dict[str, float]:

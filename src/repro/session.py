@@ -512,25 +512,28 @@ class GraphSession:
         if self._closed:
             raise RuntimeError("GraphSession is closed")
 
-    @property
-    def _placement(self):
+    def _placement(self, cfg: Optional[EngineConfig] = None):
         """With a mesh, where every resident input (graph, layouts, warm
-        blocks) goes: the runner's own in_specs, so a launch moves
+        blocks) of a query under ``cfg`` (default: the session's) goes: the
+        in_specs of the runner that query launches, so a launch moves
         nothing."""
         if self.mesh is None:
             return None
-        return shard_placement(self.mesh, self.cfg,
+        return shard_placement(self.mesh, cfg or self.cfg,
                                self.pg.vlabel is not None)
 
-    def device_graph(self):
+    def device_graph(self, cfg: Optional[EngineConfig] = None):
         """The resident stacked [P, ...] DeviceSubgraph pytree, re-uploaded
-        only when the host graph changed since the last upload. With a mesh
-        each device holds its own partitions' share."""
+        only when the host graph changed since the last upload, or when a
+        query's ``cfg`` shards it over other mesh axes. With a mesh each
+        device holds its own partitions' share."""
         self._check_open()
-        if self._device is None or self._device_version != self._host_version:
+        placement = self._placement(cfg)
+        key = (self._host_version, placement and placement.graph)
+        if self._device is None or self._device_version != key:
             self._device = None        # free the old copy before uploading
-            self._device = _device_subgraph(self.pg, self._placement)
-            self._device_version = self._host_version
+            self._device = _device_subgraph(self.pg, placement)
+            self._device_version = key
             self.stats.uploads += 1
         return self._device
 
@@ -629,12 +632,12 @@ class GraphSession:
             self.stats.result_cache_misses += 1
 
         warm_in = bool(program.monotone)
-        args = (self.device_graph(),)
+        args = (self.device_graph(cfg),)
         if eb != "coo":
             args += (self._layout_arg(program, eb, cfg),)
         args += (params_c,)
         if warm_in:
-            args += (self._warm_arg(program, entry, use_warm),)
+            args += (self._warm_arg(program, entry, use_warm, cfg),)
         compiled, compile_time, evicted = self._get_runner(
             program, pkey, params_c, cfg, warm_in, args, eb)
         t0 = time.perf_counter()
@@ -772,12 +775,12 @@ class GraphSession:
         pad = Bp - B
         params_pad = params_cs + [params_cs[0]] * pad
         batched_params = jax.tree.map(lambda *ls: jnp.stack(ls), *params_pad)
-        args = (self.device_graph(),)
+        args = (self.device_graph(cfg),)
         if eb != "coo":
             args += (self._layout_arg(program, eb, cfg),)
         args += (batched_params,)
         if warm_in:
-            blocks = [self._warm_arg(program, entries[i], use_warms[i])
+            blocks = [self._warm_arg(program, entries[i], use_warms[i], cfg)
                       for i in range(B)]
             blocks += [blocks[0]] * pad
             args += (jnp.stack(blocks),)
@@ -857,10 +860,10 @@ class GraphSession:
             if cfg.backend == "shard_map":
                 return _auto_layout_blocks(lay, self.pg, program, asg,
                                            mixed_shard=True, n_shards=ns,
-                                           placement=self._placement)
+                                           placement=self._placement(cfg))
             return _auto_layout_blocks(lay, self.pg, program, asg)
         return _layout_block_from(lay, self.pg, program, eb, n_shards=ns,
-                                  placement=self._placement)
+                                  placement=self._placement(cfg))
 
     def _layout_key(self, program, eb, cfg):
         if eb == "coo":
@@ -912,37 +915,41 @@ class GraphSession:
         self.stats.warm_cache_bytes = sum(e.nbytes
                                           for e in self._warm.values())
 
-    def _warm_arg(self, program, entry, use_warm):
+    def _warm_arg(self, program, entry, use_warm, cfg):
         """[P, v_max, K] warm block: the cached result when warming, the
         combiner identity (a structural no-op for ``warm_init``) when cold —
         so both paths share one compiled runner."""
         pg = self.pg
         K = program.payload
+        placement = self._placement(cfg)
+        sharding = placement and placement.warm
         if not use_warm:
-            # constant per (shapes, dtype, identity): keep it resident so
-            # repeated cold queries skip the rebuild + host->device transfer
+            # constant per (shapes, dtype, identity, placement): keep it
+            # resident so repeated cold queries skip the rebuild +
+            # host->device transfer
             ikey = (pg.n_parts, pg.v_max, K, str(np.dtype(program.dtype)),
-                    float(program.identity))
+                    float(program.identity), sharding)
             blk = self._identity_blocks.get(ikey)
             if blk is None:
                 blk = self._upload_warm(np.full(
                     (pg.n_parts, pg.v_max, K), program.identity,
-                    dtype=program.dtype))
+                    dtype=program.dtype), sharding)
                 self._identity_blocks[ikey] = blk
             return blk
         self._sync_warm_entry(entry)
         blk = entry.device_block
         if blk is not None and blk.shape == (pg.n_parts, pg.v_max, K):
-            return self._upload_warm(blk)
+            return self._upload_warm(blk, sharding)
         return self._upload_warm(
-            _warm_block(program, pg, entry.global_values))
+            _warm_block(program, pg, entry.global_values), sharding)
 
-    def _upload_warm(self, blk: np.ndarray):
-        """A host [P, v_max, K] block on the device (each device's own
-        partitions' rows under a mesh)."""
-        if self._placement is None:
+    @staticmethod
+    def _upload_warm(blk: np.ndarray, sharding):
+        """A [P, v_max, K] block on the device (each device's own
+        partitions' rows under a mesh ``sharding``)."""
+        if sharding is None:
             return jnp.asarray(blk)
-        return jax.device_put(blk, self._placement.warm)
+        return jax.device_put(blk, sharding)
 
     def _launch(self, compiled, args, compile_time):
         """Execute an AOT runner. With ``debug_sanitize`` armed, a *cache

@@ -8,6 +8,7 @@ import os
 import subprocess
 import sys
 
+import jax
 import numpy as np
 import pytest
 
@@ -221,9 +222,12 @@ def test_auto_inbucket_flush_never_flips_shard_map(tmp_path):
 def test_measured_cost_sweep_runs():
     """The on-chip calibration path (timed kernels) is only recorded on a
     TPU; here it must at least run every backend at one grid point — it
-    once fed the layouts 1-D values and failed on its first chip run."""
+    once fed the layouts 1-D values and failed on its first chip run. Its
+    timed calls copy nothing from the host: every upload is explicit and
+    made once, before the clock starts."""
     src, dst = autotune._synthetic_edges(256, 0.01, 1)
-    costs = autotune._measured_costs(autotune._point_units(256, src, dst),
-                                     src, dst)
+    with jax.transfer_guard_host_to_device("disallow"):
+        costs = autotune._measured_costs(
+            autotune._point_units(256, src, dst), src, dst)
     assert set(costs) == {"coo", "pallas_tiles", "pallas_windows"}
     assert all(np.isfinite(c) and c > 0 for c in costs.values())

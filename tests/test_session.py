@@ -367,3 +367,49 @@ def test_session_shard_map_backend():
                          capture_output=True, text=True, timeout=900)
     assert res.returncode == 0, res.stderr[-4000:]
     assert "SESSION_SHARD_OK" in res.stdout
+
+
+PLACEMENT_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import numpy as np
+from repro.algos import SSSP
+from repro.compat import make_mesh
+from repro.core import EngineConfig
+from repro.core.engine import shard_placement
+from repro.graphgen import powerlaw_graph
+from repro.session import GraphSession
+
+g = powerlaw_graph(400, seed=7, weighted=True).as_undirected()
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+edge = EngineConfig(subgraph_axes=("pod", "data"), edge_axes=("model",),
+                    backend="shard_map", edge_backend="pallas_windows")
+flat = EngineConfig(subgraph_axes=("pod", "data"), backend="shard_map",
+                    edge_backend="pallas_windows")
+sess = GraphSession.from_graph(g, 4, "cdbh", mesh=mesh, cfg=edge)
+want, _ = GraphSession.from_graph(g, 4, "cdbh").query(SSSP(), {"source": 0})
+
+# every resident input of a query sits where that query's runner reads it,
+# whichever axes its cfg names; the graph moves only when the axes change
+uploads = []
+for cfg in (flat, flat, edge, flat):
+    res, st = sess.query(SSSP(), {"source": 0}, cfg=cfg, warm=False)
+    assert (np.asarray(res) == np.asarray(want)).all(), cfg
+    uploads.append(sess.stats.uploads)
+    pl = shard_placement(mesh, cfg)
+    dev = sess.device_graph(cfg)
+    assert dev.esrc.sharding == pl.graph.esrc, (dev.esrc.sharding, cfg)
+    lay = sess._layout_arg(SSSP(), "pallas_windows", cfg)
+    assert lay.eslot.sharding == pl.windows.eslot, cfg
+    warm = sess._warm_arg(SSSP(), None, False, cfg)
+    assert warm.sharding == pl.warm, cfg
+assert uploads == [1, 1, 2, 3], uploads
+print("PLACEMENT_OK")
+"""
+
+
+def test_session_mesh_placement_follows_query_cfg():
+    res = subprocess.run([sys.executable, "-c", PLACEMENT_SCRIPT],
+                         capture_output=True, text=True, timeout=900)
+    assert res.returncode == 0, res.stderr[-4000:]
+    assert "PLACEMENT_OK" in res.stdout

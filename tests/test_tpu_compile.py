@@ -112,9 +112,9 @@ def test_sim_runner_compiles(one_chip, program, params, edge_backend):
     sess = GraphSession.from_graph(g, n_parts=4, partitioner="cdbh")
     eb, cfg = normalize_edge_backend(
         program, EngineConfig(edge_backend=edge_backend))
-    host_args = (sess.device_graph(), sess._layout_arg(program, eb, cfg),
+    host_args = (sess.device_graph(cfg), sess._layout_arg(program, eb, cfg),
                  canonical_params(params),
-                 sess._warm_arg(program, None, use_warm=False))
+                 sess._warm_arg(program, None, False, cfg))
     specs = jax.tree.map(lambda x: _spec(x, one_chip), host_args)
     runner = make_sim_runner(program, cfg, sess.slot_capacity,
                              warm_start=True)
