@@ -1,0 +1,6 @@
+"""Percent of the traced window in which no operation ran on a chip."""
+from bench import readers
+
+
+def read(run):
+    return readers.idle_share(run)
