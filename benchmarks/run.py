@@ -3,7 +3,7 @@ table/figure. ``python -m benchmarks.run [--scale small|large]``.
 
   Table 3  -> partitioner_metrics     Fig 4 -> cc_partitioner_exec
   Fig 5    -> strong_scaling          Table 4/Fig 6-7 -> sssp_variants
-  Fig 8    -> breakdown               Fig 9 -> weak_scaling
+  Fig 9    -> weak_scaling
   §8.5 trillion-edge claim -> trillion_dryrun (compile-only, if artifact
   present)
 
@@ -15,7 +15,7 @@ import argparse
 import time
 import traceback
 
-from benchmarks import (algo_suite, breakdown, cc_partitioner_exec,
+from benchmarks import (algo_suite, cc_partitioner_exec,
                         kernel_roofline, partitioner_metrics, sssp_variants,
                         strong_scaling, trillion_dryrun, weak_scaling)
 from repro.caches import enable_compile_cache
@@ -25,7 +25,6 @@ SUITES = [
     ("cc_partitioner_exec", cc_partitioner_exec.run),
     ("strong_scaling", strong_scaling.run),
     ("sssp_variants", sssp_variants.run),
-    ("breakdown", breakdown.run),
     ("weak_scaling", weak_scaling.run),
     ("kernel_roofline", kernel_roofline.run),
     ("algo_suite", algo_suite.run),

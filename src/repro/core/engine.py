@@ -73,6 +73,7 @@ from repro.core.subgraph import PartitionedGraph
 from repro.kernels.bsp_spmv import TM, TN, bsp_spmv
 from repro.kernels.ref import combine_identity, tile_pad_identity
 from repro.kernels.segment_combine import W, segment_combine_windowed
+from repro.obs import scope
 
 __all__ = ["EngineConfig", "EdgeCombine", "run", "run_sim", "run_shard_map",
            "make_sim_runner", "make_bsp_runner", "resolve_edge_backend",
@@ -483,6 +484,7 @@ def _auto_layout_blocks(lay: EdgeLayouts, pg: PartitionedGraph,
         ids = np.asarray([_BACKEND_IDS[b] for b in assignment], np.int32)
         ids = jnp.asarray(ids) if placement is None \
             else jax.device_put(ids, placement.part)
+        lay.uploaded_bytes += ids.nbytes
         return (_layout_block_from(lay, pg, program, "pallas_tiles",
                                    n_shards, placement),
                 _layout_block_from(lay, pg, program, "pallas_windows",
@@ -544,11 +546,12 @@ def _local_phase(program: VertexProgram, sg: DeviceSubgraph, params, state,
     ``sweep_fn`` overrides ``program.sweep`` (Pallas edge backends).
     """
     sweep = sweep_fn if sweep_fn is not None else program.sweep
-    state = jax.lax.cond(
-        first, lambda st: st,
-        lambda st: program.apply_frontier(sg, params, st, merged_v, ec)[0],
-        state)
-    state, ch = sweep(sg, params, state, ec)
+    with scope("apply"):
+        state = jax.lax.cond(
+            first, lambda st: st,
+            lambda st: program.apply_frontier(sg, params, st, merged_v,
+                                              ec)[0],
+            state)
 
     def cond(c):
         i, _, chg = c
@@ -559,8 +562,12 @@ def _local_phase(program: VertexProgram, sg: DeviceSubgraph, params, state,
         st, chg = sweep(sg, params, st, ec)
         return (i + 1, st, chg)
 
-    i, state, last_ch = jax.lax.while_loop(cond, body, (jnp.int32(1), state, ch))
-    out = program.frontier_out(sg, params, state)
+    with scope("sweep"):
+        state, ch = sweep(sg, params, state, ec)
+        i, state, last_ch = jax.lax.while_loop(cond, body,
+                                               (jnp.int32(1), state, ch))
+    with scope("pack"):
+        out = program.frontier_out(sg, params, state)
     return state, out, i, last_ch
 
 
@@ -578,11 +585,13 @@ def _batched_local_phase(program: VertexProgram, sgs, lay_blk, params, state,
     updating (its rows are select-frozen) while the others continue —
     identical results, per-partition sweep counts, and straggler bound as
     the vmapped COO path."""
-    state = jax.lax.cond(
-        first, lambda st: st,
-        lambda st: jax.vmap(
-            lambda sg, s, m: program.apply_frontier(sg, params, s, m, ec)[0]
-        )(sgs, st, merged_v), state)
+    with scope("apply"):
+        state = jax.lax.cond(
+            first, lambda st: st,
+            lambda st: jax.vmap(
+                lambda sg, s, m: program.apply_frontier(sg, params, s, m,
+                                                        ec)[0]
+            )(sgs, st, merged_v), state)
 
     def sweep_all(st):
         vals = jax.vmap(
@@ -603,7 +612,6 @@ def _batched_local_phase(program: VertexProgram, sgs, lay_blk, params, state,
             lambda sg, s, a: program.sweep_fold(sg, params, s, a)
         )(sgs, st, agg)
 
-    state, ch = sweep_all(state)
     n_parts = sgs.vmask.shape[0]
     i0 = jnp.ones((n_parts,), jnp.int32)
 
@@ -620,9 +628,12 @@ def _batched_local_phase(program: VertexProgram, sgs, lay_blk, params, state,
                                    b, a), st, st2)
         return (jnp.where(live, i + 1, i), st, jnp.where(live, ch2, chg))
 
-    i, state, last_ch = jax.lax.while_loop(cond, body, (i0, state, ch))
-    out = jax.vmap(
-        lambda sg, s: program.frontier_out(sg, params, s))(sgs, state)
+    with scope("sweep"):
+        state, ch = sweep_all(state)
+        i, state, last_ch = jax.lax.while_loop(cond, body, (i0, state, ch))
+    with scope("pack"):
+        out = jax.vmap(
+            lambda sg, s: program.frontier_out(sg, params, s))(sgs, state)
     return state, out, i, last_ch
 
 
@@ -724,7 +735,9 @@ def _make_sim_superstep(program: VertexProgram, cfg: EngineConfig,
         else None
 
     def superstep(sgs, lay, params, state, last_out, merged_buf, first):
-        merged_v = jax.vmap(lambda sg: sbs.gather_merged(merged_buf, sg.slot))(sgs)
+        with scope("apply"):
+            merged_v = jax.vmap(
+                lambda sg: sbs.gather_merged(merged_buf, sg.slot))(sgs)
         if edge_backend == "coo":
             state, out, sweeps, last_ch = jax.vmap(
                 lambda sg, st, m: _local_phase(program, sg, params, st, m, ec,
@@ -734,13 +747,15 @@ def _make_sim_superstep(program: VertexProgram, cfg: EngineConfig,
             state, out, sweeps, last_ch = _batched_local_phase(
                 program, sgs, lay, params, state, merged_v, ec,
                 cfg.local_bound, first, edge_backend, groups)
-        bufs, changed = jax.vmap(
-            lambda sg, o, lo: _pack(program, sg, o, lo, n_slots)
-        )(sgs, out, last_out)
-        merged_buf = ex.all_combine(bufs, program.combiner)
-        merged_buf = merged_buf.at[n_slots].set(ident)
-        msgs = jnp.sum(changed, dtype=jnp.int32)
-        active = jnp.sum(last_ch > 0, dtype=jnp.int32)
+        with scope("pack"):
+            bufs, changed = jax.vmap(
+                lambda sg, o, lo: _pack(program, sg, o, lo, n_slots)
+            )(sgs, out, last_out)
+        with scope("exchange"):
+            merged_buf = ex.all_combine(bufs, program.combiner)
+            merged_buf = merged_buf.at[n_slots].set(ident)
+            msgs = jnp.sum(changed, dtype=jnp.int32)
+            active = jnp.sum(last_ch > 0, dtype=jnp.int32)
         return state, out, merged_buf, msgs, active, sweeps
 
     return superstep
@@ -797,13 +812,14 @@ def make_sim_runner(program: VertexProgram, cfg: EngineConfig, n_slots: int,
 
     def _run(sgs, lay, params, warm):
         n_parts, v_max = sgs.vmask.shape
-        v_init = jax.vmap(lambda sg: program.init(sg, params, ec))(sgs)
-        if warm_start:
-            v_init = jax.vmap(
-                lambda sg, st, w: program.warm_init(sg, params, st, w)
-            )(sgs, v_init, warm[0])
-        last0 = jnp.full((n_parts, v_max, K), ident, dtype=program.dtype)
-        merged0 = jnp.full((n_slots + 1, K), ident, dtype=program.dtype)
+        with scope("init"):
+            v_init = jax.vmap(lambda sg: program.init(sg, params, ec))(sgs)
+            if warm_start:
+                v_init = jax.vmap(
+                    lambda sg, st, w: program.warm_init(sg, params, st, w)
+                )(sgs, v_init, warm[0])
+            last0 = jnp.full((n_parts, v_max, K), ident, dtype=program.dtype)
+            merged0 = jnp.full((n_slots + 1, K), ident, dtype=program.dtype)
 
         def cond(c):
             step, msgs, active = c[0], c[-2], c[-1]
@@ -822,8 +838,9 @@ def make_sim_runner(program: VertexProgram, cfg: EngineConfig, n_slots: int,
                  jnp.int32(1))
         carry = jax.lax.while_loop(cond, body, carry)
         (steps, state, last_out, merged_buf, tot_msgs, tot_sweeps, *_) = carry
-        results = jax.vmap(
-            lambda sg, st: program.result(sg, params, st))(sgs, state)
+        with scope("result"):
+            results = jax.vmap(
+                lambda sg, st: program.result(sg, params, st))(sgs, state)
         return results, steps, tot_msgs, tot_sweeps
 
     if not batch:
@@ -1068,24 +1085,28 @@ def make_bsp_runner(program: VertexProgram, mesh: Mesh,
             lay = type(lay_block)(*[_squeeze(x) for x in lay_block])
             sweep_fn = (lambda sg_, p_, st_, ec_:
                         pallas_sweep(sg_, lay, p_, st_, ec_))
-        state = program.init(sg, params, ec)
-        if warm_block is not None:
-            state = program.warm_init(sg, params, state,
-                                      _squeeze(warm_block))
-        last0 = jnp.full((sg.v_max, K), ident, dtype=program.dtype)
-        merged_v0 = jnp.full((sg.v_max, K), ident, dtype=program.dtype)
+        with scope("init"):
+            state = program.init(sg, params, ec)
+            if warm_block is not None:
+                state = program.warm_init(sg, params, state,
+                                          _squeeze(warm_block))
+            last0 = jnp.full((sg.v_max, K), ident, dtype=program.dtype)
+            merged_v0 = jnp.full((sg.v_max, K), ident, dtype=program.dtype)
 
         def _exchange_dense(out, changed):
-            buf = sbs.scatter_combine(out, sg.slot, changed, n_slots,
-                                      program.combiner, ident)
-            if cfg.sparse_sync_capacity > 0:
-                merged = sbs.compact_allgather_exchange(
-                    buf, ident, program.combiner, n_slots,
-                    cfg.sparse_sync_capacity, sub_axes)
-            else:
-                merged = ex.all_combine(buf, program.combiner)
-            merged = merged.at[n_slots].set(ident)
-            return sbs.gather_merged(merged, sg.slot)
+            with scope("pack"):
+                buf = sbs.scatter_combine(out, sg.slot, changed, n_slots,
+                                          program.combiner, ident)
+            with scope("exchange"):
+                if cfg.sparse_sync_capacity > 0:
+                    merged = sbs.compact_allgather_exchange(
+                        buf, ident, program.combiner, n_slots,
+                        cfg.sparse_sync_capacity, sub_axes)
+                else:
+                    merged = ex.all_combine(buf, program.combiner)
+                merged = merged.at[n_slots].set(ident)
+            with scope("apply"):
+                return sbs.gather_merged(merged, sg.slot)
 
         def _exchange_sharded(out, changed):
             # Sharded SBS (DESIGN.md §7): frontier slots are owned by the
@@ -1095,33 +1116,39 @@ def make_bsp_runner(program: VertexProgram, mesh: Mesh,
             # O(n_slots / n_edge_shards) state per device, which is what
             # keeps the trillion-edge configuration within HBM.
             rank = jax.lax.axis_index(edge_axes)
-            owned = changed & (sg.slot % n_edge_shards == rank)
-            slot_loc = jnp.where(owned, sg.slot // n_edge_shards, n_loc)
-            buf = sbs.scatter_combine(out, slot_loc, owned, n_loc,
-                                      program.combiner, ident)
-            merged = ex.all_combine(buf, program.combiner)
-            gather_own = sg.frontier & (sg.slot % n_edge_shards == rank)
-            mv = jnp.where(
-                gather_own[:, None],
-                merged[jnp.clip(sg.slot // n_edge_shards, 0, n_loc)], ident)
-            if program.combiner == "min":
-                return ec.min(mv)
-            if program.combiner == "max":
-                return ec.max(mv)
-            return ec.sum(jnp.where(gather_own[:, None], mv, 0).astype(mv.dtype))
+            with scope("pack"):
+                owned = changed & (sg.slot % n_edge_shards == rank)
+                slot_loc = jnp.where(owned, sg.slot // n_edge_shards, n_loc)
+                buf = sbs.scatter_combine(out, slot_loc, owned, n_loc,
+                                          program.combiner, ident)
+            with scope("exchange"):
+                merged = ex.all_combine(buf, program.combiner)
+                gather_own = sg.frontier & (sg.slot % n_edge_shards == rank)
+                mv = jnp.where(
+                    gather_own[:, None],
+                    merged[jnp.clip(sg.slot // n_edge_shards, 0, n_loc)],
+                    ident)
+                if program.combiner == "min":
+                    return ec.min(mv)
+                if program.combiner == "max":
+                    return ec.max(mv)
+                return ec.sum(jnp.where(gather_own[:, None], mv,
+                                        0).astype(mv.dtype))
 
         def superstep(state, last_out, merged_v, first):
             state, out, sweeps, last_ch = _local_phase(
                 program, sg, params, state, merged_v, ec, cfg.local_bound,
                 first, sweep_fn=sweep_fn)
             ref = merged_v if cfg.lean_frontier else last_out
-            changed = program.changed_mask(out, ref) & sg.frontier
+            with scope("pack"):
+                changed = program.changed_mask(out, ref) & sg.frontier
             if shard_slots:
                 merged_v = _exchange_sharded(out, changed)
             else:
                 merged_v = _exchange_dense(out, changed)
-            msgs = ex.all_sum_scalar(jnp.sum(changed, dtype=jnp.int32))
-            active = ex.all_sum_scalar((last_ch > 0).astype(jnp.int32))
+            with scope("exchange"):
+                msgs = ex.all_sum_scalar(jnp.sum(changed, dtype=jnp.int32))
+                active = ex.all_sum_scalar((last_ch > 0).astype(jnp.int32))
             return state, out, merged_v, msgs, active, sweeps
 
         def cond(c):
@@ -1151,7 +1178,8 @@ def make_bsp_runner(program: VertexProgram, mesh: Mesh,
             carry = (jnp.int32(0), state, last0, merged_v0, jnp.int32(0),
                      jnp.int32(0), jnp.int32(1), jnp.int32(1))
         steps, state, *_, tm, tsw, _, _ = jax.lax.while_loop(cond, body, carry)
-        res = program.result(sg, params, state)
+        with scope("result"):
+            res = program.result(sg, params, state)
         return res[None], steps, tm, tsw[None]
 
     out_specs = (vert_spec, P(), P(), specs.part)

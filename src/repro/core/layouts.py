@@ -163,6 +163,7 @@ class EdgeLayouts:
         default_factory=dict)             # [P] non-identity entries per part
     _density: Dict[Tuple, float] = dataclasses.field(default_factory=dict)
     _device: Dict[Tuple, object] = dataclasses.field(default_factory=dict)
+    uploaded_bytes: int = 0       # bytes of every device pytree built here
     # edge-axis-sharded geometry (shard_map cfg.edge_axes on the Pallas
     # backends): host geometry per shard count, rebuilt wholesale on any
     # graph change; the per-shard caps are grow-only across rebuilds so a
@@ -291,14 +292,20 @@ class EdgeLayouts:
     # ``shardings`` (a TileBlock / WindowBlock of NamedShardings) places
     # each array on a mesh; None leaves it on the default device.
     # ------------------------------------------------------------------ #
+    def _upload(self, host: NamedTuple, shardings=None):
+        """``_to_device``, counted in ``uploaded_bytes``."""
+        blk = _to_device(host, shardings)
+        self.uploaded_bytes += sum(int(x.nbytes) for x in blk)
+        return blk
+
     def device_tiles(self, pg, semiring: str, kind: str, dtype, *,
                      shardings: Optional[TileBlock] = None) -> TileBlock:
         key = ("tiles", semiring, kind, np.dtype(dtype).str, shardings)
         blk = self._device.get(key)
         if blk is None:
             vals = self.tile_values(pg, semiring, kind, dtype)
-            blk = _to_device(TileBlock(vals, self.tile_dst, self.tile_src),
-                             shardings)
+            blk = self._upload(TileBlock(vals, self.tile_dst, self.tile_src),
+                               shardings)
             self._device[key] = blk
         return blk
 
@@ -307,8 +314,8 @@ class EdgeLayouts:
         key = ("windows", shardings)
         blk = self._device.get(key)
         if blk is None:
-            blk = _to_device(WindowBlock(self.eslot, self.ldst, self.bwin),
-                             shardings)
+            blk = self._upload(WindowBlock(self.eslot, self.ldst, self.bwin),
+                               shardings)
             self._device[key] = blk
         return blk
 
@@ -418,8 +425,8 @@ class EdgeLayouts:
                     np.add.at(tiles[p], idx, vals)
                 else:
                     np.minimum.at(tiles[p], idx, vals)
-            blk = _to_device(TileBlock(tiles, g["tile_dst"], g["tile_src"]),
-                             shardings)
+            blk = self._upload(TileBlock(tiles, g["tile_dst"],
+                                         g["tile_src"]), shardings)
             self._device[key] = blk
         return blk
 
@@ -431,8 +438,8 @@ class EdgeLayouts:
         blk = self._device.get(key)
         if blk is None:
             g = self._sharded_geometry(pg, S)
-            blk = _to_device(WindowBlock(g["eslot"], g["ldst"], g["bwin"]),
-                             shardings)
+            blk = self._upload(WindowBlock(g["eslot"], g["ldst"], g["bwin"]),
+                               shardings)
             self._device[key] = blk
         return blk
 

@@ -6,7 +6,9 @@ Partitioning metrics:
 
 Execution metrics (gathered by the engine): supersteps, network messages
 ((key,value) pairs, i.e. changed frontier slots per superstep), bytes moved,
-per-phase time breakdown, PEPS (processed edges per second, paper Fig 9).
+PEPS (processed edges per second, paper Fig 9). The per-phase time split
+(sweep, exchange, ...) comes from a profile: the engine's superstep phases
+carry named scopes (``repro.obs``, docs/SERVING.md "Tracing a session").
 """
 from __future__ import annotations
 
@@ -59,8 +61,6 @@ class ExecutionStats:
     total_bytes: int = 0               # dense SBS buffer bytes actually reduced
     messages_per_step: list = dataclasses.field(default_factory=list)
     active_parts_per_step: list = dataclasses.field(default_factory=list)
-    compute_time: float = 0.0
-    sync_time: float = 0.0
     wall_time: float = 0.0             # execution only — compile billed apart
     compile_time: float = 0.0          # trace+compile on a GraphSession
                                        # runner-cache miss; 0.0 on a hit, so

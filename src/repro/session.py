@@ -107,6 +107,7 @@ from repro.core.partition import (PARTITIONERS, STREAM_ROUTERS,
                                   is_stateful_router)
 from repro.core.subgraph import (PartitionedGraph, ShapePolicy,
                                  build_partitioned_graph)
+from repro.obs import span
 from repro.partition.monitor import LoadMonitor
 from repro.partition.rebalance import (RebalanceStats, execute_rebalance,
                                        plan_rebalance)
@@ -170,6 +171,8 @@ class SessionStats:
     flushes: int = 0               # delta batches applied to the host graph
     compactions: int = 0
     uploads: int = 0               # device pytree refreshes
+    upload_bytes: int = 0          # bytes put on the device: the graph
+                                   # pytree, layout blocks and warm blocks
     compile_time_total: float = 0.0
     cache_evictions_lru: int = 0   # runners dropped by the max_runners /
                                    # max_runner_bytes bounds
@@ -214,11 +217,14 @@ class _SessionBuffer(DeltaBuffer):
         self._session = session
         super().__init__(*args, **kwargs)
 
-    def flush(self, _auto: bool = False) -> Optional[DeltaStats]:
-        st = super().flush(_auto)
-        if st is not None:
+    def _on_applied(self, st: DeltaStats) -> None:
+        with span("session/on_flush"):
             self._session._on_flush(st)
-        return st
+
+
+def _nbytes(tree) -> int:
+    """Bytes of the arrays in a pytree."""
+    return sum(int(x.nbytes) for x in jax.tree.leaves(tree))
 
 
 # --------------------------------------------------------------------------- #
@@ -531,10 +537,12 @@ class GraphSession:
         placement = self._placement(cfg)
         key = (self._host_version, placement and placement.graph)
         if self._device is None or self._device_version != key:
-            self._device = None        # free the old copy before uploading
-            self._device = _device_subgraph(self.pg, placement)
-            self._device_version = key
-            self.stats.uploads += 1
+            with span("session/upload"):
+                self._device = None    # free the old copy before uploading
+                self._device = _device_subgraph(self.pg, placement)
+                self._device_version = key
+                self.stats.uploads += 1
+                self.stats.upload_bytes += _nbytes(self._device)
         return self._device
 
     # ------------------------------------------------------------------ #
@@ -570,7 +578,17 @@ class GraphSession:
 
         Buffered updates are flushed first: a query always sees every
         mutation accepted by ``update``.
+
+        The call is the span ``session/query``; its ``upload_bytes`` is what
+        the query added to ``SessionStats.upload_bytes``.
         """
+        with span("session/query") as sp:
+            before = self.stats.upload_bytes
+            out = self._query(program, params, warm, cfg, use_result_cache)
+            sp.set_metadata(upload_bytes=self.stats.upload_bytes - before)
+        return out
+
+    def _query(self, program, params, warm, cfg, use_result_cache):
         self._check_open()
         if self.buffer is not None and len(self.buffer):
             self.flush()
@@ -637,24 +655,28 @@ class GraphSession:
             args += (self._layout_arg(program, eb, cfg),)
         args += (params_c,)
         if warm_in:
-            args += (self._warm_arg(program, entry, use_warm, cfg),)
-        compiled, compile_time, evicted = self._get_runner(
-            program, pkey, params_c, cfg, warm_in, args, eb)
-        t0 = time.perf_counter()
-        out = self._launch(compiled, args, compile_time)
-        self.stats.device_launches += 1
-        res, steps, tot_msgs, sweeps = jax.block_until_ready(out)
-        wall = time.perf_counter() - t0
+            with span("session/warm"):
+                args += (self._warm_arg(program, entry, use_warm, cfg),)
+        with span("session/runner"):
+            compiled, compile_time, evicted = self._get_runner(
+                program, pkey, params_c, cfg, warm_in, args, eb)
+        with span("session/launch"):
+            t0 = time.perf_counter()
+            out = self._launch(compiled, args, compile_time)
+            self.stats.device_launches += 1
+            res, steps, tot_msgs, sweeps = jax.block_until_ready(out)
+            wall = time.perf_counter() - t0
         if use_warm:
             self.stats.warm_queries += 1
 
-        res = np.asarray(res)
-        stats = self._execution_stats(program, cfg, int(steps),
-                                      int(tot_msgs), np.asarray(sweeps),
-                                      wall, compile_time, eb)
-        stats.evicted_runners = evicted
-        if program.monotone:
-            self._remember(program, wkey, res, stats.supersteps)
+        with span("session/fetch"):
+            res = np.asarray(res)
+            stats = self._execution_stats(program, cfg, int(steps),
+                                          int(tot_msgs), np.asarray(sweeps),
+                                          wall, compile_time, eb)
+            stats.evicted_runners = evicted
+            if program.monotone:
+                self._remember(program, wkey, res, stats.supersteps)
         if use_rc:
             stats.result_cache_tier = "miss"
             self.result_cache.put(rkey, dict(
@@ -685,7 +707,19 @@ class GraphSession:
         each lane looks up / stores its own warm entry and result-cache
         key. The result cache short-circuits only when EVERY lane hits —
         a partial hit still launches the full batch (the lanes that hit
-        are simply recomputed; their entries refresh)."""
+        are simply recomputed; their entries refresh).
+
+        The call is the span ``session/query_batch``, with the children of
+        ``query``'s span and its ``upload_bytes``."""
+        with span("session/query_batch") as sp:
+            before = self.stats.upload_bytes
+            out = self._query_batch(program, params_list, warm, cfg,
+                                    use_result_cache)
+            sp.set_metadata(upload_bytes=self.stats.upload_bytes - before)
+        return out
+
+    def _query_batch(self, program, params_list, warm, cfg,
+                     use_result_cache):
         self._check_open()
         if self.buffer is not None and len(self.buffer):
             self.flush()
@@ -780,35 +814,41 @@ class GraphSession:
             args += (self._layout_arg(program, eb, cfg),)
         args += (batched_params,)
         if warm_in:
-            blocks = [self._warm_arg(program, entries[i], use_warms[i], cfg)
-                      for i in range(B)]
-            blocks += [blocks[0]] * pad
-            args += (jnp.stack(blocks),)
-        compiled, compile_time, evicted = self._get_runner(
-            program, pkey, batched_params, cfg, warm_in, args, eb, batch=Bp)
-        t0 = time.perf_counter()
-        out = self._launch(compiled, args, compile_time)
-        self.stats.device_launches += 1
-        res_b, steps_b, msgs_b, sweeps_b = jax.block_until_ready(out)
-        wall = time.perf_counter() - t0
+            with span("session/warm"):
+                blocks = [self._warm_arg(program, entries[i], use_warms[i],
+                                         cfg) for i in range(B)]
+                blocks += [blocks[0]] * pad
+                args += (jnp.stack(blocks),)
+        with span("session/runner"):
+            compiled, compile_time, evicted = self._get_runner(
+                program, pkey, batched_params, cfg, warm_in, args, eb,
+                batch=Bp)
+        with span("session/launch"):
+            t0 = time.perf_counter()
+            out = self._launch(compiled, args, compile_time)
+            self.stats.device_launches += 1
+            res_b, steps_b, msgs_b, sweeps_b = jax.block_until_ready(out)
+            wall = time.perf_counter() - t0
 
         results = []
-        for i in range(B):
-            res = np.asarray(res_b[i])
-            st = self._execution_stats(
-                program, cfg, int(steps_b[i]), int(msgs_b[i]),
-                np.asarray(sweeps_b[i]), wall, compile_time, eb)
-            st.evicted_runners = evicted
-            st.batch_size = B
-            if use_warms[i]:
-                self.stats.warm_queries += 1
-            if program.monotone:
-                self._remember(program, wkeys[i], res, st.supersteps)
-            if use_rc:
-                st.result_cache_tier = "miss"
-                self.result_cache.put(rkeys[i], dict(
-                    results=res, supersteps=st.supersteps, edge_backend=eb))
-            results.append((res, st))
+        with span("session/fetch"):
+            for i in range(B):
+                res = np.asarray(res_b[i])
+                st = self._execution_stats(
+                    program, cfg, int(steps_b[i]), int(msgs_b[i]),
+                    np.asarray(sweeps_b[i]), wall, compile_time, eb)
+                st.evicted_runners = evicted
+                st.batch_size = B
+                if use_warms[i]:
+                    self.stats.warm_queries += 1
+                if program.monotone:
+                    self._remember(program, wkeys[i], res, st.supersteps)
+                if use_rc:
+                    st.result_cache_tier = "miss"
+                    self.result_cache.put(rkeys[i], dict(
+                        results=res, supersteps=st.supersteps,
+                        edge_backend=eb))
+                results.append((res, st))
         return results
 
     def result_key_for(self, program: VertexProgram, params=None,
@@ -852,18 +892,27 @@ class GraphSession:
         cross a bucket (a new layout shape-key). ``'auto'`` passes the
         mixed-backend blocks (group-sliced pair on the simulator, full
         blocks + per-partition backend ids under shard_map); edge-axis
-        sharding passes the per-shard geometry."""
-        lay = self.pg.ensure_edge_layouts(shape_policy=self.shape_policy)
-        ns = self._n_edge_shards(cfg)
-        if eb == "auto":
-            asg = self._resolve_assignment(program, cfg)
-            if cfg.backend == "shard_map":
-                return _auto_layout_blocks(lay, self.pg, program, asg,
-                                           mixed_shard=True, n_shards=ns,
-                                           placement=self._placement(cfg))
-            return _auto_layout_blocks(lay, self.pg, program, asg)
-        return _layout_block_from(lay, self.pg, program, eb, n_shards=ns,
-                                  placement=self._placement(cfg))
+        sharding passes the per-shard geometry. The call is the span
+        ``session/layouts``."""
+        with span("session/layouts"):
+            lay = self.pg.ensure_edge_layouts(shape_policy=self.shape_policy)
+            before = lay.uploaded_bytes
+            ns = self._n_edge_shards(cfg)
+            if eb != "auto":
+                blk = _layout_block_from(lay, self.pg, program, eb,
+                                         n_shards=ns,
+                                         placement=self._placement(cfg))
+            elif cfg.backend == "shard_map":
+                blk = _auto_layout_blocks(
+                    lay, self.pg, program,
+                    self._resolve_assignment(program, cfg), mixed_shard=True,
+                    n_shards=ns, placement=self._placement(cfg))
+            else:
+                blk = _auto_layout_blocks(
+                    lay, self.pg, program,
+                    self._resolve_assignment(program, cfg))
+            self.stats.upload_bytes += lay.uploaded_bytes - before
+        return blk
 
     def _layout_key(self, program, eb, cfg):
         if eb == "coo":
@@ -943,13 +992,13 @@ class GraphSession:
         return self._upload_warm(
             _warm_block(program, pg, entry.global_values), sharding)
 
-    @staticmethod
-    def _upload_warm(blk: np.ndarray, sharding):
+    def _upload_warm(self, blk: np.ndarray, sharding):
         """A [P, v_max, K] block on the device (each device's own
         partitions' rows under a mesh ``sharding``)."""
-        if sharding is None:
-            return jnp.asarray(blk)
-        return jax.device_put(blk, sharding)
+        out = jnp.asarray(blk) if sharding is None \
+            else jax.device_put(blk, sharding)
+        self.stats.upload_bytes += _nbytes(out)
+        return out
 
     def _launch(self, compiled, args, compile_time):
         """Execute an AOT runner. With ``debug_sanitize`` armed, a *cache
@@ -1189,14 +1238,16 @@ class GraphSession:
         ``(src, dst)``; an ``EdgeDelta`` is accepted for either role via
         ``push``. Ops coalesce in the internal ``DeltaBuffer`` and are
         applied on ``flush()`` (or automatically when a buffer threshold
-        trips — the session notices either way)."""
+        trips — the session notices either way). The call is the span
+        ``stream/update``."""
         buf = self._require_buffer("update()")
         if isinstance(adds, EdgeDelta) or isinstance(deletes, EdgeDelta):
             raise TypeError("pass an EdgeDelta through session.push()")
-        if deletes is not None:
-            buf.delete(*deletes[:2])
-        if adds is not None:
-            buf.add(*adds[:3])
+        with span("stream/update"):
+            if deletes is not None:
+                buf.delete(*deletes[:2])
+            if adds is not None:
+                buf.add(*adds[:3])
 
     def push(self, delta: EdgeDelta) -> None:
         """Enqueue a whole producer ``EdgeDelta`` (deletes-then-adds)."""

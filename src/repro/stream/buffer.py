@@ -44,6 +44,7 @@ import numpy as np
 
 from repro.core.subgraph import (PartitionedGraph, ShapePolicy,
                                  resolve_shape_policy)
+from repro.obs import span
 from repro.stream.delta import DeltaStats, EdgeDelta, apply_delta
 from repro.stream.ingest import StreamContext
 
@@ -189,9 +190,29 @@ class DeltaBuffer:
     def flush(self, _auto: bool = False) -> Optional[DeltaStats]:
         """Resolve the buffer into one ``EdgeDelta`` and apply it. Returns
         the patch's ``DeltaStats`` (also kept as ``self.last_flush``), or
-        None if nothing was buffered."""
+        None if nothing was buffered. The span ``stream/flush`` covers the
+        coalescing (``stream/coalesce``), ``apply_delta`` and
+        ``_on_applied``."""
         if not self._ops:
             return None
+        with span("stream/flush"):
+            with span("stream/coalesce"):
+                delta = self._coalesce()
+            self.stats.n_flushes += 1
+            self.stats.auto_flushes += int(_auto)
+            self.stats.edges_flushed += delta.n_adds + delta.n_dels
+            self.last_flush = apply_delta(self.pg, self.ctx, delta,
+                                          shape_policy=self.shape_policy)
+            self._on_applied(self.last_flush)
+        return self.last_flush
+
+    def _on_applied(self, st: DeltaStats) -> None:
+        """Called after every applied flush (manual or threshold-tripped)
+        with its ``DeltaStats``; a no-op here."""
+
+    def _coalesce(self) -> EdgeDelta:
+        """The buffered ops as one ``EdgeDelta`` (pairs in sorted order);
+        empties the buffer."""
         keys = sorted(self._ops)                # deterministic flush order
         asrc, adst, aw, dsrc, ddst = [], [], [], [], []
         for k in keys:
@@ -209,9 +230,4 @@ class DeltaBuffer:
             del_src=np.array(dsrc, np.int64), del_dst=np.array(ddst, np.int64))
         self._ops.clear()
         self._parts.clear()
-        self.stats.n_flushes += 1
-        self.stats.auto_flushes += int(_auto)
-        self.stats.edges_flushed += delta.n_adds + delta.n_dels
-        self.last_flush = apply_delta(self.pg, self.ctx, delta,
-                                      shape_policy=self.shape_policy)
-        return self.last_flush
+        return delta

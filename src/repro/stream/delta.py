@@ -58,6 +58,7 @@ from repro.core.partition import route_vertices_rh
 from repro.core.subgraph import (PartitionedGraph, ShapePolicy,
                                  localize_edges, recompute_frontier,
                                  repack_partitions, resolve_shape_policy)
+from repro.obs import span
 from repro.stream.ingest import StreamContext
 
 __all__ = ["EdgeDelta", "DeltaStats", "apply_delta",
@@ -175,6 +176,9 @@ def apply_delta(pg: PartitionedGraph, ctx: StreamContext, delta: EdgeDelta,
     coalescing — "I added this pair a moment ago, now forget it" — is the
     ``DeltaBuffer``'s job (stream/buffer.py), which resolves op order
     *before* anything reaches this function.
+
+    Spans: ``stream/patch`` (routing through the degree refresh),
+    ``stream/frontier`` and ``stream/layouts``.
     """
     policy = resolve_shape_policy(shape_policy, pad_multiple)
     stats = DeltaStats(n_slots_before=pg.n_slots,
@@ -183,6 +187,38 @@ def apply_delta(pg: PartitionedGraph, ctx: StreamContext, delta: EdgeDelta,
     if delta.n_adds == 0 and delta.n_dels == 0:
         stats.n_slots_after = pg.n_slots
         return stats
+    with span("stream/patch"):
+        patched = _patch(pg, ctx, delta, policy, stats)
+
+    # ---- frontier-slot + master maintenance ------------------------------ #
+    with span("stream/frontier"):
+        recompute_frontier(pg)
+    stats.n_slots_after = pg.n_slots
+
+    # ---- Pallas edge-compute layouts: incremental refresh ----------------- #
+    # Only the partitions this delta actually patched get their tile/window
+    # geometry (and the touched rows of every cached tile realization)
+    # rebuilt; capacities are grow-only buckets, so an in-bucket flush keeps
+    # every compiled Pallas runner's input shapes intact. v_max growth moves
+    # the tile/window grid itself — then the whole layout is rebuilt (it
+    # coincides with a shape-key change, which already recompiles runners).
+    if pg.edge_layouts is not None:
+        with span("stream/layouts"):
+            lay = pg.edge_layouts
+            if lay.sync_capacity(pg):
+                lay.rebuild_partitions(pg, patched)
+            else:
+                pg.edge_layouts = None
+                pg.ensure_edge_layouts(shape_policy=lay.policy,
+                                       block_edges=lay.block_edges)
+    return stats
+
+
+def _patch(pg: PartitionedGraph, ctx: StreamContext, delta: EdgeDelta,
+           policy: ShapePolicy, stats: DeltaStats) -> list:
+    """``apply_delta``'s patch of the host arrays, from id growth through
+    the degree refresh; fills ``stats`` and returns the patched
+    partitions."""
     old_v_max = pg.v_max
     old_nv = pg.vmask.sum(axis=1)    # rows are packed at the front
 
@@ -311,27 +347,7 @@ def apply_delta(pg: PartitionedGraph, ctx: StreamContext, delta: EdgeDelta,
     sel = pg.vmask
     pg.out_deg[sel] = g_out[pg.gvid[sel]].astype(np.float32)
     pg.in_deg[sel] = g_in[pg.gvid[sel]].astype(np.float32)
-
-    # ---- frontier-slot + master maintenance ------------------------------ #
-    recompute_frontier(pg)
-    stats.n_slots_after = pg.n_slots
-
-    # ---- Pallas edge-compute layouts: incremental refresh ----------------- #
-    # Only the partitions this delta actually patched get their tile/window
-    # geometry (and the touched rows of every cached tile realization)
-    # rebuilt; capacities are grow-only buckets, so an in-bucket flush keeps
-    # every compiled Pallas runner's input shapes intact. v_max growth moves
-    # the tile/window grid itself — then the whole layout is rebuilt (it
-    # coincides with a shape-key change, which already recompiles runners).
-    if pg.edge_layouts is not None:
-        lay = pg.edge_layouts
-        if lay.sync_capacity(pg):
-            lay.rebuild_partitions(pg, staged.keys())
-        else:
-            pg.edge_layouts = None
-            pg.ensure_edge_layouts(shape_policy=lay.policy,
-                                   block_edges=lay.block_edges)
-    return stats
+    return list(staged)
 
 
 # --------------------------------------------------------------------------- #
