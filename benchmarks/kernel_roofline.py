@@ -90,57 +90,69 @@ def run(scale: str = "small"):
 
 
 def crossover(smoke: bool = False):
-    """Backend-crossover sweep: for every calibrated density point of the
-    platform's autotune table, report each backend's fitted sweep latency
-    next to the ``edge_backend='auto'`` pick. With ``smoke`` the pick is
-    asserted never slower than the worst manual backend at any point — the
-    guardrail CI runs against the shipped policy."""
-    tbl = autotune.get_table()
+    """Backend-crossover sweep: for the simulator's sweep key of each
+    shipped semiring program (BFS, SSSP, CC, PageRank), report each grid
+    point's fitted sweep latency per backend next to the
+    ``edge_backend='auto'`` pick, and what the key's calibration took. With
+    ``smoke`` the pick is asserted never slower than the worst manual
+    backend at any point — the guardrail CI runs against the shipped
+    policy."""
+    from repro.algos import BFS, SSSP, ConnectedComponents, PageRank
     backends = autotune.BACKEND_ORDER
-    rows, out = [], []
-    for p in tbl.points:
-        kw = dict(n_edges=[p["n_edges"]], n_vertices=p["n_vertices"],
-                  n_tiles=[p["n_tiles"]], n_blocks=[p["n_blocks"]],
-                  n_windows=p["n_windows"])
-        fitted = {b: float(c[0])
-                  for b, c in tbl.partition_costs(**kw).items()}
-        (pick,) = tbl.pick(**kw)
-        sampled = {"coo": p["cost_coo"], "pallas_tiles": p["cost_tiles"],
-                   "pallas_windows": p["cost_windows"]}
-        rows.append([p["n_vertices"], p["n_edges"],
-                     f"{p['density']:.4f}"]
-                    + [f"{fitted[b]*1e6:.2f}" for b in backends]
-                    + [pick])
-        out.append(dict(n_vertices=p["n_vertices"], n_edges=p["n_edges"],
-                        density=p["density"], pick=pick,
-                        fitted_us={b: fitted[b] * 1e6 for b in backends},
-                        sampled_us={b: sampled[b] * 1e6 for b in backends}))
-        if smoke:
-            worst = max(sampled.values())
-            assert sampled[pick] <= worst * (1.0 + 1e-9), (
-                f"auto picked {pick} ({sampled[pick]:.3e}s) but the worst "
-                f"manual backend costs {worst:.3e}s at density "
-                f"{p['density']:.4f}")
-
-    table(f"Edge-backend crossover — {tbl.source} calibration "
-          f"({tbl.platform}), fitted µs per sweep",
-          ["nv", "edges", "density"] + [f"{b} µs" for b in backends]
-          + ["auto pick"], rows)
-    picked = {b: sum(1 for o in out if o["pick"] == b) for b in backends}
-    print(f"picks: {picked}" + ("  [smoke: pick never worst — OK]"
-                                if smoke else ""))
-    return save("kernel_crossover", {
-        "platform": tbl.platform, "source": tbl.source,
-        "unit_costs": tbl.unit_costs, "points": out, "picks": picked,
-    })
+    out = []
+    for prog in (BFS(), SSSP(), ConnectedComponents(), PageRank()):
+        tbl = autotune.get_table(autotune.sweep_key(prog, "sim"))
+        name, rows, points = type(prog).__name__, [], []
+        for p in tbl.points:
+            kw = dict(n_edges=[p["n_edges"]], n_vertices=p["n_vertices"],
+                      n_tiles=[p["n_tiles"]], n_blocks=[p["n_blocks"]],
+                      n_windows=p["n_windows"])
+            fitted = {b: float(c[0])
+                      for b, c in tbl.partition_costs(**kw).items()}
+            (pick,) = tbl.pick(**kw)
+            sampled = {"coo": p["cost_coo"], "pallas_tiles": p["cost_tiles"],
+                       "pallas_windows": p["cost_windows"]}
+            rows.append([p["n_vertices"], p["n_edges"]]
+                        + [f"{fitted[b]*1e6:.2f}" for b in backends]
+                        + [f"{sampled[b]*1e6:.2f}" for b in backends]
+                        + [pick])
+            points.append(dict(n_vertices=p["n_vertices"],
+                               n_edges=p["n_edges"], pick=pick,
+                               fitted_us={b: fitted[b] * 1e6
+                                          for b in backends},
+                               sampled_us={b: sampled[b] * 1e6
+                                           for b in backends}))
+            if smoke:
+                worst = max(sampled.values())
+                assert sampled[pick] <= worst * (1.0 + 1e-9), (
+                    f"auto picked {pick} ({sampled[pick]:.3e}s) but the "
+                    f"worst manual backend costs {worst:.3e}s at "
+                    f"{p['n_vertices']} vertices, {p['n_edges']} edges")
+        k = tbl.key
+        table(f"Edge-backend crossover — {name} "
+              f"({k.semiring}/{k.edge_values}/{k.dtype}, {k.engine}), "
+              f"{tbl.source} calibration ({k.platform}, "
+              f"{tbl.seconds:.1f}s), µs per partition sweep",
+              ["nv", "edges"] + [f"{b} fit" for b in backends]
+              + [f"{b} point" for b in backends] + ["auto pick"], rows)
+        picks = {b: sum(1 for o in points if o["pick"] == b)
+                 for b in backends}
+        print(f"picks: {picks}" + ("  [smoke: pick never worst — OK]"
+                                   if smoke else ""))
+        out.append(dict(key=k._asdict(), program=name,
+                        source=tbl.source, seconds=tbl.seconds,
+                        unit_costs=tbl.unit_costs, points=points,
+                        picks=picks))
+    return save("kernel_crossover", {"tables": out})
 
 
 if __name__ == "__main__":
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--scale", default="small", choices=("small", "large"))
     ap.add_argument("--crossover", action="store_true",
-                    help="sweep the calibrated density grid and report "
-                         "per-backend latency plus the auto policy's pick")
+                    help="sweep each sweep key's calibration grid and "
+                         "report per-backend latency plus the auto "
+                         "policy's pick")
     ap.add_argument("--smoke", action="store_true",
                     help="with --crossover: assert the auto pick is never "
                          "slower than the worst manual backend")

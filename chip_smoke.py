@@ -9,8 +9,8 @@ plain scipy / numpy reference that does not use the engine.
 
 One chip: connected components, SSSP from two sources and BFS on the
 ``coo`` and ``pallas_windows`` edge backends over the full graph, and on
-``auto`` (only BFS when ``auto`` puts every partition on one of those
-two); one repeated query that must hit the compiled-runner cache; one small
+``auto`` (BFS, and each query whose sweep key's assignment is not all on
+one of those two); one repeated query that must hit the compiled-runner cache; one small
 ``update`` + ``flush`` followed by a warm query. Then, on the largest
 graph of scale <= 16 whose dense tiles fit: PageRank on those three
 backends and every query on ``pallas_tiles``. ``--chips 4``: a session over
@@ -296,23 +296,31 @@ def one_chip(args, check: Checks, on_tpu: bool) -> None:
 
 def run_auto(sess, queries, check: Checks, on_tpu: bool, ref: Reference,
              checked) -> None:
-    """``'auto'`` under its calibration (measured on a TPU). An assignment
-    that puts every partition on one backend already ``checked`` on every
-    query computes what that backend computed: one query (BFS, the
-    cheapest) shows it runs."""
-    t = time.perf_counter()
-    table = autotune.get_table()
-    log(f"autotune table for {table.platform!r}: {table.source} "
-        f"({time.perf_counter() - t:.1f}s) "
-        f"unit costs {json.dumps(table.unit_costs)}")
-    check("auto calibration measured on a TPU, modeled elsewhere",
-          table.source == ("measured" if on_tpu else "modeled"))
-    asg = sess._resolve_assignment(queries[0][1],
-                                   EngineConfig(edge_backend="auto"))
-    log(f"auto assignment: {dict(collections.Counter(asg))}")
-    if len(set(asg)) == 1 and asg[0] in checked:
-        queries = queries[-1:]
-    run_queries(sess, queries, "auto", check, on_tpu, ref)
+    """``'auto'`` under the calibration of each query's sweep key (measured
+    on a TPU). A query whose assignment puts every partition on one
+    backend already ``checked`` computes what that backend computed, and
+    is skipped; BFS, the cheapest, runs in any case to show ``auto``
+    runs."""
+    cfg = EngineConfig(edge_backend="auto")
+    run = []
+    for q in queries:
+        prog = q[1]
+        t = time.perf_counter()
+        table = autotune.get_table(autotune.sweep_key(prog, cfg.backend))
+        k = table.key
+        log(f"autotune table {k.semiring}/{k.edge_values}/{k.dtype} on "
+            f"{k.platform!r}: {table.source} "
+            f"({time.perf_counter() - t:.1f}s here, calibration "
+            f"{table.seconds:.1f}s) unit costs "
+            f"{json.dumps(table.unit_costs)}")
+        check(f"auto/{q[0]} calibration measured on a TPU, modeled "
+              f"elsewhere",
+              table.source == ("measured" if on_tpu else "modeled"))
+        asg = sess._resolve_assignment(prog, cfg)
+        log(f"auto assignment for {q[0]}: {dict(collections.Counter(asg))}")
+        if len(set(asg)) > 1 or asg[0] not in checked or q is queries[-1]:
+            run.append(q)
+    run_queries(sess, run, "auto", check, on_tpu, ref)
 
 
 def served(sess, g, repeat, check: Checks) -> None:

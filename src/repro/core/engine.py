@@ -306,10 +306,11 @@ def resolve_partition_backends(program: VertexProgram, cfg: EngineConfig,
                                table=None) -> tuple:
     """Per-partition concrete backend assignment. Uniform (non-``'auto'``)
     configs broadcast the resolved backend; ``'auto'`` consults the
-    platform's calibration table (core/autotune.py) over the partition's
-    layout-geometry unit counts. Deterministic for a given (table,
-    geometry) — sessions additionally pin the assignment per shape bucket
-    so in-bucket growth cannot flip it."""
+    calibration table of the program's sweep key (device kind, engine
+    backend, semiring, edge values, dtype — core/autotune.py) over the
+    partition's layout-geometry unit counts. Deterministic for a given
+    (table, geometry) — sessions additionally pin the assignment per sweep
+    key and shape bucket so in-bucket growth cannot flip it."""
     eb = resolve_edge_backend(program, cfg)
     if eb != "auto":
         return (eb,) * pg.n_parts
@@ -317,7 +318,7 @@ def resolve_partition_backends(program: VertexProgram, cfg: EngineConfig,
     if lay is None:
         lay = pg.ensure_edge_layouts()
     if table is None:
-        table = autotune.get_table()
+        table = autotune.get_table(autotune.sweep_key(program, cfg.backend))
     return autotune.pick_backends(table, pg, lay)
 
 
@@ -450,6 +451,16 @@ def _layout_block_from(lay: EdgeLayouts, pg: PartitionedGraph,
     return lay.device_windows(shardings=w_pl)
 
 
+def _effective_backend(edge_backend: str, assignment) -> str:
+    """The backend whose path a runner takes: an ``'auto'`` assignment that
+    puts every partition on one backend runs that backend's own uniform
+    path (no group slicing, no per-partition switch) — the form the
+    calibration timed."""
+    if edge_backend == "auto" and len(set(assignment)) == 1:
+        return assignment[0]
+    return edge_backend
+
+
 def _assignment_groups(assignment) -> tuple:
     """Static per-backend partition groups of an ``'auto'`` assignment:
     ``((backend, [P_g] int64 indices), ...)`` in a fixed order."""
@@ -468,6 +479,9 @@ def _auto_layout_blocks(lay: EdgeLayouts, pg: PartitionedGraph,
                         placement: Optional[ShardSpecs] = None):
     """Layout input of an ``'auto'`` runner.
 
+    A uniform assignment takes the uniform backend's own input (None for
+    ``coo``), as ``_effective_backend`` runs its path.
+
     Simulator (``mixed_shard=False``): ``(tiles, windows)`` with each block
     group-sliced to just the partitions its backend owns (``None`` when the
     backend owns nothing) — the mixed superstep launches one kernel per
@@ -480,6 +494,12 @@ def _auto_layout_blocks(lay: EdgeLayouts, pg: PartitionedGraph,
     picks the path, so one executable serves any assignment shape;
     ``placement`` puts all three on the mesh."""
     spec = program.sweep_spec
+    uniform = _effective_backend("auto", assignment)
+    if uniform == "coo":
+        return None
+    if uniform != "auto":
+        return _layout_block_from(lay, pg, program, uniform, n_shards,
+                                  placement)
     if mixed_shard:
         ids = np.asarray([_BACKEND_IDS[b] for b in assignment], np.int32)
         ids = jnp.asarray(ids) if placement is None \
@@ -509,29 +529,32 @@ def _auto_layout_blocks(lay: EdgeLayouts, pg: PartitionedGraph,
     return blk
 
 
+def _stack_product(backend: str, spec: SemiringSweep, sgs, lay_blk, v):
+    """Stacked [P, v_max, K] semiring product on one backend: the vmapped
+    COO reference product, or the flattened kernel launch over the stacked
+    layout block (``lax.map``'d per partition for windows)."""
+    from repro.core.api import coo_semiring_product
+    v_max = sgs.vmask.shape[-1]
+    if backend == "coo":
+        return jax.vmap(
+            lambda sg, vv: coo_semiring_product(sg, spec, vv))(sgs, v)
+    if backend == "pallas_tiles":
+        return _tile_product(lay_blk, v, spec, v_max)
+    return _window_product(lay_blk, v, spec, v_max, sgs.esrc, sgs.ew)
+
+
 def _mixed_product(program: VertexProgram, groups, sgs, lay_blks, v):
     """Stacked [P, v_max, K] semiring product under a mixed per-partition
     backend assignment: one launch per backend group over its (static)
     partition sub-stack, scattered back into the full aggregate. Matches
-    the uniform paths bit-for-bit per partition — the COO group is the
-    vmapped reference product, the Pallas groups are the flattened kernel
-    launches over group-sliced layout blocks."""
-    from repro.core.api import coo_semiring_product
-    spec = program.sweep_spec
-    t_blk, w_blk = lay_blks
-    v_max = sgs.vmask.shape[-1]
+    the uniform paths bit-for-bit per partition."""
+    blks = {"coo": None, "pallas_tiles": lay_blks[0],
+            "pallas_windows": lay_blks[1]}
     agg = jnp.zeros(v.shape, v.dtype)       # every row overwritten below
     for backend, gidx in groups:
-        if backend == "coo":
-            sub = jax.tree.map(lambda a: a[gidx], sgs)
-            part = jax.vmap(
-                lambda sg, vv: coo_semiring_product(sg, spec, vv)
-            )(sub, v[gidx])
-        elif backend == "pallas_tiles":
-            part = _tile_product(t_blk, v[gidx], spec, v_max)
-        else:
-            part = _window_product(w_blk, v[gidx], spec, v_max,
-                                   sgs.esrc[gidx], sgs.ew[gidx])
+        sub = jax.tree.map(lambda a: a[gidx], sgs)
+        part = _stack_product(backend, program.sweep_spec, sub,
+                              blks[backend], v[gidx])
         agg = agg.at[jnp.asarray(gidx)].set(part)
     return agg
 
@@ -598,14 +621,11 @@ def _batched_local_phase(program: VertexProgram, sgs, lay_blk, params, state,
             lambda sg, s: program.sweep_values(sg, params, s))(sgs, st)
         squeeze = vals.ndim == 2
         v = vals[..., None] if squeeze else vals
-        v_max = sgs.vmask.shape[-1]
         if edge_backend == "auto":
             agg = _mixed_product(program, groups, sgs, lay_blk, v)
-        elif edge_backend == "pallas_tiles":
-            agg = _tile_product(lay_blk, v, program.sweep_spec, v_max)
         else:
-            agg = _window_product(lay_blk, v, program.sweep_spec, v_max,
-                                  sgs.esrc, sgs.ew)
+            agg = _stack_product(edge_backend, program.sweep_spec, sgs,
+                                 lay_blk, v)
         if squeeze:
             agg = agg[..., 0]
         return jax.vmap(
@@ -727,10 +747,12 @@ def _make_sim_superstep(program: VertexProgram, cfg: EngineConfig,
     """One BSP superstep over the stacked [P, ...] pytree: vmapped local
     phase on the COO backend, one flattened Pallas launch per sweep on the
     kernel backends (per backend group under a mixed ``'auto'``
-    ``assignment``). ``lay`` is the device layout pytree (None for COO)."""
+    ``assignment``; a uniform one runs its backend's path). ``lay`` is the
+    device layout pytree (None for COO)."""
     ident = program.identity
     ec = EdgeCombine(())
     ex = sbs.SimExchange()
+    edge_backend = _effective_backend(edge_backend, assignment)
     groups = _assignment_groups(assignment) if edge_backend == "auto" \
         else None
 
@@ -1019,7 +1041,9 @@ def make_bsp_runner(program: VertexProgram, mesh: Mesh,
     sums, exactly like the COO path's sharded product. Under ``'auto'``
     (``partition_backends`` required) the layout input is
     ``(tiles, windows, backend_ids)`` with full blocks and a per-partition
-    ``lax.switch`` picks the sweep — one executable serves any assignment.
+    ``lax.switch`` picks the sweep — one executable serves any mixed
+    assignment; a uniform one takes its backend's own layout input (None
+    for ``coo``) and sweep.
 
     ``batch=True`` (requires ``params_as_input=True``) builds the
     micro-batching variant: the warm block (when present) and every params
@@ -1057,6 +1081,10 @@ def make_bsp_runner(program: VertexProgram, mesh: Mesh,
             raise ValueError("edge_backend='auto' runners need the resolved "
                              "partition_backends assignment "
                              "(resolve_partition_backends)")
+        edge_backend = _effective_backend(edge_backend, partition_backends)
+        if edge_backend == "coo":
+            lay_specs = P()                 # the layout input is None
+    if edge_backend == "auto":
         lay_specs = (specs.tiles, specs.windows, specs.part)
         tiles_sweep = _make_pallas_sweep(program, "pallas_tiles")
         windows_sweep = _make_pallas_sweep(program, "pallas_windows")

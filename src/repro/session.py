@@ -100,6 +100,7 @@ from repro.core.engine import (EngineConfig, _auto_layout_blocks,
                                normalize_edge_backend,
                                resolve_partition_backends, run_sim,
                                shard_placement)
+from repro.core import autotune
 from repro.core.api import VertexProgram
 from repro.core.graph import Graph
 from repro.core.metrics import ExecutionStats
@@ -343,8 +344,9 @@ class GraphSession:
         self._host_version = 0         # bumped by every applied flush/compact
         self._warm: OrderedDict = OrderedDict()     # (pkey, params) -> entry
         self._identity_blocks: dict = {}  # cold-start [P,v_max,K] blocks
-        self._auto_pin: dict = {}      # (shape, tiles, windows buckets) ->
-                                       # pinned 'auto' backend assignment
+        self._auto_pin: dict = {}      # (sweep key, shape, tiles, windows
+                                       # buckets) -> pinned 'auto' backend
+                                       # assignment
         self._keepalive: dict = {}     # id-keyed programs pinned alive
         self._warm_epoch = 0           # advances per layout-moving event
         self._remap_log: list = []     # [(epoch, stats-with-remap_state)]:
@@ -580,12 +582,15 @@ class GraphSession:
         mutation accepted by ``update``.
 
         The call is the span ``session/query``; its ``upload_bytes`` is what
-        the query added to ``SessionStats.upload_bytes``.
+        the query added to ``SessionStats.upload_bytes``, and its
+        ``pallas_edge_share`` the share of resident edges in partitions
+        whose edge backend is a Pallas kernel.
         """
         with span("session/query") as sp:
             before = self.stats.upload_bytes
             out = self._query(program, params, warm, cfg, use_result_cache)
-            sp.set_metadata(upload_bytes=self.stats.upload_bytes - before)
+            sp.set_metadata(upload_bytes=self.stats.upload_bytes - before,
+                            pallas_edge_share=self._pallas_edge_share(out[1]))
         return out
 
     def _query(self, program, params, warm, cfg, use_result_cache):
@@ -710,12 +715,17 @@ class GraphSession:
         are simply recomputed; their entries refresh).
 
         The call is the span ``session/query_batch``, with the children of
-        ``query``'s span and its ``upload_bytes``."""
+        ``query``'s span and its ``upload_bytes`` and
+        ``pallas_edge_share``."""
         with span("session/query_batch") as sp:
             before = self.stats.upload_bytes
             out = self._query_batch(program, params_list, warm, cfg,
                                     use_result_cache)
-            sp.set_metadata(upload_bytes=self.stats.upload_bytes - before)
+            meta = dict(upload_bytes=self.stats.upload_bytes - before)
+            if out:
+                meta["pallas_edge_share"] = self._pallas_edge_share(
+                    out[0][1])
+            sp.set_metadata(**meta)
         return out
 
     def _query_batch(self, program, params_list, warm, cfg,
@@ -861,24 +871,42 @@ class GraphSession:
         return _result_key(self.tenant, self._host_version, program,
                            _canonical_params(params), cfg)
 
+    def _pallas_edge_share(self, st: ExecutionStats) -> float:
+        """Share of the resident edges that the query's edge backend put
+        in a Pallas kernel: 0 on ``coo``, 1 on ``pallas_*``, and under
+        ``'auto'`` the edges of the partitions assigned a kernel."""
+        if st.edge_backend != "auto":
+            return 0.0 if st.edge_backend == "coo" else 1.0
+        epp = self.pg.edges_per_part.astype(np.float64)
+        kernel = np.array([b != "coo" for b in st.partition_edge_backends],
+                          bool)
+        if kernel.shape != epp.shape or epp.sum() == 0:
+            return 0.0
+        return float(epp[kernel].sum() / epp.sum())
+
     def _n_edge_shards(self, cfg) -> int:
         if cfg.backend != "shard_map" or not cfg.edge_axes \
                 or self.mesh is None:
             return 1
         return int(np.prod([self.mesh.shape[a] for a in cfg.edge_axes]))
 
+    def _pin_key(self, skey) -> tuple:
+        lay = self.pg.ensure_edge_layouts(shape_policy=self.shape_policy)
+        return (skey, self.shape_key, lay.shape_key("pallas_tiles"),
+                lay.shape_key("pallas_windows"))
+
     def _resolve_assignment(self, program, cfg) -> tuple:
         """The per-partition backend assignment a ``'auto'`` query runs
-        with, PINNED per (padded-shape, layout-capacity) bucket: the policy
-        is consulted once when a bucket combination is first seen, and every
-        later query in the same buckets reuses the pick even though the
-        measured densities drift with streaming growth — that is the
-        zero-retrace guarantee ('auto' never flips a backend mid-bucket).
-        Bucket crossings (flush past a capacity, compact, rebalance)
-        naturally re-resolve under their new key."""
+        with, PINNED per (sweep key, padded-shape, layout-capacity) bucket:
+        the policy is consulted once when a program's sweep key (semiring,
+        edge values, dtype, engine backend) meets a bucket combination, and
+        every later query of that key in the same buckets reuses the pick
+        even though the measured densities drift with streaming growth —
+        that is the zero-retrace guarantee ('auto' never flips a backend
+        mid-bucket). Bucket crossings (flush past a capacity, compact,
+        rebalance) naturally re-resolve under their new key."""
         lay = self.pg.ensure_edge_layouts(shape_policy=self.shape_policy)
-        key = (self.shape_key, lay.shape_key("pallas_tiles"),
-               lay.shape_key("pallas_windows"))
+        key = self._pin_key(autotune.sweep_key(program, cfg.backend))
         asg = self._auto_pin.get(key)
         if asg is None:
             asg = resolve_partition_backends(program, cfg, self.pg, lay=lay)
@@ -926,7 +954,7 @@ class GraphSession:
             # lands on different picks must compile a fresh runner (group
             # composition is baked into the traced argument structure)
             asg = self._resolve_assignment(program, cfg)
-            return ("auto", asg,
+            return ("auto", autotune.sweep_key(program, cfg.backend), asg,
                     lay.shape_key("pallas_tiles", n_shards=ns, pg=self.pg),
                     lay.shape_key("pallas_windows", n_shards=ns, pg=self.pg))
         return lay.shape_key(eb, n_shards=ns, pg=self.pg)
@@ -1412,16 +1440,14 @@ class GraphSession:
             if not have_lay:
                 return True
             if lkey[0] == "auto":
-                _, asg, tk, wk = lkey
+                _, skey, asg, tk, wk = lkey
                 ns = tk[1] if len(tk) == 5 else 1
                 if tk != lay_key_now("pallas_tiles", ns) \
                         or wk != lay_key_now("pallas_windows", ns):
                     return True
                 # a re-resolved pin that landed on different picks stales
                 # the old mixed-backend executable
-                pin = self._auto_pin.get(
-                    (cur, lay.shape_key("pallas_tiles"),
-                     lay.shape_key("pallas_windows")))
+                pin = self._auto_pin.get(self._pin_key(skey))
                 return pin is not None and pin != asg
             ns = lkey[1] if len(lkey) == 5 else 1
             backend = "pallas_tiles" if lkey[0] == "tiles" \
