@@ -390,9 +390,15 @@ def _window_product(blk: WindowBlock, vals, spec: SemiringSweep, v_max: int,
     msgs = _edge_messages(spec, vals, esrc, ew)
     K = vals.shape[-1]
     n_buf = blk.ldst.shape[-1]
-    slot = jnp.where(blk.eslot >= 0, blk.eslot, n_buf)       # pad -> dropped
+    # a partition's real edges lead its edge slots in ascending buffer
+    # slot (localize_edges sorts them by dst, padding trails), so with each
+    # padding edge sent to a slot of its own past the buffer the scatter is
+    # sorted and unique: XLA then needs no sort of the slots before it
+    pad = n_buf + jnp.arange(blk.eslot.shape[-1], dtype=jnp.int32)
+    slot = jnp.where(blk.eslot >= 0, blk.eslot, pad)         # pad -> dropped
     buf = jnp.full((n_buf, K), ident, vals.dtype)
-    buf = buf.at[slot].set(msgs, mode="drop")
+    buf = buf.at[slot].set(msgs, mode="drop", indices_are_sorted=True,
+                           unique_indices=True)
     out = segment_combine_windowed(buf, blk.ldst, blk.bwin, n_windows=nw,
                                    combiner=spec.combiner)
     return out.reshape(nw * W, K)[:v_max]
@@ -489,10 +495,13 @@ def _auto_layout_blocks(lay: EdgeLayouts, pg: PartitionedGraph,
     repeated queries reuse the slices until a rebuild invalidates them.
 
     shard_map (``mixed_shard=True``): ``(tiles, windows, backend_ids)``
-    with *full* (possibly edge-axis-sharded) blocks — every device gets
-    same-shaped slices and a ``lax.switch`` on its partition's backend id
-    picks the path, so one executable serves any assignment shape;
-    ``placement`` puts all three on the mesh."""
+    — every device gets same-shaped slices and a ``lax.switch`` on its
+    partition's backend id picks the path. A backend that some partition
+    runs takes its full (possibly edge-axis-sharded) block; one that no
+    partition runs takes ``EdgeLayouts.device_stub``, one tile or window a
+    partition, for a branch that is traced but never taken (a graph's
+    dense tiles can outgrow any memory). ``placement`` puts all three on
+    the mesh."""
     spec = program.sweep_spec
     uniform = _effective_backend("auto", assignment)
     if uniform == "coo":
@@ -505,10 +514,15 @@ def _auto_layout_blocks(lay: EdgeLayouts, pg: PartitionedGraph,
         ids = jnp.asarray(ids) if placement is None \
             else jax.device_put(ids, placement.part)
         lay.uploaded_bytes += ids.nbytes
-        return (_layout_block_from(lay, pg, program, "pallas_tiles",
-                                   n_shards, placement),
-                _layout_block_from(lay, pg, program, "pallas_windows",
-                                   n_shards, placement), ids)
+        t_pl = None if placement is None else placement.tiles
+        w_pl = None if placement is None else placement.windows
+        return tuple(
+            _layout_block_from(lay, pg, program, b, n_shards, placement)
+            if b in assignment else
+            lay.device_stub(b, spec.semiring, program.dtype, n_shards,
+                            shardings=pl)
+            for b, pl in (("pallas_tiles", t_pl),
+                          ("pallas_windows", w_pl))) + (ids,)
     t_idx = tuple(p for p, b in enumerate(assignment)
                   if b == "pallas_tiles")
     w_idx = tuple(p for p, b in enumerate(assignment)
@@ -776,6 +790,7 @@ def _make_sim_superstep(program: VertexProgram, cfg: EngineConfig,
         with scope("exchange"):
             merged_buf = ex.all_combine(bufs, program.combiner)
             merged_buf = merged_buf.at[n_slots].set(ident)
+        with scope("vote"):
             msgs = jnp.sum(changed, dtype=jnp.int32)
             active = jnp.sum(last_ch > 0, dtype=jnp.int32)
         return state, out, merged_buf, msgs, active, sweeps
@@ -1039,10 +1054,10 @@ def make_bsp_runner(program: VertexProgram, mesh: Mesh,
     aggregates across the shards before the fold — bit-identical to the
     unsharded launch for min-combines, float-associativity-tolerant for
     sums, exactly like the COO path's sharded product. Under ``'auto'``
-    (``partition_backends`` required) the layout input is
-    ``(tiles, windows, backend_ids)`` with full blocks and a per-partition
-    ``lax.switch`` picks the sweep — one executable serves any mixed
-    assignment; a uniform one takes its backend's own layout input (None
+    (``partition_backends`` required) a mixed assignment's layout input is
+    ``(tiles, windows, backend_ids)`` (``_auto_layout_blocks``: a stub for
+    a backend no partition runs) and a per-partition ``lax.switch`` picks
+    the sweep; a uniform one takes its backend's own layout input (None
     for ``coo``) and sweep.
 
     ``batch=True`` (requires ``params_as_input=True``) builds the
@@ -1174,7 +1189,7 @@ def make_bsp_runner(program: VertexProgram, mesh: Mesh,
                 merged_v = _exchange_sharded(out, changed)
             else:
                 merged_v = _exchange_dense(out, changed)
-            with scope("exchange"):
+            with scope("vote"):
                 msgs = ex.all_sum_scalar(jnp.sum(changed, dtype=jnp.int32))
                 active = ex.all_sum_scalar((last_ch > 0).astype(jnp.int32))
             return state, out, merged_v, msgs, active, sweeps

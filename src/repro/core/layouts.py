@@ -319,6 +319,30 @@ class EdgeLayouts:
             self._device[key] = blk
         return blk
 
+    def device_stub(self, backend: str, semiring: str, dtype,
+                    n_shards: int = 1, *, shardings=None):
+        """A block of ``backend``'s pytree structure that holds one tile or
+        one window a partition (edge shard), every edge padding: the input
+        a mixed shard_map ``'auto'`` runner takes for a backend that no
+        partition runs, whose branch is traced but never taken."""
+        S, P = int(n_shards), self.n_parts
+        key = ("stub", backend, semiring, np.dtype(dtype).str, S, shardings)
+        blk = self._device.get(key)
+        if blk is None:
+            if backend == "pallas_tiles":
+                ident = tile_pad_identity(semiring, np.dtype(dtype))
+                host = TileBlock(np.full((P, S, TM, TN), ident, dtype),
+                                 np.zeros((P, S), np.int32),
+                                 np.zeros((P, S), np.int32))
+            else:
+                host = WindowBlock(np.full((P, self.e_max), -1, np.int32),
+                                   np.zeros((P, S * self.block_edges),
+                                            np.int32),
+                                   np.zeros((P, S), np.int32))
+            blk = self._upload(host, shardings)
+            self._device[key] = blk
+        return blk
+
     # ------------------------------------------------------------------ #
     # edge-axis-sharded geometry (shard_map edge_axes on Pallas backends)
     # ------------------------------------------------------------------ #

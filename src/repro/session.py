@@ -582,15 +582,17 @@ class GraphSession:
         mutation accepted by ``update``.
 
         The call is the span ``session/query``; its ``upload_bytes`` is what
-        the query added to ``SessionStats.upload_bytes``, and its
+        the query added to ``SessionStats.upload_bytes``, its
         ``pallas_edge_share`` the share of resident edges in partitions
-        whose edge backend is a Pallas kernel.
+        whose edge backend is a Pallas kernel, and its ``exchange_bytes``
+        the query's ``ExecutionStats.total_bytes``.
         """
         with span("session/query") as sp:
             before = self.stats.upload_bytes
             out = self._query(program, params, warm, cfg, use_result_cache)
             sp.set_metadata(upload_bytes=self.stats.upload_bytes - before,
-                            pallas_edge_share=self._pallas_edge_share(out[1]))
+                            pallas_edge_share=self._pallas_edge_share(out[1]),
+                            exchange_bytes=out[1].total_bytes)
         return out
 
     def _query(self, program, params, warm, cfg, use_result_cache):
@@ -715,8 +717,8 @@ class GraphSession:
         are simply recomputed; their entries refresh).
 
         The call is the span ``session/query_batch``, with the children of
-        ``query``'s span and its ``upload_bytes`` and
-        ``pallas_edge_share``."""
+        ``query``'s span and its ``upload_bytes``, ``pallas_edge_share``
+        and ``exchange_bytes`` (summed over the lanes)."""
         with span("session/query_batch") as sp:
             before = self.stats.upload_bytes
             out = self._query_batch(program, params_list, warm, cfg,
@@ -725,6 +727,8 @@ class GraphSession:
             if out:
                 meta["pallas_edge_share"] = self._pallas_edge_share(
                     out[0][1])
+                meta["exchange_bytes"] = sum(st.total_bytes
+                                             for _, st in out)
             sp.set_metadata(**meta)
         return out
 

@@ -497,3 +497,77 @@ def test_bfs_sssp_cc_pin_separately_shard_map(tmp_path):
                          env=env)
     assert res.returncode == 0, res.stdout + res.stderr
     assert "PIN_SHARD_OK" in res.stdout
+
+
+MIXED_SHARD_SCRIPT = r"""
+import os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ["DRONE_AUTOTUNE_DIR"] = os.environ["AUTOTUNE_TMP"]
+import numpy as np
+from scipy.sparse import csr_matrix
+from scipy.sparse.csgraph import shortest_path
+
+import repro.algos as algos
+import repro.session as session
+from repro.compat import make_mesh
+from repro.core import EngineConfig
+from repro.core.layouts import EdgeLayouts
+from repro.graphgen import kronecker_graph
+
+MIXED = ("pallas_windows", "coo", "pallas_windows", "coo")
+session.resolve_partition_backends = lambda *a, **k: MIXED
+
+
+def no_tiles(*a, **k):
+    raise AssertionError("a dense tile block was built")
+
+
+for name in ("_realize_tiles", "device_tiles", "device_tiles_sharded"):
+    setattr(EdgeLayouts, name, no_tiles)
+
+g = kronecker_graph(10, seed=3, weighted=True)
+sess = session.GraphSession.from_graph(g, 4, "cdbh",
+                                       mesh=make_mesh((4,), ("sub",)))
+prog = getattr(algos, sys.argv[1])()
+key = int(np.argmax(np.bincount(g.src, minlength=g.n_vertices)))
+cfg = EngineConfig(edge_backend="auto", subgraph_axes=("sub",))
+res, st = sess.query(prog, {"source": key}, warm=False, cfg=cfg)
+assert tuple(st.partition_edge_backends) == MIXED, st.partition_edge_backends
+got = sess.pg.collect(res, fill=np.float32(np.inf)).astype(np.float64)
+
+A = csr_matrix((g.weight.astype(np.float64), (g.src, g.dst)),
+               shape=(g.n_vertices, g.n_vertices))
+want = shortest_path(A, directed=True, unweighted=sys.argv[1] == "BFS",
+                     indices=key)
+assert np.array_equal(np.isfinite(got), np.isfinite(want))
+fin = np.isfinite(want)
+np.testing.assert_allclose(got[fin], want[fin], rtol=1e-5)
+
+ref, st_coo = sess.query(prog, {"source": key}, warm=False,
+                         cfg=EngineConfig(edge_backend="coo",
+                                          subgraph_axes=("sub",)))
+np.testing.assert_array_equal(np.asarray(ref), np.asarray(res))
+assert (st.supersteps, st.processed_edges) == \
+    (st_coo.supersteps, st_coo.processed_edges)
+
+# the tiles input is the stub: one identity tile a partition
+tiles, windows, ids = sess._layout_arg(prog, "auto", sess._normalize_cfg(cfg))
+assert tiles.tiles.shape[:2] == (4, 1), tiles.tiles.shape
+assert windows.eslot.shape == (4, sess.pg.e_max)
+assert np.asarray(ids).tolist() == [2, 0, 2, 0]
+print("MIXED_SHARD_OK")
+"""
+
+
+@pytest.mark.parametrize("program", ["BFS", "SSSP"])
+def test_mixed_shard_map_builds_no_block_for_an_unused_backend(tmp_path,
+                                                               program):
+    """A mixed shard_map ``'auto'`` assignment (windows on two partitions,
+    coo on two) answers as the oracle and as coo, with the same supersteps,
+    and never realizes the dense tiles that no partition runs."""
+    env = dict(os.environ, AUTOTUNE_TMP=str(tmp_path))
+    res = subprocess.run([sys.executable, "-c", MIXED_SHARD_SCRIPT, program],
+                         capture_output=True, text=True, timeout=1200,
+                         env=env)
+    assert res.returncode == 0, res.stdout + res.stderr[-4000:]
+    assert "MIXED_SHARD_OK" in res.stdout

@@ -317,6 +317,42 @@ def test_compact_rebuilds_layouts(graph):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+def _assert_slots_sorted_unique(eslot):
+    """The windowed product's scatter promises XLA sorted, unique slots:
+    each row is a strictly ascending run of real slots, then padding."""
+    for row in np.asarray(eslot):
+        ne = int((row >= 0).sum())
+        assert (row[ne:] == -1).all(), "padding edge before a real edge"
+        assert (np.diff(row[:ne]) > 0).all(), "slots not strictly ascending"
+
+
+@pytest.mark.parametrize("when", ["built", "flushed", "edge_sharded"])
+def test_window_slots_sorted_and_unique(graph, when):
+    sess = GraphSession.from_graph(
+        graph, 4, "cdbh", cfg=EngineConfig(edge_backend="pallas_windows"))
+    pg = sess.pg
+    if when == "flushed":
+        m = pg.emask[1]
+        gs = pg.gvid[1][pg.esrc[1][m]]
+        gd = pg.gvid[1][pg.edst[1][m]]
+        sess.update(adds=([0, 7, 11], [900 - 1, 3, 5], [1.5, 2.5, 3.5]),
+                    deletes=(gs[:4], gd[:4]))
+        sess.flush()
+    got, st = sess.query(SSSP(), {"source": 0}, warm=False)
+    assert st.edge_backend == "pallas_windows"
+    want, _ = sess.query(SSSP(), {"source": 0},
+                         cfg=EngineConfig(edge_backend="coo"), warm=False)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    lay = pg.edge_layouts
+    if when == "edge_sharded":
+        eslot = lay._sharded_geometry(pg, 2)["eslot"]
+        Se = lay.e_max // 2
+        for s in range(2):
+            _assert_slots_sorted_unique(eslot[:, s * Se:(s + 1) * Se])
+    else:
+        _assert_slots_sorted_unique(lay.eslot)
+
+
 # --------------------------------------------------------------------------- #
 # byte-accounted LRU (satellite: max_runner_bytes / max_warm_bytes)
 # --------------------------------------------------------------------------- #

@@ -1,10 +1,12 @@
 """Program spans and device scopes (``repro.obs``): the host spans of the
 stream flush and the session's query path nest as documented in a profile,
 the engine's superstep phases carry their named scopes into the lowered
-program (metadata only), and ``SessionStats.upload_bytes`` counts what a
-query puts on the device."""
+program (metadata only), the termination vote under ``drone_vote`` apart
+from the exchange, ``SessionStats.upload_bytes`` counts what a query puts
+on the device, and the query spans carry the exchanged bytes."""
 import glob
 import os
+import re
 import subprocess
 import sys
 
@@ -134,6 +136,7 @@ def test_sim_runner_carries_phase_scopes(graph, edge_backend):
 
 SHARD_SCRIPT = r"""
 import os
+import re
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax
 import numpy as np
@@ -205,3 +208,135 @@ def test_upload_bytes_counts_what_went_up(graph):
     assert sess.stats.upload_bytes - before == (
         _nbytes(sess._device) + _nbytes(sess.pg.edge_layouts.device_windows())
         + pg.n_parts * pg.v_max * 4)
+
+
+# --------------------------------------------------------------------------- #
+# exchange_bytes on the query spans; the termination vote's own scope
+# --------------------------------------------------------------------------- #
+def _span_stat(tdir, name, stat):
+    """``stat`` of every ``drone/<name>`` event of the profile in ``tdir``."""
+    path = glob.glob(os.path.join(tdir, "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    return [dict(e.stats).get(stat)
+            for plane in ProfileData.from_file(path).planes
+            for line in plane.lines for e in line.events
+            if e.name == SPAN_PREFIX + name]
+
+
+def test_query_spans_carry_exchange_bytes(graph, tmp_path):
+    sess = _session(graph)
+    sess.query(SSSP(), {"source": 0}, warm=False)
+    sess.query_batch(BFS(), [{"source": 0}, {"source": 2}])
+    with jax.profiler.trace(str(tmp_path)):
+        _, st = sess.query(SSSP(), {"source": 1}, warm=False)
+        lanes = sess.query_batch(BFS(), [{"source": 0}, {"source": 2}])
+    assert st.total_bytes > 0
+    assert _span_stat(str(tmp_path), "session/query", "exchange_bytes") \
+        == [st.total_bytes]
+    assert _span_stat(str(tmp_path), "session/query_batch",
+                      "exchange_bytes") == \
+        [sum(s.total_bytes for _, s in lanes)]
+
+
+SHARD_EXCHANGE_SCRIPT = r"""
+import glob, os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax
+import numpy as np
+from jax.profiler import ProfileData
+from repro.algos import SSSP
+from repro.compat import make_mesh
+from repro.core import EngineConfig
+from repro.graphgen import powerlaw_graph
+from repro.session import GraphSession
+
+g = powerlaw_graph(300, seed=4, weighted=True).as_undirected()
+sess = GraphSession.from_graph(g, 4, "cdbh", mesh=make_mesh((4,), ("sub",)))
+cfg = EngineConfig(subgraph_axes=("sub",))
+sess.query(SSSP(), {"source": 0}, warm=False, cfg=cfg)
+tdir = sys.argv[1]
+with jax.profiler.trace(tdir):
+    _, st = sess.query(SSSP(), {"source": 1}, warm=False, cfg=cfg)
+assert st.total_bytes > 0
+path = glob.glob(os.path.join(tdir, "**", "*.xplane.pb"), recursive=True)[0]
+got = [dict(e.stats).get("exchange_bytes")
+       for p in ProfileData.from_file(path).planes for l in p.lines
+       for e in l.events if e.name == "drone/session/query"]
+assert got == [st.total_bytes], (got, st.total_bytes)
+print("SHARD_EXCHANGE_BYTES_OK")
+"""
+
+
+def test_shard_map_query_span_carries_exchange_bytes(tmp_path):
+    res = subprocess.run([sys.executable, "-c", SHARD_EXCHANGE_SCRIPT,
+                          str(tmp_path)],
+                         capture_output=True, text=True, timeout=600)
+    assert res.returncode == 0, res.stderr[-4000:]
+    assert "SHARD_EXCHANGE_BYTES_OK" in res.stdout
+
+
+#: the vote's operations: the counts and, on shard_map, their all-reduce
+VOTE_OPS = {"reduce_sum", "psum"}
+
+
+def _scope_ops(text, scope):
+    """Primitives whose innermost ``drone_`` scope in the lowered text's
+    locations is ``drone_<scope>``."""
+    out = set()
+    for path in re.findall(r'loc\("([^"]*drone_[^"]*)"', text):
+        inner = path.rsplit("drone_", 1)[1]
+        if inner.split("/", 1)[0] == scope:
+            out.add(inner.rsplit("/", 1)[-1])
+    return out
+
+
+def test_sim_runner_scopes_the_vote_apart(graph):
+    pg = partition_and_build(graph, 4)
+    lowered = jax.jit(make_sim_runner(SSSP(), EngineConfig(),
+                                      pg.n_slots)).lower(
+        _device_subgraph(pg), {"source": np.int32(0)})
+    text = lowered.as_text(debug_info=True)
+    assert "reduce_sum" in _scope_ops(text, "vote")
+    assert not _scope_ops(text, "exchange") & VOTE_OPS
+    assert _scope_ops(text, "exchange")        # the combine stays there
+
+
+SHARD_VOTE_SCRIPT = r"""
+import os, re, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+sys.path.insert(0, os.environ["TESTS_DIR"])
+import jax
+import numpy as np
+from repro.algos import SSSP
+from repro.compat import make_mesh
+from repro.core import EngineConfig, partition_and_build
+from repro.core.engine import (_device_subgraph, make_bsp_runner,
+                               shard_placement)
+from repro.graphgen import powerlaw_graph
+from test_obs import VOTE_OPS, _scope_ops
+
+g = powerlaw_graph(300, seed=4, weighted=True).as_undirected()
+pg = partition_and_build(g, 4)
+mesh = make_mesh((4,), ("sub",))
+cfg = EngineConfig(subgraph_axes=("sub",), backend="shard_map")
+params = {"source": np.int32(0)}
+go = make_bsp_runner(SSSP(), mesh, cfg, pg.n_slots, params=params,
+                     params_as_input=True)
+sgs = _device_subgraph(pg, shard_placement(mesh, cfg))
+with mesh:
+    text = jax.jit(go).lower(sgs, params).as_text(debug_info=True)
+vote, exchange = _scope_ops(text, "vote"), _scope_ops(text, "exchange")
+assert VOTE_OPS <= vote, vote
+assert not exchange & VOTE_OPS, exchange
+assert "pmin" in exchange, exchange
+print("SHARD_VOTE_OK")
+"""
+
+
+def test_shard_map_runner_scopes_the_vote_apart():
+    env = dict(os.environ, TESTS_DIR=os.path.dirname(__file__))
+    res = subprocess.run([sys.executable, "-c", SHARD_VOTE_SCRIPT],
+                         capture_output=True, text=True, timeout=600,
+                         env=env)
+    assert res.returncode == 0, res.stderr[-4000:]
+    assert "SHARD_VOTE_OK" in res.stdout
