@@ -4,8 +4,10 @@ Label propagation: every vertex starts labelled with its own global id; one
 local sweep takes the min label over in-neighbours (the graph must be stored
 undirected, i.e. both edge directions present, so this is symmetric). The
 engine iterates sweeps to the partition-local fixed point — the vectorized
-equivalent of the paper's ``SequentialCC`` per subgraph — and SBS merges
-frontier labels with ``min`` (the paper's Aggregate operator for CC).
+equivalent of the paper's ``SequentialCC`` per subgraph; once a superstep
+on a partition without interior paths, ``engine.local_bound`` — and SBS
+merges frontier labels with ``min`` (the paper's Aggregate operator for
+CC).
 """
 from __future__ import annotations
 

@@ -5,7 +5,8 @@ hostile to a vector unit, so the TPU-native local solver is Bellman–Ford
 iterated to the partition-local fixed point (min-plus semiring sweeps) — the
 superstep/communication behaviour is identical to the paper's SC model
 (distances propagate arbitrarily far inside a partition per superstep), and
-the SBS Aggregate operator is ``min``, as in the paper.
+the SBS Aggregate operator is ``min``, as in the paper. A partition without
+interior paths sweeps once a superstep instead (``engine.local_bound``).
 
 Weights must be non-negative. Distances are float32.
 """

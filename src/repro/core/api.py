@@ -15,11 +15,14 @@ spec plus ``sweep_values``/``sweep_fold`` transforms instead of overriding
 (``coo_semiring_product``), and the engine can swap in a Pallas kernel
 backend (``EngineConfig.edge_backend``) without the program noticing.
 
-The engine (engine.py) iterates ``sweep`` to a local fixed point per superstep
-("think like a graph"; ``max_local_iters=1`` degrades to the vertex-centric
-baseline), performs SBS with the program's combiner, counts changed
-(key,value) pairs — the paper's network-message metric — and terminates when
-no partition emits changes (voteToHalt + no pending messages).
+The engine (engine.py) runs ``sweep`` each superstep: to a local fixed point
+("think like a graph") on a partition that holds interior paths, once on any
+other partition when the combiner is ``min``/``max`` (the exchange carries
+the paths there); ``mode="vc"`` or ``max_local_iters=1`` sweeps once
+everywhere, the vertex-centric baseline. It performs SBS with the program's
+combiner, counts changed (key,value) pairs — the paper's network-message
+metric — and terminates when no partition emits changes (voteToHalt + no
+pending messages).
 """
 from __future__ import annotations
 
@@ -54,6 +57,7 @@ class DeviceSubgraph(NamedTuple):
     out_deg: jnp.ndarray  # [v_max] f32 full out-degree
     in_deg: jnp.ndarray   # [v_max] f32 full in-degree
     is_master: jnp.ndarray  # [v_max] bool
+    local_paths: jnp.ndarray  # [] bool — most edges join interior vertices
     vlabel: Optional[jnp.ndarray] = None  # [v_max] int32
 
     @property

@@ -12,7 +12,15 @@ supersteps:
 
 ``mode='vc'`` bounds local iteration at one hop — the vertex-centric
 (Pregel/Giraph) baseline the paper compares against. ``mode='sc'`` iterates to
-the local fixed point — the subgraph-centric model. The partitioner choice
+the local fixed point — the subgraph-centric model — on a partition that
+holds interior paths (``PartitionedGraph.local_paths``: at least half of its
+real edges join two unreplicated vertices). For a ``min``/``max`` program it
+sweeps once a superstep on every other partition: there paths cross
+partitions, and after t supersteps a run that exchanges after every sweep
+holds values no worse than t lock-step local sweeps would, so the local fixed
+point's extra sweeps buy no superstep the exchange does not. ``local_bound``
+derives the bound, per partition, on device; ``sum`` programs (PageRank)
+keep the local fixed point everywhere. The partitioner choice
 (vertex-cut vs edge-cut) is orthogonal and lives in the PartitionedGraph,
 exactly the DRONE-VC / DRONE-EC split of §8.
 
@@ -77,6 +85,7 @@ from repro.obs import scope
 
 __all__ = ["EngineConfig", "EdgeCombine", "run", "run_sim", "run_shard_map",
            "make_sim_runner", "make_bsp_runner", "resolve_edge_backend",
+           "local_bound",
            "normalize_edge_backend", "resolve_partition_backends",
            "ShardSpecs", "shard_specs", "shard_placement"]
 
@@ -172,9 +181,24 @@ class EngineConfig:
                 raise ValueError(f"EngineConfig.{name} must be >= 0, got "
                                  f"{getattr(self, name)}")
 
-    @property
-    def local_bound(self) -> int:
-        return 1 if self.mode == "vc" else self.max_local_iters
+
+def local_bound(program: VertexProgram, cfg: EngineConfig, local_paths):
+    """Sweeps the local phase may run in a superstep, per partition: one
+    under ``mode='vc'``; for a ``min``/``max`` program under ``mode='sc'``,
+    ``cfg.max_local_iters`` where the partition holds interior paths
+    (``local_paths``) and one elsewhere; ``cfg.max_local_iters`` for any
+    other combiner, whose sweeps the monotone argument does not cover.
+
+    ``local_paths`` is the graph's per-partition flag: traced inside a
+    runner (a [P] vector, or a scalar per partition), so the bound is a
+    traced value and a flush that flips it recompiles nothing; or the
+    host's [P] array, for the session's ``one_sweep_share``."""
+    if cfg.mode == "vc":
+        return 1
+    if program.combiner not in ("min", "max"):
+        return cfg.max_local_iters
+    xp = np if isinstance(local_paths, np.ndarray) else jnp
+    return xp.where(local_paths, cfg.max_local_iters, 1)
 
 
 # --------------------------------------------------------------------------- #
@@ -204,7 +228,8 @@ def shard_specs(cfg: EngineConfig, has_vlabel: bool = False) -> ShardSpecs:
             esrc=edge_spec, edst=edge_spec, ew=edge_spec, emask=edge_spec,
             slot=vert_spec, vmask=vert_spec, vid32=vert_spec,
             is_frontier=vert_spec, out_deg=vert_spec, in_deg=vert_spec,
-            is_master=vert_spec, vlabel=vert_spec if has_vlabel else None),
+            is_master=vert_spec, local_paths=P(sub_axes),
+            vlabel=vert_spec if has_vlabel else None),
         tiles=TileBlock(tiles=P(sub_axes, e_ax, None, None),
                         tile_dst=P(sub_axes, e_ax),
                         tile_src=P(sub_axes, e_ax)),
@@ -235,7 +260,8 @@ def _device_subgraph(pg: PartitionedGraph,
         esrc=pg.esrc, edst=pg.edst, ew=pg.ew, emask=pg.emask, slot=pg.slot,
         vmask=pg.vmask, vid32=vid32.astype(np.int32),
         is_frontier=pg.is_frontier, out_deg=pg.out_deg, in_deg=pg.in_deg,
-        is_master=pg.is_master, vlabel=pg.vlabel)
+        is_master=pg.is_master, local_paths=pg.local_paths,
+        vlabel=pg.vlabel)
     if placement is None:
         return jax.tree.map(jnp.asarray, host)
     return jax.device_put(host, placement.graph)
@@ -574,15 +600,17 @@ def _mixed_product(program: VertexProgram, groups, sgs, lay_blks, v):
 
 
 def _local_phase(program: VertexProgram, sg: DeviceSubgraph, params, state,
-                 merged_v, ec: EdgeCombine, bound: int, first,
+                 merged_v, ec: EdgeCombine, cfg: EngineConfig, first,
                  sweep_fn=None):
-    """apply incoming -> sweep to local fixed point (or one hop).
+    """apply incoming -> sweep to local fixed point (or one hop: the
+    partition's ``local_bound``).
 
     ``first`` is True at superstep 0, where there are no incoming messages
     (paper Algorithm 1's ``if superstep = 0`` branch) and apply is skipped.
     ``sweep_fn`` overrides ``program.sweep`` (Pallas edge backends).
     """
     sweep = sweep_fn if sweep_fn is not None else program.sweep
+    bound = local_bound(program, cfg, sg.local_paths)
     with scope("apply"):
         state = jax.lax.cond(
             first, lambda st: st,
@@ -609,7 +637,7 @@ def _local_phase(program: VertexProgram, sg: DeviceSubgraph, params, state,
 
 
 def _batched_local_phase(program: VertexProgram, sgs, lay_blk, params, state,
-                         merged_v, ec: EdgeCombine, bound: int, first,
+                         merged_v, ec: EdgeCombine, cfg: EngineConfig, first,
                          edge_backend: str, groups=None):
     """Stacked-graph local phase for the simulator's Pallas (and mixed
     ``'auto'``) path.
@@ -620,8 +648,9 @@ def _batched_local_phase(program: VertexProgram, sgs, lay_blk, params, state,
     under a mixed assignment — and the while loop emulates vmap-of-while
     semantics by hand: a partition whose local fixed point is reached stops
     updating (its rows are select-frozen) while the others continue —
-    identical results, per-partition sweep counts, and straggler bound as
-    the vmapped COO path."""
+    identical results, per-partition sweep counts, and per-partition
+    ``local_bound`` as the vmapped COO path."""
+    bound = local_bound(program, cfg, sgs.local_paths)
     with scope("apply"):
         state = jax.lax.cond(
             first, lambda st: st,
@@ -777,12 +806,12 @@ def _make_sim_superstep(program: VertexProgram, cfg: EngineConfig,
         if edge_backend == "coo":
             state, out, sweeps, last_ch = jax.vmap(
                 lambda sg, st, m: _local_phase(program, sg, params, st, m, ec,
-                                               cfg.local_bound, first)
+                                               cfg, first)
             )(sgs, state, merged_v)
         else:
             state, out, sweeps, last_ch = _batched_local_phase(
-                program, sgs, lay, params, state, merged_v, ec,
-                cfg.local_bound, first, edge_backend, groups)
+                program, sgs, lay, params, state, merged_v, ec, cfg, first,
+                edge_backend, groups)
         with scope("pack"):
             bufs, changed = jax.vmap(
                 lambda sg, o, lo: _pack(program, sg, o, lo, n_slots)
@@ -1180,8 +1209,8 @@ def make_bsp_runner(program: VertexProgram, mesh: Mesh,
 
         def superstep(state, last_out, merged_v, first):
             state, out, sweeps, last_ch = _local_phase(
-                program, sg, params, state, merged_v, ec, cfg.local_bound,
-                first, sweep_fn=sweep_fn)
+                program, sg, params, state, merged_v, ec, cfg, first,
+                sweep_fn=sweep_fn)
             ref = merged_v if cfg.lean_frontier else last_out
             with scope("pack"):
                 changed = program.changed_mask(out, ref) & sg.frontier

@@ -29,6 +29,11 @@ without ever materializing the global edge list:
   - ``recompute_frontier``       — re-derive slots/masters in place after a
                                    membership patch (stream/delta.py).
 
+Wherever ``is_frontier`` is derived, so is ``local_paths``: per partition,
+whether at least half of its real edges join two interior (unreplicated)
+vertices, i.e. whether it holds paths that a local fixed point walks without
+an exchange. The engine bounds the local phase by it (engine.local_bound).
+
 Padded capacities (``v_max``/``e_max``) are chosen by a ``ShapePolicy``.
 The default everywhere in this low-level layer is the exact policy (round
 the content maximum up to ``pad_multiple`` — the historical behavior, and
@@ -50,7 +55,7 @@ from repro.core.partition import route_vertices_rh
 
 __all__ = ["PartitionedGraph", "ShapePolicy", "build_partitioned_graph",
            "frontier_election", "assemble_partitioned_graph",
-           "partition_vertex_sets", "recompute_frontier",
+           "partition_vertex_sets", "recompute_frontier", "local_paths_of",
            "repack_partitions", "localize_edges"]
 
 
@@ -154,6 +159,30 @@ def _pad_to(arr: np.ndarray, n: int, fill) -> np.ndarray:
     return out
 
 
+def local_paths_of(pg: "PartitionedGraph") -> np.ndarray:
+    """[P] bool: whether at least half of each partition's real edges join
+    two interior (not replicated) vertices. Such a partition holds paths a
+    local fixed point walks in one superstep; elsewhere (a random
+    vertex-cut leaves almost no interior edge) paths cross partitions and
+    the exchange, not the local phase, carries them. An edge-less
+    partition has none.
+
+    Every edge of an interior vertex lies in its one partition, so the
+    full degrees of the interior vertices bound the interior edges from
+    above without touching an edge: only a partition that bound leaves
+    open pays the per-edge count (a flush runs this on every partition)."""
+    interior = pg.vmask & ~pg.is_frontier
+    real = pg.emask.sum(axis=1)
+    into = np.where(interior, pg.in_deg, 0).sum(axis=1, dtype=np.float64)
+    outof = np.where(interior, pg.out_deg, 0).sum(axis=1, dtype=np.float64)
+    out = np.zeros(pg.n_parts, bool)
+    for p in np.nonzero((real > 0) & (2 * np.minimum(into, outof) >= real))[0]:
+        m = pg.emask[p]
+        both = interior[p][pg.esrc[p][m]] & interior[p][pg.edst[p][m]]
+        out[p] = 2 * int(both.sum()) >= real[p]
+    return out
+
+
 def localize_edges(lv: np.ndarray, gs: np.ndarray, gd: np.ndarray, w):
     """Global-id edges -> local int32 indices against the sorted membership
     ``lv``, stably sorted by destination (segment ops expect ascending dst).
@@ -194,6 +223,9 @@ class PartitionedGraph:
     is_master: np.ndarray  # [P, v_max] bool
 
     frontier_gvid: np.ndarray  # [n_slots] int64
+    # [P] bool — most real edges join interior vertices; derived here
+    # (local_paths_of) unless given, and again by recompute_frontier
+    local_paths: Optional[np.ndarray] = None
     edge_part: Optional[np.ndarray] = None  # [E] int32 host-side assignment
     vlabel: Optional[np.ndarray] = None     # [P, v_max] int32 (gsim labels)
     # Stacked tile/window decompositions for the Pallas edge-compute
@@ -201,6 +233,10 @@ class PartitionedGraph:
     # ensure_edge_layouts (or eagerly at assembly), kept incrementally
     # fresh by stream/delta.py, rebuilt by repack_partitions.
     edge_layouts: Optional[object] = None
+
+    def __post_init__(self):
+        if self.local_paths is None:
+            self.local_paths = local_paths_of(self)
 
     # ------------------------------------------------------------------ #
     @property
@@ -216,7 +252,7 @@ class PartitionedGraph:
         d = dict(esrc=self.esrc, edst=self.edst, ew=self.ew, emask=self.emask,
                  slot=self.slot, vmask=self.vmask, is_frontier=self.is_frontier,
                  out_deg=self.out_deg, in_deg=self.in_deg,
-                 is_master=self.is_master)
+                 is_master=self.is_master, local_paths=self.local_paths)
         if self.vlabel is not None:
             d["vlabel"] = self.vlabel
         return d
@@ -535,7 +571,8 @@ def repack_partitions(pg: PartitionedGraph,
 # --------------------------------------------------------------------------- #
 def recompute_frontier(pg: PartitionedGraph) -> None:
     """Re-derive ``slot``/``is_frontier``/``is_master``/``frontier_gvid``
-    in place from the current ``gvid``/``vmask`` membership. Uses the same
+    in place from the current ``gvid``/``vmask`` membership, and
+    ``local_paths`` from them and the current edges. Uses the same
     hash election as the builders, so an unchanged membership round-trips
     bit-identically; a patched membership gets consistent fresh slots."""
     part_vertices = [pg.gvid[p][pg.vmask[p]] for p in range(pg.n_parts)]
@@ -551,3 +588,4 @@ def recompute_frontier(pg: PartitionedGraph) -> None:
     pg.n_slots = n_slots
     pg.frontier_gvid = frontier_gvid
     pg.is_frontier = (pg.slot < n_slots) & pg.vmask
+    pg.local_paths = local_paths_of(pg)

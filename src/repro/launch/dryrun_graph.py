@@ -87,7 +87,11 @@ def _sds_subgraph(meta, n_parts, mesh, sub_axes, edge_axes):
         esrc=E(jnp.int32), edst=E(jnp.int32), ew=E(jnp.float32),
         emask=E(jnp.bool_), slot=V(jnp.int32), vmask=V(jnp.bool_),
         vid32=V(jnp.int32), is_frontier=V(jnp.bool_), out_deg=V(jnp.float32),
-        in_deg=V(jnp.float32), is_master=V(jnp.bool_), vlabel=None)
+        in_deg=V(jnp.float32), is_master=V(jnp.bool_),
+        local_paths=jax.ShapeDtypeStruct(
+            (n_parts,), jnp.bool_,
+            sharding=NamedSharding(mesh, P(sub_axes))),
+        vlabel=None)
 
 
 def lower_graph_cell(scale_name: str, algo: str, multi_pod: bool,

@@ -96,7 +96,7 @@ from repro.core.engine import (EngineConfig, _auto_layout_blocks,
                                _device_subgraph,
                                _exchange_bytes_per_step, _flops_per_sweep,
                                _layout_block_from, _warm_block,
-                               make_bsp_runner, make_sim_runner,
+                               local_bound, make_bsp_runner, make_sim_runner,
                                normalize_edge_backend,
                                resolve_partition_backends, run_sim,
                                shard_placement)
@@ -584,14 +584,18 @@ class GraphSession:
         The call is the span ``session/query``; its ``upload_bytes`` is what
         the query added to ``SessionStats.upload_bytes``, its
         ``pallas_edge_share`` the share of resident edges in partitions
-        whose edge backend is a Pallas kernel, and its ``exchange_bytes``
-        the query's ``ExecutionStats.total_bytes``.
+        whose edge backend is a Pallas kernel, its ``one_sweep_share`` the
+        share in partitions whose local phase sweeps once a superstep
+        (``engine.local_bound``), and its ``exchange_bytes`` the query's
+        ``ExecutionStats.total_bytes``.
         """
         with span("session/query") as sp:
             before = self.stats.upload_bytes
             out = self._query(program, params, warm, cfg, use_result_cache)
             sp.set_metadata(upload_bytes=self.stats.upload_bytes - before,
                             pallas_edge_share=self._pallas_edge_share(out[1]),
+                            one_sweep_share=self._one_sweep_share(
+                                program, cfg or self.cfg),
                             exchange_bytes=out[1].total_bytes)
         return out
 
@@ -717,8 +721,9 @@ class GraphSession:
         are simply recomputed; their entries refresh).
 
         The call is the span ``session/query_batch``, with the children of
-        ``query``'s span and its ``upload_bytes``, ``pallas_edge_share``
-        and ``exchange_bytes`` (summed over the lanes)."""
+        ``query``'s span and its ``upload_bytes``, ``pallas_edge_share``,
+        ``one_sweep_share`` and ``exchange_bytes`` (summed over the
+        lanes)."""
         with span("session/query_batch") as sp:
             before = self.stats.upload_bytes
             out = self._query_batch(program, params_list, warm, cfg,
@@ -727,6 +732,8 @@ class GraphSession:
             if out:
                 meta["pallas_edge_share"] = self._pallas_edge_share(
                     out[0][1])
+                meta["one_sweep_share"] = self._one_sweep_share(
+                    program, cfg or self.cfg)
                 meta["exchange_bytes"] = sum(st.total_bytes
                                              for _, st in out)
             sp.set_metadata(**meta)
@@ -887,6 +894,16 @@ class GraphSession:
         if kernel.shape != epp.shape or epp.sum() == 0:
             return 0.0
         return float(epp[kernel].sum() / epp.sum())
+
+    def _one_sweep_share(self, program: VertexProgram,
+                         cfg: EngineConfig) -> float:
+        """Share of the resident edges in partitions whose local phase
+        ``local_bound`` holds to one sweep a superstep for ``program``."""
+        epp = self.pg.edges_per_part.astype(np.float64)
+        one = np.broadcast_to(
+            np.asarray(local_bound(program, cfg, self.pg.local_paths)) == 1,
+            epp.shape)
+        return float(epp[one].sum() / epp.sum()) if epp.sum() else 0.0
 
     def _n_edge_shards(self, cfg) -> int:
         if cfg.backend != "shard_map" or not cfg.edge_axes \

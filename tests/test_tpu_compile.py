@@ -171,3 +171,36 @@ def test_window_product_scatter_sorts_nothing(one_chip):
     text = compiled.as_text()
     assert " scatter(" in text
     assert " sort(" not in text
+
+
+def test_shard_runner_compiles_on_four_chips(one_chip):
+    """The served shard_map runner on a described 2x2 v5e, one partition a
+    chip, as the four-chip cell runs it (``pallas_windows``, warm input,
+    traced params): the per-partition local bound and the termination vote
+    lower to one program per chip with its all-reduces."""
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import make_bsp_runner
+    from repro.core.engine import shard_specs
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices), ("sub",))
+    g = kronecker_graph(10, seed=0, weighted=True)
+    sess = GraphSession.from_graph(g, n_parts=4, partitioner="cdbh")
+    program, params = SSSP(), canonical_params({"source": 0})
+    eb, cfg = normalize_edge_backend(program, EngineConfig(
+        backend="shard_map", edge_backend="pallas_windows"))
+    specs = shard_specs(cfg)
+    args = (sess.device_graph(), sess._layout_arg(program, eb, cfg),
+            sess._warm_arg(program, None, False, cfg), params)
+    places = (specs.graph, specs.windows, specs.warm,
+              jax.tree.map(lambda _: P(), params))
+    sds = jax.tree.map(lambda x, sp: _spec(x, NamedSharding(mesh, sp)),
+                       args, places, is_leaf=lambda x: isinstance(x, P))
+    runner = make_bsp_runner(program, mesh, cfg, sess.slot_capacity,
+                             params=params, warm_start=True,
+                             params_as_input=True)
+    compiled = jax.jit(runner).lower(*sds).compile()
+    assert _has_kernel(compiled)
+    assert "all-reduce" in compiled.as_text()
