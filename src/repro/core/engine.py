@@ -24,13 +24,18 @@ keep the local fixed point everywhere. The partitioner choice
 (vertex-cut vs edge-cut) is orthogonal and lives in the PartitionedGraph,
 exactly the DRONE-VC / DRONE-EC split of §8.
 
-Backends:
-  - ``sim``       — single-process: [P, ...] stacked arrays, vmapped local
-    phase, SBS = axis-0 reductions. Used by tests/benchmarks on CPU.
-  - ``shard_map`` — production: partitions on the (pod, data) mesh axes, the
-    model axis shards each partition's *edges* (hierarchical SVHM,
-    DESIGN.md §2); SBS = lax.pmin/psum over (pod, data), intra-partition
+Backends — one superstep and one loop (``_make_superstep``, ``_make_loop``)
+over a stacked local block [p, ...], run by both:
+  - ``sim``       — single-process: the block holds all P partitions, the
+    local phase is vmapped (or one kernel launch over the stack), SBS =
+    axis-0 reductions (``sbs.SimExchange``). Used by tests/benchmarks on CPU.
+  - ``shard_map`` — production: the block holds one partition a device,
+    partitions on the (pod, data) mesh axes, the model axis shards each
+    partition's *edges* (hierarchical SVHM, DESIGN.md §2); SBS =
+    lax.pmin/psum over (pod, data) (``sbs.ShardExchange``), intra-partition
     edge-combine = collectives over (model,).
+Both runners take ``(sgs[, lay], params[, warm])``; ``execution_stats`` bills
+every run.
 
 This module is the **low-level one-shot layer**: ``run``/``run_sim``/
 ``run_shard_map`` build a fresh runner, upload the graph and execute a single
@@ -65,7 +70,7 @@ import dataclasses
 import os
 import time
 from functools import partial
-from typing import Any, NamedTuple, Optional, Sequence
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -85,7 +90,7 @@ from repro.obs import scope
 
 __all__ = ["EngineConfig", "EdgeCombine", "run", "run_sim", "run_shard_map",
            "make_sim_runner", "make_bsp_runner", "resolve_edge_backend",
-           "local_bound",
+           "local_bound", "execution_stats",
            "normalize_edge_backend", "resolve_partition_backends",
            "ShardSpecs", "shard_specs", "shard_placement"]
 
@@ -323,7 +328,7 @@ def normalize_edge_backend(program: VertexProgram,
     return eb, cfg
 
 
-#: lax.switch branch ids of the shard_map mixed-backend sweep
+#: lax.switch branch ids of the shard_map mixed-backend product
 _BACKEND_IDS = {"coo": 0, "pallas_tiles": 1, "pallas_windows": 2}
 
 
@@ -428,30 +433,6 @@ def _window_product(blk: WindowBlock, vals, spec: SemiringSweep, v_max: int,
     out = segment_combine_windowed(buf, blk.ldst, blk.bwin, n_windows=nw,
                                    combiner=spec.combiner)
     return out.reshape(nw * W, K)[:v_max]
-
-
-def _make_pallas_sweep(program: VertexProgram, edge_backend: str):
-    """Per-partition sweep closure for the shard_map body (and the
-    superstep of ``_batched_local_phase``): pre-transform -> kernel product
-    -> edge-combine -> fold, exactly the shape of the base-class COO sweep
-    in api.py."""
-    spec = program.sweep_spec
-
-    def sweep(sg: DeviceSubgraph, lay_blk, params, state, ec: EdgeCombine):
-        vals = program.sweep_values(sg, params, state)
-        squeeze = vals.ndim == sg.vmask.ndim           # [.., v_max] -> K=1
-        v = vals[..., None] if squeeze else vals
-        v_max = sg.vmask.shape[-1]
-        if edge_backend == "pallas_tiles":
-            agg = _tile_product(lay_blk, v, spec, v_max)
-        else:
-            agg = _window_product(lay_blk, v, spec, v_max, sg.esrc, sg.ew)
-        agg = ec.min(agg) if spec.semiring == "min_plus" else ec.sum(agg)
-        if squeeze:
-            agg = agg[..., 0]
-        return program.sweep_fold(sg, params, state, agg)
-
-    return sweep
 
 
 def _layout_block_from(lay: EdgeLayouts, pg: PartitionedGraph,
@@ -569,21 +550,37 @@ def _auto_layout_blocks(lay: EdgeLayouts, pg: PartitionedGraph,
     return blk
 
 
+def _each(f, *stacks):
+    """``f`` over the leading partition axis of ``stacks``: ``vmap``'d over
+    a stack of several partitions; on a stack of one (a shard_map device's
+    block) called on that partition itself, so a device lowers the
+    per-partition program and no batched form of it."""
+    if jax.tree.leaves(stacks)[0].shape[0] > 1:
+        return jax.vmap(f)(*stacks)
+    out = f(*jax.tree.map(lambda x: x[0], stacks))
+    return jax.tree.map(lambda x: x[None], out)
+
+
 def _stack_product(backend: str, spec: SemiringSweep, sgs, lay_blk, v):
     """Stacked [P, v_max, K] semiring product on one backend: the vmapped
     COO reference product, or the flattened kernel launch over the stacked
-    layout block (``lax.map``'d per partition for windows)."""
+    layout block (``lax.map``'d per partition for windows). A stack of one
+    runs its partition's own product."""
     from repro.core.api import coo_semiring_product
     v_max = sgs.vmask.shape[-1]
     if backend == "coo":
-        return jax.vmap(
-            lambda sg, vv: coo_semiring_product(sg, spec, vv))(sgs, v)
+        return _each(lambda sg, vv: coo_semiring_product(sg, spec, vv),
+                     sgs, v)
+    if v.shape[0] == 1:
+        sgs, lay_blk, v = jax.tree.map(lambda x: x[0], (sgs, lay_blk, v))
     if backend == "pallas_tiles":
-        return _tile_product(lay_blk, v, spec, v_max)
-    return _window_product(lay_blk, v, spec, v_max, sgs.esrc, sgs.ew)
+        agg = _tile_product(lay_blk, v, spec, v_max)
+    else:
+        agg = _window_product(lay_blk, v, spec, v_max, sgs.esrc, sgs.ew)
+    return agg if agg.ndim == 3 else agg[None]
 
 
-def _mixed_product(program: VertexProgram, groups, sgs, lay_blks, v):
+def _mixed_product(spec: SemiringSweep, groups, sgs, lay_blks, v):
     """Stacked [P, v_max, K] semiring product under a mixed per-partition
     backend assignment: one launch per backend group over its (static)
     partition sub-stack, scattered back into the full aggregate. Matches
@@ -593,23 +590,30 @@ def _mixed_product(program: VertexProgram, groups, sgs, lay_blks, v):
     agg = jnp.zeros(v.shape, v.dtype)       # every row overwritten below
     for backend, gidx in groups:
         sub = jax.tree.map(lambda a: a[gidx], sgs)
-        part = _stack_product(backend, program.sweep_spec, sub,
-                              blks[backend], v[gidx])
+        part = _stack_product(backend, spec, sub, blks[backend], v[gidx])
         agg = agg.at[jnp.asarray(gidx)].set(part)
     return agg
 
 
+def _switch_product(spec: SemiringSweep, sgs, lay_blks, v):
+    """The product of a shard_map device's one partition under a mixed
+    assignment: a ``lax.switch`` on its backend id (``_BACKEND_IDS``) over
+    the three products (``_auto_layout_blocks``' ``mixed_shard`` input)."""
+    tiles, windows, bid = lay_blks
+    blks = {"coo": None, "pallas_tiles": tiles, "pallas_windows": windows}
+    return jax.lax.switch(
+        bid[0], [partial(_stack_product, b, spec, sgs, blks[b])
+                 for b in _BACKEND_IDS], v)
+
+
 def _local_phase(program: VertexProgram, sg: DeviceSubgraph, params, state,
-                 merged_v, ec: EdgeCombine, cfg: EngineConfig, first,
-                 sweep_fn=None):
+                 merged_v, ec: EdgeCombine, cfg: EngineConfig, first):
     """apply incoming -> sweep to local fixed point (or one hop: the
-    partition's ``local_bound``).
+    partition's ``local_bound``), one partition, ``program.sweep``.
 
     ``first`` is True at superstep 0, where there are no incoming messages
     (paper Algorithm 1's ``if superstep = 0`` branch) and apply is skipped.
-    ``sweep_fn`` overrides ``program.sweep`` (Pallas edge backends).
     """
-    sweep = sweep_fn if sweep_fn is not None else program.sweep
     bound = local_bound(program, cfg, sg.local_paths)
     with scope("apply"):
         state = jax.lax.cond(
@@ -624,11 +628,11 @@ def _local_phase(program: VertexProgram, sg: DeviceSubgraph, params, state,
 
     def body(c):
         i, st, _ = c
-        st, chg = sweep(sg, params, st, ec)
+        st, chg = program.sweep(sg, params, st, ec)
         return (i + 1, st, chg)
 
     with scope("sweep"):
-        state, ch = sweep(sg, params, state, ec)
+        state, ch = program.sweep(sg, params, state, ec)
         i, state, last_ch = jax.lax.while_loop(cond, body,
                                                (jnp.int32(1), state, ch))
     with scope("pack"):
@@ -638,45 +642,42 @@ def _local_phase(program: VertexProgram, sg: DeviceSubgraph, params, state,
 
 def _batched_local_phase(program: VertexProgram, sgs, lay_blk, params, state,
                          merged_v, ec: EdgeCombine, cfg: EngineConfig, first,
-                         edge_backend: str, groups=None):
-    """Stacked-graph local phase for the simulator's Pallas (and mixed
-    ``'auto'``) path.
+                         product):
+    """Stacked local phase of a ``SemiringSweep`` program on the kernels:
+    ``product(sgs, lay_blk, v)`` evaluates the semiring product of the
+    whole stack (``_stack_product``, ``_mixed_product`` or
+    ``_switch_product``), then the ``EdgeCombine`` epilogue merges the
+    edge shards' partial aggregates (the identity without edge axes).
 
     The vmapped ``_local_phase`` cannot host a Pallas call (the batching
     rule would have to lift the kernel); instead the whole [P, ...] stack
-    goes through ONE flattened kernel launch per sweep — per backend group
-    under a mixed assignment — and the while loop emulates vmap-of-while
-    semantics by hand: a partition whose local fixed point is reached stops
-    updating (its rows are select-frozen) while the others continue —
-    identical results, per-partition sweep counts, and per-partition
-    ``local_bound`` as the vmapped COO path."""
+    goes through ONE product per sweep and the while loop emulates
+    vmap-of-while semantics by hand: a partition whose local fixed point is
+    reached stops updating (its rows are select-frozen) while the others
+    continue — identical results, per-partition sweep counts, and
+    per-partition ``local_bound`` as the vmapped COO path."""
+    spec = program.sweep_spec
     bound = local_bound(program, cfg, sgs.local_paths)
     with scope("apply"):
         state = jax.lax.cond(
             first, lambda st: st,
-            lambda st: jax.vmap(
+            lambda st: _each(
                 lambda sg, s, m: program.apply_frontier(sg, params, s, m,
-                                                        ec)[0]
-            )(sgs, st, merged_v), state)
+                                                        ec)[0],
+                sgs, st, merged_v), state)
 
     def sweep_all(st):
-        vals = jax.vmap(
-            lambda sg, s: program.sweep_values(sg, params, s))(sgs, st)
+        vals = _each(lambda sg, s: program.sweep_values(sg, params, s),
+                     sgs, st)
         squeeze = vals.ndim == 2
-        v = vals[..., None] if squeeze else vals
-        if edge_backend == "auto":
-            agg = _mixed_product(program, groups, sgs, lay_blk, v)
-        else:
-            agg = _stack_product(edge_backend, program.sweep_spec, sgs,
-                                 lay_blk, v)
+        agg = product(sgs, lay_blk, vals[..., None] if squeeze else vals)
+        agg = ec.min(agg) if spec.semiring == "min_plus" else ec.sum(agg)
         if squeeze:
             agg = agg[..., 0]
-        return jax.vmap(
-            lambda sg, s, a: program.sweep_fold(sg, params, s, a)
-        )(sgs, st, agg)
+        return _each(lambda sg, s, a: program.sweep_fold(sg, params, s, a),
+                     sgs, st, agg)
 
     n_parts = sgs.vmask.shape[0]
-    i0 = jnp.ones((n_parts,), jnp.int32)
 
     def cond(c):
         i, _, chg = c
@@ -684,8 +685,10 @@ def _batched_local_phase(program: VertexProgram, sgs, lay_blk, params, state,
 
     def body(c):
         i, st, chg = c
-        live = (chg > 0) & (i < bound)
         st2, ch2 = sweep_all(st)
+        if n_parts == 1:           # the loop runs only while it is live
+            return i + 1, st2, ch2
+        live = (chg > 0) & (i < bound)
         st = jax.tree.map(
             lambda a, b: jnp.where(live.reshape((-1,) + (1,) * (b.ndim - 1)),
                                    b, a), st, st2)
@@ -693,19 +696,239 @@ def _batched_local_phase(program: VertexProgram, sgs, lay_blk, params, state,
 
     with scope("sweep"):
         state, ch = sweep_all(state)
+        i0 = jnp.ones((n_parts,), jnp.int32)
         i, state, last_ch = jax.lax.while_loop(cond, body, (i0, state, ch))
     with scope("pack"):
-        out = jax.vmap(
-            lambda sg, s: program.frontier_out(sg, params, s))(sgs, state)
+        out = _each(lambda sg, s: program.frontier_out(sg, params, s),
+                    sgs, state)
     return state, out, i, last_ch
 
 
-def _pack(program: VertexProgram, sg: DeviceSubgraph, out, last_out,
-          n_slots: int):
-    changed = program.changed_mask(out, last_out) & sg.frontier
-    buf = sbs.scatter_combine(out, sg.slot, changed, n_slots,
-                              program.combiner, program.identity)
-    return buf, changed
+def _exchange_dense(program: VertexProgram, ex, n_slots: int, capacity: int,
+                    sgs, out, changed):
+    """The SBS exchange over the dense ``[n_slots + 1, K]`` slot buffer:
+    each partition scatters its changed frontier values into its buffer,
+    ``ex`` combines the stack (an all-reduce over the subgraph axes under
+    shard_map), and each partition gathers its merged view back. With a
+    ``capacity`` (shard_map's ``sparse_sync_capacity``) the combine is the
+    compacted all-gather of ``sbs.compact_allgather_exchange``."""
+    comb, ident = program.combiner, program.identity
+    with scope("pack"):
+        bufs = _each(lambda sg, o, c: sbs.scatter_combine(
+            o, sg.slot, c, n_slots, comb, ident), sgs, out, changed)
+    with scope("exchange"):
+        if capacity > 0:
+            merged = sbs.compact_allgather_exchange(
+                sbs.reduce_stack(bufs, comb), ident, comb, n_slots,
+                capacity, ex.axis_names)
+        else:
+            merged = ex.combine_stack(bufs, comb)
+        merged = merged.at[n_slots].set(ident)
+    with scope("apply"):
+        return _each(lambda sg: sbs.gather_merged(merged, sg.slot), sgs)
+
+
+def _exchange_sharded_slots(program: VertexProgram, ex, ec: EdgeCombine,
+                            n_slots: int, n_edge_shards: int, sgs, out,
+                            changed):
+    """Sharded SBS (``cfg.shard_slots``, DESIGN.md §7): frontier slots are
+    owned by the edge-axis shard ``slot % n_edge_shards``; the subgraph-axes
+    all-reduce runs on the 1/n_edge_shards slot slice, and the per-vertex
+    merged view is rebuilt with an edge-axis combine — O(n_slots /
+    n_edge_shards) state per device, which is what keeps the trillion-edge
+    configuration within HBM."""
+    comb, ident = program.combiner, program.identity
+    n_loc = -(-(n_slots + 1) // n_edge_shards)
+    rank = jax.lax.axis_index(ec.axis_names)
+
+    def pack(sg, o, c):
+        owned = c & (sg.slot % n_edge_shards == rank)
+        slot_loc = jnp.where(owned, sg.slot // n_edge_shards, n_loc)
+        return sbs.scatter_combine(o, slot_loc, owned, n_loc, comb, ident)
+
+    def view(sg, merged):
+        gather_own = sg.frontier & (sg.slot % n_edge_shards == rank)
+        mv = jnp.where(gather_own[:, None],
+                       merged[jnp.clip(sg.slot // n_edge_shards, 0, n_loc)],
+                       ident)
+        if comb == "min":
+            return ec.min(mv)
+        if comb == "max":
+            return ec.max(mv)
+        return ec.sum(jnp.where(gather_own[:, None], mv, 0).astype(mv.dtype))
+
+    with scope("pack"):
+        bufs = _each(pack, sgs, out, changed)
+    with scope("exchange"):
+        merged = ex.combine_stack(bufs, comb)
+        return _each(lambda sg: view(sg, merged), sgs)
+
+
+def _n_edge_shards(mesh: Mesh, cfg: EngineConfig) -> int:
+    return int(np.prod([mesh.shape[a] for a in cfg.edge_axes])) \
+        if cfg.edge_axes else 1
+
+
+def _make_superstep(program: VertexProgram, cfg: EngineConfig, n_slots: int,
+                    assignment=None, mesh: Optional[Mesh] = None):
+    """One BSP superstep over a stacked local block [p, ...] — all P
+    partitions in the simulator (``mesh=None``), one partition a device
+    under shard_map — and the ``EdgeCombine`` it runs with:
+
+        superstep(sgs, lay, params, state, last_out, merged_v, first) ->
+            (state, out, merged_v, messages, active partitions, sweeps[p])
+
+    Chosen once here: the local phase (``_local_phase`` through ``_each``
+    for ``coo`` and hand-rolled sweeps; ``_batched_local_phase`` around the
+    product of the kernel backends, per ``_effective_backend``), the
+    exchange (``sbs.SimExchange`` reduces the stack; ``sbs.ShardExchange``
+    reduces it and all-reduces over the subgraph axes) and its variant
+    (dense; ``cfg.sparse_sync_capacity``'s compacted all-gather and
+    ``cfg.shard_slots``' sharded slots, both shard_map only).
+    ``cfg.lean_frontier`` compares the changed mask against ``merged_v``
+    rather than ``last_out``."""
+    edge_backend = _effective_backend(resolve_edge_backend(program, cfg),
+                                      assignment)
+    if mesh is None:
+        ex, ec, n_edge = sbs.SimExchange(), EdgeCombine(()), 1
+    else:
+        ex = sbs.ShardExchange(tuple(cfg.subgraph_axes))
+        ec = EdgeCombine(tuple(cfg.edge_axes))
+        n_edge = _n_edge_shards(mesh, cfg)
+
+    if edge_backend == "coo":
+        def local_phase(sgs, lay, params, state, merged_v, first):
+            return _each(lambda sg, st, m: _local_phase(
+                program, sg, params, st, m, ec, cfg, first),
+                sgs, state, merged_v)
+    else:
+        spec = program.sweep_spec
+        if edge_backend != "auto":
+            product = partial(_stack_product, edge_backend, spec)
+        elif mesh is not None:
+            product = partial(_switch_product, spec)
+        else:
+            product = partial(_mixed_product, spec,
+                              _assignment_groups(assignment))
+
+        def local_phase(sgs, lay, params, state, merged_v, first):
+            return _batched_local_phase(program, sgs, lay, params, state,
+                                        merged_v, ec, cfg, first, product)
+
+    if mesh is not None and cfg.shard_slots and n_edge > 1:
+        exchange = partial(_exchange_sharded_slots, program, ex, ec, n_slots,
+                           n_edge)
+    else:
+        exchange = partial(_exchange_dense, program, ex, n_slots,
+                           cfg.sparse_sync_capacity if mesh is not None
+                           else 0)
+
+    def superstep(sgs, lay, params, state, last_out, merged_v, first):
+        state, out, sweeps, last_ch = local_phase(sgs, lay, params, state,
+                                                  merged_v, first)
+        ref = merged_v if cfg.lean_frontier else last_out
+        with scope("pack"):
+            changed = _each(
+                lambda sg, o, r: program.changed_mask(o, r) & sg.frontier,
+                sgs, out, ref)
+        merged_v = exchange(sgs, out, changed)
+        with scope("vote"):
+            msgs = ex.all_sum_scalar(jnp.sum(changed, axis=-1,
+                                             dtype=jnp.int32))
+            active = ex.all_sum_scalar((last_ch > 0).astype(jnp.int32))
+        return state, out, merged_v, msgs, active, sweeps
+
+    return superstep, ec
+
+
+def _loop_init(program: VertexProgram, ec: EdgeCombine, sgs, params, warm):
+    """Initial state of a stacked block (``warm`` folded in through
+    ``program.warm_init`` when given) and the identity-filled [p, v_max, K]
+    block that starts ``last_out`` and ``merged_v``."""
+    with scope("init"):
+        state = _each(lambda sg: program.init(sg, params, ec), sgs)
+        if warm is not None:
+            state = _each(
+                lambda sg, st, w: program.warm_init(sg, params, st, w),
+                sgs, state, warm)
+        fill = jnp.full(sgs.vmask.shape + (program.payload,),
+                        program.identity, dtype=program.dtype)
+    return state, fill
+
+
+def _make_loop(program: VertexProgram, cfg: EngineConfig, n_slots: int,
+               assignment=None, mesh: Optional[Mesh] = None):
+    """The BSP loop over a stacked local block, init -> ``while_loop`` of
+    ``_make_superstep``'s superstep -> result:
+
+        run(sgs, lay, params, warm) ->
+            (results[p, ...], supersteps, total_messages, sweeps[p])
+
+    It halts once no partition changed anything and no message is pending
+    (or at ``cfg.max_supersteps``). Under ``cfg.lean_frontier`` the carry's
+    ``last_out`` is ``None``."""
+    superstep, ec = _make_superstep(program, cfg, n_slots, assignment, mesh)
+
+    def run(sgs, lay, params, warm):
+        state, fill = _loop_init(program, ec, sgs, params, warm)
+
+        def cond(c):
+            step, msgs, active = c[0], c[-2], c[-1]
+            return (step == 0) | (((msgs > 0) | (active > 0))
+                                  & (step < cfg.max_supersteps))
+
+        def body(c):
+            step, state, last_out, merged_v, tot_msgs, tot_sweeps, _, _ = c
+            state, out, merged_v, msgs, active, sweeps = superstep(
+                sgs, lay, params, state, last_out, merged_v, step == 0)
+            return (step + 1, state, None if cfg.lean_frontier else out,
+                    merged_v, tot_msgs + msgs, tot_sweeps + sweeps, msgs,
+                    active)
+
+        carry = (jnp.int32(0), state, None if cfg.lean_frontier else fill,
+                 fill, jnp.int32(0), jnp.zeros(sgs.vmask.shape[:1],
+                                               jnp.int32),
+                 jnp.int32(1), jnp.int32(1))
+        steps, state, _, _, tot_msgs, tot_sweeps, _, _ = \
+            jax.lax.while_loop(cond, body, carry)
+        with scope("result"):
+            results = _each(lambda sg, st: program.result(sg, params, st),
+                            sgs, state)
+        return results, steps, tot_msgs, tot_sweeps
+
+    return run
+
+
+def _runner_args(args, has_layout: bool, warm_start: bool):
+    """``(sgs[, lay], params[, warm])`` -> ``(sgs, lay, params, warm)``."""
+    n = 1 + has_layout
+    assert len(args) == n + 1 + warm_start, \
+        "a runner takes (sgs[, lay], params[, warm])"
+    return (args[0], args[1] if has_layout else None, args[n],
+            args[n + 1] if warm_start else None)
+
+
+def _lanes(runner, n_shared: int, vmap: bool):
+    """The micro-batching form of ``runner``: its first ``n_shared``
+    inputs (graph, layout) are shared and the rest (params, warm block)
+    carry a leading lane axis B, as do the outputs — ``vmap`` over the
+    lanes, or a ``lax.scan`` through them inside one launch where a vmap
+    would have to lift a Pallas call or a shard_map collective."""
+    def batched(*args):
+        shared, lanes = args[:n_shared], args[n_shared:]
+        if vmap:
+            return jax.vmap(lambda *x: runner(*shared, *x))(*lanes)
+        _, out = jax.lax.scan(lambda c, x: (c, runner(*shared, *x)),
+                              jnp.int32(0), lanes)
+        return out
+    return batched
+
+
+def _check_assignment(edge_backend: str, partition_backends) -> None:
+    if edge_backend == "auto" and partition_backends is None:
+        raise ValueError("edge_backend='auto' runners need the resolved "
+                         "partition_backends assignment "
+                         "(resolve_partition_backends)")
 
 
 def _warm_block(program: VertexProgram, pg: PartitionedGraph,
@@ -735,19 +958,21 @@ def _warm_block(program: VertexProgram, pg: PartitionedGraph,
 def _exchange_bytes_per_step(cfg: EngineConfig, n_slots: int, K: int,
                              dtype, n_parts: int, n_edge_shards: int) -> int:
     """Collective bytes one superstep's SBS exchange moves — matching the
-    exchange variant the runner actually lowered, so sparse-vs-dense
-    benchmark comparisons measure real volume. Counts the inter-partition
-    (subgraph-axes) collective only: intra-partition edge-axis combines
-    (sweep reductions, the sharded merged-view rebuild) are excluded
-    everywhere, like the paper's network-message metric."""
+    exchange variant the runner actually lowered (the simulator lowers only
+    the dense one), so sparse-vs-dense benchmark comparisons measure real
+    volume. Counts the inter-partition (subgraph-axes) collective only:
+    intra-partition edge-axis combines (sweep reductions, the sharded
+    merged-view rebuild) are excluded everywhere, like the paper's
+    network-message metric."""
     itemsize = np.dtype(dtype).itemsize
-    if cfg.shard_slots and n_edge_shards > 1:
+    shard = cfg.backend == "shard_map"
+    if shard and cfg.shard_slots and n_edge_shards > 1:
         # each of the n_edge_shards slot slices is all-reduced over the
         # subgraph axes: n_loc + 1 rows (incl. the dump row) per device,
         # n_parts * n_edge_shards devices
         n_loc = -(-(n_slots + 1) // n_edge_shards)
         return (n_loc + 1) * K * itemsize * n_parts * n_edge_shards
-    if cfg.sparse_sync_capacity > 0:
+    if shard and cfg.sparse_sync_capacity > 0:
         # compacted all-gather: capacity (int32 idx, K-vector val) pairs
         cap = min(cfg.sparse_sync_capacity, n_slots + 1)
         return cap * (4 + K * itemsize) * n_parts
@@ -781,156 +1006,115 @@ def _flops_per_sweep(program: VertexProgram, edge_backend: str,
                                pg=pg)
 
 
+def execution_stats(program: VertexProgram, cfg: EngineConfig,
+                    pg: PartitionedGraph, n_slots: int, steps, messages,
+                    sweeps, *, n_edge_shards: int = 1,
+                    assignment=None) -> ExecutionStats:
+    """The bill of one BSP run from a runner's outputs (supersteps,
+    messages, the [P] per-partition sweep counts): ``supersteps``,
+    ``total_messages``, ``processed_edges``, ``total_bytes`` of the
+    exchange the runner lowered on ``n_slots`` slot rows (the height it
+    reduced: a session's bucketed capacity), ``backend_flops``, the
+    ``'auto'`` ``assignment`` as ``partition_edge_backends``, the tile
+    density on ``pallas_tiles``/``'auto'``, and the per-partition edge
+    counts and flops. The caller adds the times."""
+    edge_backend = resolve_edge_backend(program, cfg)
+    sweeps = np.asarray(sweeps, dtype=np.int64)
+    epp = pg.edges_per_part.astype(np.int64)
+    lay = None if edge_backend == "coo" else pg.edge_layouts
+    flops = sweeps * _flops_per_sweep(program, edge_backend, pg, lay,
+                                      assignment, n_edge_shards)
+    st = ExecutionStats(
+        supersteps=int(steps), total_messages=int(messages),
+        processed_edges=int((sweeps * epp).sum()),
+        total_bytes=int(steps) * _exchange_bytes_per_step(
+            cfg, n_slots, program.payload, program.dtype, pg.n_parts,
+            n_edge_shards),
+        edge_backend=edge_backend, backend_flops=int(flops.sum()),
+        partition_edge_counts=[int(x) for x in epp],
+        partition_flops=[int(x) for x in flops])
+    if assignment is not None:
+        st.partition_edge_backends = list(assignment)
+    if edge_backend in ("pallas_tiles", "auto") and lay is not None:
+        spec = program.sweep_spec
+        st.tile_density = lay.density(pg, spec.semiring, spec.edge_values,
+                                      program.dtype)
+        st.partition_tile_density = [float(x) for x in lay.partition_density(
+            pg, spec.semiring, spec.edge_values, program.dtype)]
+    return st
+
+
+def _one_shot_layouts(program: VertexProgram, cfg: EngineConfig,
+                      pg: PartitionedGraph, *, mixed_shard: bool = False,
+                      n_shards: int = 1,
+                      placement: Optional[ShardSpecs] = None) -> tuple:
+    """``(edge backend, 'auto' assignment, layout input)`` of a one-shot
+    run: the layout input is ``()`` on ``coo`` and a one-tuple otherwise,
+    ready to splice into the runner's arguments."""
+    edge_backend = resolve_edge_backend(program, cfg)
+    if edge_backend == "coo":
+        return edge_backend, None, ()
+    lay = pg.ensure_edge_layouts()
+    if edge_backend != "auto":
+        return edge_backend, None, (_layout_block_from(
+            lay, pg, program, edge_backend, n_shards, placement),)
+    assignment = resolve_partition_backends(program, cfg, pg, lay=lay)
+    return edge_backend, assignment, (_auto_layout_blocks(
+        lay, pg, program, assignment, mixed_shard, n_shards, placement),)
+
+
 # --------------------------------------------------------------------------- #
-# Simulator backend
+# The two runners: the one loop, on a stack of every partition or, under
+# shard_map, on one partition a device
 # --------------------------------------------------------------------------- #
-def _make_sim_superstep(program: VertexProgram, cfg: EngineConfig,
-                        n_slots: int, edge_backend: str = "coo",
-                        assignment=None):
-    """One BSP superstep over the stacked [P, ...] pytree: vmapped local
-    phase on the COO backend, one flattened Pallas launch per sweep on the
-    kernel backends (per backend group under a mixed ``'auto'``
-    ``assignment``; a uniform one runs its backend's path). ``lay`` is the
-    device layout pytree (None for COO)."""
-    ident = program.identity
-    ec = EdgeCombine(())
-    ex = sbs.SimExchange()
-    edge_backend = _effective_backend(edge_backend, assignment)
-    groups = _assignment_groups(assignment) if edge_backend == "auto" \
-        else None
-
-    def superstep(sgs, lay, params, state, last_out, merged_buf, first):
-        with scope("apply"):
-            merged_v = jax.vmap(
-                lambda sg: sbs.gather_merged(merged_buf, sg.slot))(sgs)
-        if edge_backend == "coo":
-            state, out, sweeps, last_ch = jax.vmap(
-                lambda sg, st, m: _local_phase(program, sg, params, st, m, ec,
-                                               cfg, first)
-            )(sgs, state, merged_v)
-        else:
-            state, out, sweeps, last_ch = _batched_local_phase(
-                program, sgs, lay, params, state, merged_v, ec, cfg, first,
-                edge_backend, groups)
-        with scope("pack"):
-            bufs, changed = jax.vmap(
-                lambda sg, o, lo: _pack(program, sg, o, lo, n_slots)
-            )(sgs, out, last_out)
-        with scope("exchange"):
-            merged_buf = ex.all_combine(bufs, program.combiner)
-            merged_buf = merged_buf.at[n_slots].set(ident)
-        with scope("vote"):
-            msgs = jnp.sum(changed, dtype=jnp.int32)
-            active = jnp.sum(last_ch > 0, dtype=jnp.int32)
-        return state, out, merged_buf, msgs, active, sweeps
-
-    return superstep
-
-
 def make_sim_runner(program: VertexProgram, cfg: EngineConfig, n_slots: int,
                     *, warm_start=False, batch=False,
                     partition_backends=None):
-    """Build the simulator BSP loop as a pure function
+    """Build the simulator BSP runner: the one loop (``_make_loop``) over
+    the stacked [P, ...] pytree, with ``sbs.SimExchange`` reducing the
+    stack, as a pure function of the runners' one protocol
 
         runner(sgs[, lay], params[, warm_block]) ->
-            (results, supersteps, total_messages, sweeps_per_part)
+            (results[P, ...], supersteps, total_messages, sweeps_per_part[P])
 
-    ``sgs`` is the stacked [P, ...] DeviceSubgraph pytree, ``params`` the
-    program's parameter pytree (traced — repeated calls with different
-    params reuse one compilation), ``warm_block`` (``warm_start=True``) a
-    [P, v_max, K] previous-result block threaded into ``program.warm_init``.
+    ``sgs`` is the stacked [P, ...] DeviceSubgraph pytree; ``lay`` the
+    device layout pytree, present exactly when
+    ``resolve_edge_backend(program, cfg)`` is not ``'coo'``
+    (``TileBlock``/``WindowBlock`` from ``_layout_block_from``; under
+    ``'auto'``, which needs the ``partition_backends`` assignment of
+    ``resolve_partition_backends``, the group-sliced ``(tiles, windows)``
+    pair of ``_auto_layout_blocks``) — an explicit input, not a closure, so
+    a serving session's compiled executable keeps working as the layouts
+    evolve under streaming; ``params`` the program's parameter pytree
+    (traced — one compilation serves every parameter value);
+    ``warm_block`` (``warm_start=True``) a [P, v_max, K] previous-result
+    block threaded into ``program.warm_init``.
 
     ``batch=True`` builds the cross-request micro-batching variant
     (serving/batcher.py): every params leaf — and the warm block — carries
     a leading batch axis B, the graph (and layout) inputs stay shared, and
     ONE launch returns per-lane ``(results[B], steps[B], msgs[B],
-    sweeps[B, P])``. The COO path vmaps the whole BSP loop over the lanes
+    sweeps[B, P])``. The COO path vmaps the whole loop over the lanes
     (vmap-of-while: a converged lane's carry is select-frozen while the
     rest run on, so per-lane math is identical to a singleton run); the
     Pallas backends cannot ride vmap's lifting of ``pallas_call``, so they
-    scan the lanes sequentially inside the same single launch instead —
-    same executable-count and dispatch amortization, no lane parallelism.
-
-    When ``resolve_edge_backend(program, cfg)`` picks a Pallas backend the
-    runner takes the device layout pytree (``TileBlock``/``WindowBlock``,
-    built by ``_layout_block_from``) as its second argument — an explicit
-    input,
-    not a closure, so a serving session's compiled executable keeps working
-    as the layouts evolve under streaming. Under ``'auto'`` the caller must
-    pass the per-partition ``partition_backends`` assignment
-    (``resolve_partition_backends``) and the layout argument becomes the
-    group-sliced ``(tiles, windows)`` pair of ``_auto_layout_blocks``.
+    scan the lanes inside the same single launch instead.
 
     ``run_sim`` calls the runner eagerly once per job; ``GraphSession``
     wraps it in ``jax.jit``, AOT-compiles it once per
     (program, config, padded shapes) key and reuses the executable across
     queries with zero retraces."""
-    K = program.payload
-    ident = program.identity
-    ec = EdgeCombine(())
     edge_backend = resolve_edge_backend(program, cfg)
-    if edge_backend == "auto" and partition_backends is None:
-        raise ValueError("edge_backend='auto' runners need the resolved "
-                         "partition_backends assignment "
-                         "(resolve_partition_backends)")
-    superstep = _make_sim_superstep(program, cfg, n_slots, edge_backend,
-                                    partition_backends)
+    _check_assignment(edge_backend, partition_backends)
+    run = _make_loop(program, cfg, n_slots, partition_backends)
+    has_layout = edge_backend != "coo"
 
-    def _run(sgs, lay, params, warm):
-        n_parts, v_max = sgs.vmask.shape
-        with scope("init"):
-            v_init = jax.vmap(lambda sg: program.init(sg, params, ec))(sgs)
-            if warm_start:
-                v_init = jax.vmap(
-                    lambda sg, st, w: program.warm_init(sg, params, st, w)
-                )(sgs, v_init, warm[0])
-            last0 = jnp.full((n_parts, v_max, K), ident, dtype=program.dtype)
-            merged0 = jnp.full((n_slots + 1, K), ident, dtype=program.dtype)
-
-        def cond(c):
-            step, msgs, active = c[0], c[-2], c[-1]
-            return (step == 0) | (((msgs > 0) | (active > 0))
-                                  & (step < cfg.max_supersteps))
-
-        def body(c):
-            step, state, last_out, merged_buf, tot_msgs, tot_sweeps, _, _ = c
-            state, out, merged_buf, msgs, active, sweeps = superstep(
-                sgs, lay, params, state, last_out, merged_buf, step == 0)
-            return (step + 1, state, out, merged_buf, tot_msgs + msgs,
-                    tot_sweeps + sweeps, msgs, active)
-
-        carry = (jnp.int32(0), v_init, last0, merged0, jnp.int32(0),
-                 jnp.zeros((n_parts,), jnp.int32), jnp.int32(1),
-                 jnp.int32(1))
-        carry = jax.lax.while_loop(cond, body, carry)
-        (steps, state, last_out, merged_buf, tot_msgs, tot_sweeps, *_) = carry
-        with scope("result"):
-            results = jax.vmap(
-                lambda sg, st: program.result(sg, params, st))(sgs, state)
-        return results, steps, tot_msgs, tot_sweeps
+    def runner(*args):
+        return run(*_runner_args(args, has_layout, warm_start))
 
     if not batch:
-        if edge_backend == "coo":
-            def runner(sgs, params, *warm):
-                return _run(sgs, None, params, warm)
-        else:
-            def runner(sgs, lay, params, *warm):
-                return _run(sgs, lay, params, warm)
         return runner
-
-    if edge_backend == "coo":
-        def runner(sgs, params, *warm):
-            return jax.vmap(lambda p, w: _run(sgs, None, p, w),
-                            in_axes=(0, 0))(params, warm)
-    else:
-        def runner(sgs, lay, params, *warm):
-            def step(c, x):
-                p, w = x
-                return c, _run(sgs, lay, p, w)
-            _, out = jax.lax.scan(step, jnp.int32(0), (params, warm))
-            return out
-
-    return runner
+    return _lanes(runner, 1 + has_layout, vmap=edge_backend == "coo")
 
 
 def run_sim(program: VertexProgram, pg: PartitionedGraph, params=None,
@@ -940,8 +1124,11 @@ def run_sim(program: VertexProgram, pg: PartitionedGraph, params=None,
     (Low-level layer — ``repro.session.GraphSession`` amortizes the upload
     and the compilation across queries.)
 
+    ``cfg.trace`` steps the runner's superstep from the host, one launch a
+    superstep, for per-step stats (``messages_per_step``,
+    ``active_parts_per_step``) and checkpoints (``cfg.checkpoint_every``).
     ``resume_from``: path to a BSP checkpoint written by a previous trace
-    run (cfg.checkpoint_every) — restart mid-job (DESIGN.md §7).
+    run — restart mid-job (DESIGN.md §7).
 
     ``init_state``: global per-vertex values [n_vertices(, K)] from a
     previous *converged* run (e.g. before a stream delta was applied) — a
@@ -950,349 +1137,139 @@ def run_sim(program: VertexProgram, pg: PartitionedGraph, params=None,
     (PageRank) silently fall back to a cold start. Shorter arrays (the graph
     grew) are padded with the combiner identity."""
     sgs = _device_subgraph(pg)
-    n_slots, K = pg.n_slots, program.payload
-    warm = init_state is not None and program.monotone
-    edge_backend = resolve_edge_backend(program, cfg)
-    lay = lay_blk = assignment = None
-    if edge_backend == "auto":
-        lay = pg.ensure_edge_layouts()
-        assignment = resolve_partition_backends(program, cfg, pg, lay=lay)
-        lay_blk = _auto_layout_blocks(lay, pg, program, assignment)
-    elif edge_backend != "coo":
-        lay = pg.ensure_edge_layouts()
-        lay_blk = _layout_block_from(lay, pg, program, edge_backend)
-
-    stats = ExecutionStats(edge_backend=edge_backend)
-    epp_host = pg.edges_per_part.astype(np.int64)
-    flops_pp = _flops_per_sweep(program, edge_backend, pg, lay, assignment)
-    if assignment is not None:
-        stats.partition_edge_backends = list(assignment)
-    if edge_backend in ("pallas_tiles", "auto"):
-        spec = program.sweep_spec
-        stats.tile_density = lay.density(pg, spec.semiring, spec.edge_values,
-                                         program.dtype)
-        stats.partition_tile_density = list(lay.partition_density(
-            pg, spec.semiring, spec.edge_values, program.dtype))
+    warm = None
+    if init_state is not None and program.monotone:
+        warm = jnp.asarray(_warm_block(program, pg, init_state))
+    edge_backend, assignment, lay_arg = _one_shot_layouts(program, cfg, pg)
     t0 = time.perf_counter()
+    per_step = start_step = None
 
     if cfg.trace:
-        ident = program.identity
-        ec = EdgeCombine(())
-        v_init = jax.vmap(lambda sg: program.init(sg, params, ec))(sgs)
-        if warm:
-            wv = _warm_block(program, pg, init_state)
-            v_init = jax.vmap(
-                lambda sg, st, w: program.warm_init(sg, params, st, w)
-            )(sgs, v_init, jnp.asarray(wv))
-        last0 = jnp.full((pg.n_parts, pg.v_max, K), ident,
-                         dtype=program.dtype)
-        merged0 = jnp.full((n_slots + 1, K), ident, dtype=program.dtype)
+        superstep, ec = _make_superstep(program, cfg, pg.n_slots, assignment)
+        state, fill = _loop_init(program, ec, sgs, params, warm)
+        last_out = merged_v = fill
         start_step = 0
         if resume_from is not None:
             from repro.training.checkpoint import load_pytree
-            ckpt, meta = load_pytree(
-                resume_from, like=dict(state=v_init, last_out=last0,
-                                       merged=merged0, step=jnp.int32(0)))
-            v_init, last0, merged0 = (ckpt["state"], ckpt["last_out"],
-                                      ckpt["merged"])
+            ckpt, _ = load_pytree(
+                resume_from, like=dict(state=state, last_out=fill,
+                                       merged=fill, step=jnp.int32(0)))
+            state, last_out, merged_v = (ckpt["state"], ckpt["last_out"],
+                                         ckpt["merged"])
             start_step = int(ckpt["step"])
-
-        superstep = _make_sim_superstep(program, cfg, n_slots, edge_backend,
-                                        assignment)
-        step_fn = jax.jit(lambda st, lo, mb, first: superstep(
-            sgs, lay_blk, params, st, lo, mb, first))
-        state, last_out, merged_buf = v_init, last0, merged0
+        lay = lay_arg[0] if lay_arg else None
+        step_fn = jax.jit(lambda st, lo, mv, first: superstep(
+            sgs, lay, params, st, lo, mv, first))
+        per_step = ([], [])
+        steps, tot_msgs = 0, 0
+        tot_sweeps = np.zeros(pg.n_parts, np.int64)
         for step in range(start_step, cfg.max_supersteps):
-            state, last_out, merged_buf, msgs, active, sweeps = step_fn(
-                state, last_out, merged_buf, jnp.bool_(step == 0))
+            state, last_out, merged_v, msgs, active, sweeps = step_fn(
+                state, last_out, merged_v, jnp.bool_(step == 0))
             msgs, active = int(msgs), int(active)
-            stats.messages_per_step.append(msgs)
-            stats.active_parts_per_step.append(active)
-            stats.total_messages += msgs
-            sweeps_h = np.asarray(sweeps, dtype=np.int64)
-            stats.processed_edges += int((sweeps_h * epp_host).sum())
-            stats.backend_flops += int((sweeps_h * flops_pp).sum())
-            stats.total_bytes += (n_slots + 1) * K * np.dtype(program.dtype).itemsize * pg.n_parts
-            stats.supersteps = step + 1
+            per_step[0].append(msgs)
+            per_step[1].append(active)
+            steps, tot_msgs = steps + 1, tot_msgs + msgs
+            tot_sweeps += np.asarray(sweeps, dtype=np.int64)
             if cfg.checkpoint_every and (step + 1) % cfg.checkpoint_every == 0 \
                     and cfg.checkpoint_dir:
                 from repro.training.checkpoint import save_pytree
                 os.makedirs(cfg.checkpoint_dir, exist_ok=True)
                 save_pytree(f"{cfg.checkpoint_dir}/bsp_{step + 1:06d}.npz",
                             dict(state=state, last_out=last_out,
-                                 merged=merged_buf, step=step + 1))
+                                 merged=merged_v, step=step + 1))
             if msgs == 0 and active == 0:
                 break
-        results = jax.vmap(
-            lambda sg, st: program.result(sg, params, st))(sgs, state)
+        results = _each(lambda sg, st: program.result(sg, params, st),
+                        sgs, state)
     else:
         assert resume_from is None, "resume requires trace mode"
-        runner = make_sim_runner(program, cfg, n_slots, warm_start=warm,
+        runner = make_sim_runner(program, cfg, pg.n_slots,
+                                 warm_start=warm is not None,
                                  partition_backends=assignment)
-        args = (sgs,) if edge_backend == "coo" else (sgs, lay_blk)
-        args += (params,)
-        if warm:
-            args += (jnp.asarray(_warm_block(program, pg, init_state)),)
-        results, steps, tot_msgs, tot_sweeps = runner(*args)
-        stats.supersteps = int(steps)
-        stats.total_messages = int(tot_msgs)
-        sweeps_h = np.asarray(tot_sweeps, dtype=np.int64)
-        stats.processed_edges = int((sweeps_h * epp_host).sum())
-        stats.backend_flops = int((sweeps_h * flops_pp).sum())
-        stats.total_bytes = stats.supersteps * (n_slots + 1) * K * \
-            np.dtype(program.dtype).itemsize * pg.n_parts
+        results, steps, tot_msgs, tot_sweeps = runner(
+            sgs, *lay_arg, params, *(() if warm is None else (warm,)))
+    results = np.asarray(results)
+    wall = time.perf_counter() - t0
 
-    stats.wall_time = time.perf_counter() - t0
-    return np.asarray(results), stats
+    # the simulator lowers the dense exchange whatever cfg.backend says
+    stats = execution_stats(program, dataclasses.replace(cfg, backend="sim"),
+                            pg, pg.n_slots, steps, tot_msgs, tot_sweeps,
+                            assignment=assignment)
+    stats.wall_time = wall
+    if per_step is not None:
+        stats.messages_per_step, stats.active_parts_per_step = per_step
+        stats.supersteps += start_step      # counted from the job's start
+    return results, stats
 
 
-# --------------------------------------------------------------------------- #
-# shard_map backend
-# --------------------------------------------------------------------------- #
 def make_bsp_runner(program: VertexProgram, mesh: Mesh,
-                    cfg: EngineConfig, n_slots: int, *, params=None,
-                    has_vlabel=False, warm_start=False,
-                    params_as_input=False, batch=False,
+                    cfg: EngineConfig, n_slots: int, *,
+                    has_vlabel=False, warm_start=False, batch=False,
                     partition_backends=None):
-    """Build the shard_map'd BSP loop (shared by run_shard_map, the
-    graph-engine dry-run — which lowers it against ShapeDtypeStructs — and
-    ``GraphSession``'s compiled-runner cache).
+    """Build the shard_map BSP runner: the one loop (``_make_loop``) that
+    the simulator runs, wrapped in ``shard_map`` over ``mesh`` with the
+    ``shard_specs`` placement — each device runs it on its stack of one
+    partition, and ``sbs.ShardExchange`` all-reduces over the subgraph
+    axes. Shared by ``run_shard_map``, the graph-engine dry-run (which
+    lowers it against ShapeDtypeStructs) and ``GraphSession``'s
+    compiled-runner cache. It takes the simulator runner's protocol,
 
-    ``params`` is the program's parameter pytree. By default it is closed
-    over at trace time (EngineConfig is frozen and never carries it). With
-    ``params_as_input=True`` it is instead a *template*: the returned runner
-    takes a pytree of the same structure as its last argument, replicated
-    (``P()``) across the mesh — so one compiled runner serves every
-    parameter value (e.g. SSSP from any source) with zero retraces.
+        runner(sgs[, lay], params[, warm_block]) ->
+            (results[P, ...], supersteps, total_messages, sweeps_per_part[P])
 
-    ``warm_start=True`` builds the runner with an extra input: a
-    [P, v_max, K] warm-state block sharded like the vertex tables, threaded
+    with ``sgs``, ``lay`` and ``warm_block`` sharded over the subgraph axes
+    like the vertex tables, and ``params`` replicated (``P()``).
+
+    ``warm_start=True`` adds the [P, v_max, K] warm-state block, threaded
     into ``program.warm_init`` right after on-device init — the incremental
     recompute path (docs/STREAMING.md). The caller owns the soundness check
     (monotone program, insert-only delta).
 
-    When ``resolve_edge_backend(program, cfg)`` picks a Pallas backend the
-    runner takes the device layout pytree as an additional input directly
-    after ``sgs`` (positional protocol: ``sgs[, layout][, warm][, params]``),
-    sharded over the subgraph axes like the vertex tables. With
-    ``cfg.edge_axes`` set, the tile/window lists are additionally sharded
-    over the edge axes (``EdgeLayouts._sharded_geometry``): each edge shard
-    runs the kernel product over its own per-shard tile/window lists and
-    the ``EdgeCombine`` epilogue of the generated sweep (pmin for
-    ``min_plus``, psum for ``plus_times``) reduces the partial per-vertex
-    aggregates across the shards before the fold — bit-identical to the
-    unsharded launch for min-combines, float-associativity-tolerant for
-    sums, exactly like the COO path's sharded product. Under ``'auto'``
-    (``partition_backends`` required) a mixed assignment's layout input is
-    ``(tiles, windows, backend_ids)`` (``_auto_layout_blocks``: a stub for
-    a backend no partition runs) and a per-partition ``lax.switch`` picks
-    the sweep; a uniform one takes its backend's own layout input (None
-    for ``coo``) and sweep.
+    ``lay`` is present exactly when ``resolve_edge_backend(program, cfg)``
+    is not ``'coo'``. With ``cfg.edge_axes`` set, the tile/window lists are
+    additionally sharded over the edge axes
+    (``EdgeLayouts._sharded_geometry``): each edge shard runs the kernel
+    product over its own per-shard lists and the ``EdgeCombine`` epilogue
+    after the product (pmin for ``min_plus``, psum for ``plus_times``)
+    reduces the partial per-vertex aggregates across the shards before the
+    fold — bit-identical to the unsharded launch for min-combines,
+    float-associativity-tolerant for sums, exactly like the COO path's
+    sharded product. Under ``'auto'`` (``partition_backends`` required) a
+    mixed assignment's layout input is ``(tiles, windows, backend_ids)``
+    (``_auto_layout_blocks``: a stub for a backend no partition runs) and a
+    ``lax.switch`` on the device's backend id picks the product; a uniform
+    one takes its backend's own layout input (None for ``coo``).
 
-    ``batch=True`` (requires ``params_as_input=True``) builds the
-    micro-batching variant: the warm block (when present) and every params
-    leaf carry a leading batch axis B, and the returned runner scans the
-    lanes through the shard_map'd superstep loop inside one launch —
-    ``lax.scan`` rather than vmap, because a vmap would have to batch
-    through the shard_map collectives. Outputs gain the same leading B."""
-    sub_axes = tuple(cfg.subgraph_axes)
-    edge_axes = tuple(cfg.edge_axes)
-    K = program.payload
-    ident = program.identity
-    ec = EdgeCombine(edge_axes)
-    ex = sbs.ShardExchange(sub_axes)
+    ``batch=True`` builds the micro-batching variant: the warm block (when
+    present) and every params leaf carry a leading batch axis B, and the
+    returned runner scans the lanes through the shard_map'd loop inside one
+    launch — ``lax.scan`` rather than vmap, because a vmap would have to
+    batch through the shard_map collectives. Outputs gain the same leading
+    B."""
     edge_backend = resolve_edge_backend(program, cfg)
-
+    _check_assignment(edge_backend, partition_backends)
+    run = _make_loop(program, cfg, n_slots, partition_backends, mesh)
     specs = shard_specs(cfg, has_vlabel)
-    vert_spec = P(sub_axes, None)
+    has_layout = edge_backend != "coo"
+    in_specs = (specs.graph,)
+    if has_layout:
+        in_specs += ({"coo": P(),           # the layout input is None
+                      "pallas_tiles": specs.tiles,
+                      "pallas_windows": specs.windows,
+                      "auto": (specs.tiles, specs.windows, specs.part)}[
+            _effective_backend(edge_backend, partition_backends)],)
+    in_specs += (P(),) + ((specs.warm,) if warm_start else ())
 
-    def _squeeze(x):
-        return None if x is None else x.reshape(x.shape[1:])
-
-    n_edge_shards = int(np.prod([mesh.shape[a] for a in edge_axes])) \
-        if edge_axes else 1
-    shard_slots = cfg.shard_slots and n_edge_shards > 1
-    n_loc = -(-(n_slots + 1) // n_edge_shards) if shard_slots else n_slots + 1
-
-    # Pallas layout specs (specs.tiles / specs.windows): tile/window lists
-    # shard over the edge axes like the edge arrays themselves, and the
-    # EdgeCombine epilogue inside the generated sweep merges the partial
-    # aggregates across shards. With no edge axes these reduce to the
-    # replicated-within-partition specs of the unsharded launch.
-    lay_specs = None
-    if edge_backend == "auto":
-        if partition_backends is None:
-            raise ValueError("edge_backend='auto' runners need the resolved "
-                             "partition_backends assignment "
-                             "(resolve_partition_backends)")
-        edge_backend = _effective_backend(edge_backend, partition_backends)
-        if edge_backend == "coo":
-            lay_specs = P()                 # the layout input is None
-    if edge_backend == "auto":
-        lay_specs = (specs.tiles, specs.windows, specs.part)
-        tiles_sweep = _make_pallas_sweep(program, "pallas_tiles")
-        windows_sweep = _make_pallas_sweep(program, "pallas_windows")
-    elif edge_backend != "coo":
-        lay_specs = specs.tiles if edge_backend == "pallas_tiles" \
-            else specs.windows
-        pallas_sweep = _make_pallas_sweep(program, edge_backend)
-
-    def _body(sg_block, lay_block, warm_block, params):
-        sg = DeviceSubgraph(*[_squeeze(x) for x in sg_block])
-        sweep_fn = None
-        if lay_block is not None and edge_backend == "auto":
-            t_raw, w_raw, bid = lay_block
-            t_lay = TileBlock(*[_squeeze(x) for x in t_raw])
-            w_lay = WindowBlock(*[_squeeze(x) for x in w_raw])
-            bid = _squeeze(bid)                      # () int32 backend id
-
-            def sweep_fn(sg_, p_, st_, ec_):
-                return jax.lax.switch(
-                    bid,
-                    [lambda s: program.sweep(sg_, p_, s, ec_),
-                     lambda s: tiles_sweep(sg_, t_lay, p_, s, ec_),
-                     lambda s: windows_sweep(sg_, w_lay, p_, s, ec_)],
-                    st_)
-        elif lay_block is not None:
-            lay = type(lay_block)(*[_squeeze(x) for x in lay_block])
-            sweep_fn = (lambda sg_, p_, st_, ec_:
-                        pallas_sweep(sg_, lay, p_, st_, ec_))
-        with scope("init"):
-            state = program.init(sg, params, ec)
-            if warm_block is not None:
-                state = program.warm_init(sg, params, state,
-                                          _squeeze(warm_block))
-            last0 = jnp.full((sg.v_max, K), ident, dtype=program.dtype)
-            merged_v0 = jnp.full((sg.v_max, K), ident, dtype=program.dtype)
-
-        def _exchange_dense(out, changed):
-            with scope("pack"):
-                buf = sbs.scatter_combine(out, sg.slot, changed, n_slots,
-                                          program.combiner, ident)
-            with scope("exchange"):
-                if cfg.sparse_sync_capacity > 0:
-                    merged = sbs.compact_allgather_exchange(
-                        buf, ident, program.combiner, n_slots,
-                        cfg.sparse_sync_capacity, sub_axes)
-                else:
-                    merged = ex.all_combine(buf, program.combiner)
-                merged = merged.at[n_slots].set(ident)
-            with scope("apply"):
-                return sbs.gather_merged(merged, sg.slot)
-
-        def _exchange_sharded(out, changed):
-            # Sharded SBS (DESIGN.md §7): frontier slots are owned by the
-            # edge-axis shard slot % n_edge_shards; the (pod,data) combiner
-            # all-reduce runs on the 1/n_edge_shards slot slice, and the
-            # per-vertex merged view is rebuilt with an edge-axis combine —
-            # O(n_slots / n_edge_shards) state per device, which is what
-            # keeps the trillion-edge configuration within HBM.
-            rank = jax.lax.axis_index(edge_axes)
-            with scope("pack"):
-                owned = changed & (sg.slot % n_edge_shards == rank)
-                slot_loc = jnp.where(owned, sg.slot // n_edge_shards, n_loc)
-                buf = sbs.scatter_combine(out, slot_loc, owned, n_loc,
-                                          program.combiner, ident)
-            with scope("exchange"):
-                merged = ex.all_combine(buf, program.combiner)
-                gather_own = sg.frontier & (sg.slot % n_edge_shards == rank)
-                mv = jnp.where(
-                    gather_own[:, None],
-                    merged[jnp.clip(sg.slot // n_edge_shards, 0, n_loc)],
-                    ident)
-                if program.combiner == "min":
-                    return ec.min(mv)
-                if program.combiner == "max":
-                    return ec.max(mv)
-                return ec.sum(jnp.where(gather_own[:, None], mv,
-                                        0).astype(mv.dtype))
-
-        def superstep(state, last_out, merged_v, first):
-            state, out, sweeps, last_ch = _local_phase(
-                program, sg, params, state, merged_v, ec, cfg, first,
-                sweep_fn=sweep_fn)
-            ref = merged_v if cfg.lean_frontier else last_out
-            with scope("pack"):
-                changed = program.changed_mask(out, ref) & sg.frontier
-            if shard_slots:
-                merged_v = _exchange_sharded(out, changed)
-            else:
-                merged_v = _exchange_dense(out, changed)
-            with scope("vote"):
-                msgs = ex.all_sum_scalar(jnp.sum(changed, dtype=jnp.int32))
-                active = ex.all_sum_scalar((last_ch > 0).astype(jnp.int32))
-            return state, out, merged_v, msgs, active, sweeps
-
-        def cond(c):
-            step, msgs, active = c[0], c[-2], c[-1]
-            return (step == 0) | (((msgs > 0) | (active > 0))
-                                  & (step < cfg.max_supersteps))
-
-        if cfg.lean_frontier:
-            # no last_out buffer: 2 fewer [v_max, K] live values in the loop
-            def body(c):
-                step, state, merged_v, tm, tsw, _, _ = c
-                state, _, merged_v, msgs, active, sweeps = superstep(
-                    state, None, merged_v, step == 0)
-                return (step + 1, state, merged_v, tm + msgs, tsw + sweeps,
-                        msgs, active)
-
-            carry = (jnp.int32(0), state, merged_v0, jnp.int32(0),
-                     jnp.int32(0), jnp.int32(1), jnp.int32(1))
-        else:
-            def body(c):
-                step, state, last_out, merged_v, tm, tsw, _, _ = c
-                state, out, merged_v, msgs, active, sweeps = superstep(
-                    state, last_out, merged_v, step == 0)
-                return (step + 1, state, out, merged_v, tm + msgs,
-                        tsw + sweeps, msgs, active)
-
-            carry = (jnp.int32(0), state, last0, merged_v0, jnp.int32(0),
-                     jnp.int32(0), jnp.int32(1), jnp.int32(1))
-        steps, state, *_, tm, tsw, _, _ = jax.lax.while_loop(cond, body, carry)
-        with scope("result"):
-            res = program.result(sg, params, state)
-        return res[None], steps, tm, tsw[None]
-
-    out_specs = (vert_spec, P(), P(), specs.part)
-    # positional protocol (in this order): sgs [, layout][, warm][, params]
-    in_specs = [specs.graph]
-    if lay_specs is not None:
-        in_specs.append(lay_specs)
-    if warm_start:
-        in_specs.append(specs.warm)
-    if params_as_input:
-        in_specs.append(jax.tree.map(lambda _: P(), params))
-
-    @partial(shard_map, mesh=mesh, in_specs=tuple(in_specs),
-             out_specs=out_specs)
-    def go(*args):
-        it = iter(args)
-        sg_block = next(it)
-        lay_block = next(it) if lay_specs is not None else None
-        warm_block = next(it) if warm_start else None
-        p = next(it) if params_as_input else params
-        return _body(sg_block, lay_block, warm_block, p)
+    @partial(shard_map, mesh=mesh, in_specs=in_specs,
+             out_specs=(P(tuple(cfg.subgraph_axes), None), P(), P(),
+                        specs.part))
+    def runner(*args):
+        return run(*_runner_args(args, has_layout, warm_start))
 
     if not batch:
-        return go
-
-    assert params_as_input, "batch=True batches the params input"
-    # positional protocol unchanged (sgs[, layout][, warm][, params]); the
-    # warm block and params are the scanned ("moving") inputs, graph and
-    # layout stay shared across the lanes
-    n_static = 2 if lay_specs is not None else 1
-
-    def go_batched(*args):
-        static, moving = args[:n_static], tuple(args[n_static:])
-
-        def step(c, x):
-            return c, go(*static, *x)
-
-        _, out = jax.lax.scan(step, jnp.int32(0), moving)
-        return out
-
-    return go_batched
+        return runner
+    return _lanes(runner, 1 + has_layout, vmap=False)
 
 
 def run_shard_map(program: VertexProgram, pg: PartitionedGraph, mesh: Mesh,
@@ -1303,62 +1280,34 @@ def run_shard_map(program: VertexProgram, pg: PartitionedGraph, mesh: Mesh,
     ``run_sim``: monotone programs only; non-monotone programs get an
     explicit cold start — the runner is built without the warm input, so the
     fallback is visible in the lowered program, never a silent drop)."""
-    sub_axes = tuple(cfg.subgraph_axes)
-    edge_axes = tuple(cfg.edge_axes)
-    n_sub = int(np.prod([mesh.shape[a] for a in sub_axes]))
-    n_edge = int(np.prod([mesh.shape[a] for a in edge_axes])) if edge_axes else 1
+    n_sub = int(np.prod([mesh.shape[a] for a in cfg.subgraph_axes]))
+    n_edge = _n_edge_shards(mesh, cfg)
     assert pg.n_parts == n_sub, (pg.n_parts, n_sub)
     assert pg.e_max % n_edge == 0, "pad edges to a multiple of the edge axes"
 
-    n_slots, K = pg.n_slots, program.payload
     warm = init_state is not None and program.monotone
     placement = shard_placement(mesh, cfg, pg.vlabel is not None)
     sgs = _device_subgraph(pg, placement)
-    edge_backend = resolve_edge_backend(program, cfg)
-    lay = assignment = None
-    args = (sgs,)
-    if edge_backend == "auto":
-        lay = pg.ensure_edge_layouts()
-        assignment = resolve_partition_backends(program, cfg, pg, lay=lay)
-        args += (_auto_layout_blocks(lay, pg, program, assignment,
-                                     mixed_shard=True, n_shards=n_edge,
-                                     placement=placement),)
-    elif edge_backend != "coo":
-        lay = pg.ensure_edge_layouts()
-        args += (_layout_block_from(lay, pg, program, edge_backend,
-                                    n_shards=n_edge, placement=placement),)
-    go = make_bsp_runner(program, mesh, cfg, n_slots, params=params,
-                         has_vlabel=pg.vlabel is not None, warm_start=warm,
-                         partition_backends=assignment)
+    edge_backend, assignment, lay_arg = _one_shot_layouts(
+        program, cfg, pg, mixed_shard=True, n_shards=n_edge,
+        placement=placement)
+    runner = make_bsp_runner(program, mesh, cfg, pg.n_slots,
+                             has_vlabel=pg.vlabel is not None,
+                             warm_start=warm, partition_backends=assignment)
 
     t0 = time.perf_counter()
     with mesh:
+        args = (sgs, *lay_arg, params)
         if warm:
             args += (jax.device_put(_warm_block(program, pg, init_state),
                                     placement.warm),)
-        res, steps, tot_msgs, sweeps_per_part = go(*args)
+        res, steps, tot_msgs, sweeps_per_part = runner(*args)
     res = np.asarray(res)
-    sweeps_per_part = np.asarray(sweeps_per_part, dtype=np.int64)
-    stats = ExecutionStats(
-        supersteps=int(steps), total_messages=int(tot_msgs),
-        processed_edges=int(
-            (sweeps_per_part * pg.edges_per_part.astype(np.int64)).sum()),
-        total_bytes=int(steps) * _exchange_bytes_per_step(
-            cfg, n_slots, K, program.dtype, pg.n_parts, n_edge),
-        wall_time=time.perf_counter() - t0,
-        edge_backend=edge_backend,
-        backend_flops=int((sweeps_per_part * _flops_per_sweep(
-            program, edge_backend, pg, lay, assignment,
-            n_edge_shards=n_edge)).sum()),
-    )
-    if assignment is not None:
-        stats.partition_edge_backends = list(assignment)
-    if edge_backend in ("pallas_tiles", "auto"):
-        spec = program.sweep_spec
-        stats.tile_density = lay.density(pg, spec.semiring, spec.edge_values,
-                                         program.dtype)
-        stats.partition_tile_density = list(lay.partition_density(
-            pg, spec.semiring, spec.edge_values, program.dtype))
+    wall = time.perf_counter() - t0
+    stats = execution_stats(program, cfg, pg, pg.n_slots, steps, tot_msgs,
+                            sweeps_per_part, n_edge_shards=n_edge,
+                            assignment=assignment)
+    stats.wall_time = wall
     return res, stats
 
 

@@ -11,9 +11,12 @@ Two exchange contexts share one scatter/gather implementation:
 
   - ``SimExchange``    — single-process simulator: the per-partition buffers
     are stacked on a leading P axis and reduced with jnp over axis 0.
-  - ``ShardExchange``  — shard_map backend: each partition holds its own
-    buffer; the reduce is ``jax.lax.psum/pmin/pmax`` over the subgraph mesh
-    axes (pod, data).
+  - ``ShardExchange``  — shard_map backend: each device holds its own stack
+    of one partition's buffer; it is reduced, then combined with
+    ``jax.lax.psum/pmin/pmax`` over the subgraph mesh axes (pod, data).
+
+The engine's one superstep (``engine._make_superstep``) calls
+``combine_stack`` and ``all_sum_scalar`` of either on its stacked block.
 
 A sparse compacted exchange (``compact_exchange``) is provided as the
 beyond-paper optimization for frontier-sparse supersteps: the changed slots
@@ -29,8 +32,8 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-__all__ = ["scatter_combine", "gather_merged", "SimExchange", "ShardExchange",
-           "compact_allgather_exchange"]
+__all__ = ["scatter_combine", "gather_merged", "reduce_stack", "SimExchange",
+           "ShardExchange", "compact_allgather_exchange"]
 
 
 def scatter_combine(out, slot, vmask, n_slots: int, combiner: str, identity):
@@ -57,20 +60,30 @@ def gather_merged(buf, slot):
     return buf[slot]
 
 
+def reduce_stack(bufs: jnp.ndarray, combiner: str) -> jnp.ndarray:
+    """Combine stacked buffers [p, n_slots+1, K] over the leading axis."""
+    if combiner == "min":
+        return jnp.min(bufs, axis=0)
+    if combiner == "max":
+        return jnp.max(bufs, axis=0)
+    if combiner == "sum":
+        return jnp.sum(bufs, axis=0)
+    raise ValueError(combiner)
+
+
 @dataclasses.dataclass(frozen=True)
 class SimExchange:
     """Reduce stacked buffers [P, n_slots+1, K] over axis 0."""
 
     def all_combine(self, bufs: jnp.ndarray, combiner: str) -> jnp.ndarray:
-        if combiner == "min":
-            return jnp.min(bufs, axis=0)
-        if combiner == "max":
-            return jnp.max(bufs, axis=0)
-        if combiner == "sum":
-            return jnp.sum(bufs, axis=0)
-        raise ValueError(combiner)
+        return reduce_stack(bufs, combiner)
+
+    def combine_stack(self, bufs: jnp.ndarray, combiner: str) -> jnp.ndarray:
+        """The engine's exchange: every partition is in the stack."""
+        return self.all_combine(bufs, combiner)
 
     def all_sum_scalar(self, x):
+        """Sum of a [P] per-partition vector."""
         return jnp.sum(x, axis=0)
 
 
@@ -90,8 +103,14 @@ class ShardExchange:
             return jax.lax.psum(buf, ax)
         raise ValueError(combiner)
 
+    def combine_stack(self, bufs: jnp.ndarray, combiner: str) -> jnp.ndarray:
+        """The engine's exchange: the device's local stack [p, n_slots+1,
+        K] reduced, then combined across the devices."""
+        return self.all_combine(reduce_stack(bufs, combiner), combiner)
+
     def all_sum_scalar(self, x):
-        return jax.lax.psum(x, tuple(self.axis_names))
+        """Sum of the local [p] per-partition vector over every device."""
+        return jax.lax.psum(jnp.sum(x, axis=0), tuple(self.axis_names))
 
 
 # --------------------------------------------------------------------------- #

@@ -123,11 +123,10 @@ def lower_graph_cell(scale_name: str, algo: str, multi_pod: bool,
                        subgraph_axes=sub_axes, edge_axes=edge_axes,
                        max_local_iters=max_local_iters,
                        shard_slots=not dense_slots, lean_frontier=lean)
-    go = make_bsp_runner(prog, mesh, cfg, meta["n_slots"], params=params,
-                         has_vlabel=False)
+    go = make_bsp_runner(prog, mesh, cfg, meta["n_slots"], has_vlabel=False)
     sgs = _sds_subgraph(meta, n_parts, mesh, sub_axes, edge_axes)
     with mesh:
-        lowered = jax.jit(go).lower(sgs)
+        lowered = jax.jit(go).lower(sgs, params)
         compiled = lowered.compile()
     return meta, n_parts, compiled
 

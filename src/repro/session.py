@@ -93,10 +93,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.engine import (EngineConfig, _auto_layout_blocks,
-                               _device_subgraph,
-                               _exchange_bytes_per_step, _flops_per_sweep,
-                               _layout_block_from, _warm_block,
-                               local_bound, make_bsp_runner, make_sim_runner,
+                               _device_subgraph, _layout_block_from,
+                               _warm_block, execution_stats, local_bound,
+                               make_bsp_runner, make_sim_runner,
                                normalize_edge_backend,
                                resolve_partition_backends, run_sim,
                                shard_placement)
@@ -1102,20 +1101,12 @@ class GraphSession:
             compiled = jax.jit(fn).lower(*args).compile()
         else:
             self._check_mesh(cfg)
-            go = make_bsp_runner(program, self.mesh, cfg, n_slots,
-                                 params=params_c,
+            fn = make_bsp_runner(program, self.mesh, cfg, n_slots,
                                  has_vlabel=self.pg.vlabel is not None,
-                                 warm_start=warm_in, params_as_input=True,
-                                 batch=bool(batch), partition_backends=asg)
-            # session args are (sgs[, lay], params[, warm]); the shard
-            # runner wants (sgs[, lay][, warm], params) — reorder inside
-            # the jitted wrapper
-            n_pre = 2 if eb != "coo" else 1
+                                 warm_start=warm_in, batch=bool(batch),
+                                 partition_backends=asg)
             with self.mesh:
-                compiled = jax.jit(
-                    lambda *a: go(*(a[:n_pre] + a[n_pre + 1:]
-                                    + (a[n_pre],)))
-                ).lower(*args).compile()
+                compiled = jax.jit(fn).lower(*args).compile()
         compile_time = time.perf_counter() - t0
         self.stats.compile_time_total += compile_time
         entry = _RunnerEntry(
@@ -1169,67 +1160,35 @@ class GraphSession:
                            if i in live}
 
     def _check_mesh(self, cfg: EngineConfig):
-        sub = tuple(cfg.subgraph_axes)
-        edge = tuple(cfg.edge_axes)
-        n_sub = int(np.prod([self.mesh.shape[a] for a in sub]))
-        n_edge = int(np.prod([self.mesh.shape[a] for a in edge])) \
-            if edge else 1
+        n_sub = int(np.prod([self.mesh.shape[a] for a in cfg.subgraph_axes]))
         assert self.pg.n_parts == n_sub, (self.pg.n_parts, n_sub)
-        assert self.pg.e_max % n_edge == 0, \
+        assert self.pg.e_max % self._n_edge_shards(cfg) == 0, \
             "pad edges to a multiple of the edge axes"
 
     def _execution_stats(self, program, cfg, steps, msgs, sweeps, wall,
                          compile_time, eb="coo") -> ExecutionStats:
+        """The engine's bill of the launch, on the bucketed exchange height
+        the runner reduces (``slot_capacity``), plus what is the session's
+        own: the times, the per-partition sweep times and ``SessionStats``."""
         pg = self.pg
-        K = program.payload
-        itemsize = np.dtype(program.dtype).itemsize
-        # bytes are billed on the bucketed exchange height the runner
-        # actually reduces, not the exact n_slots
-        n_slots = self.slot_capacity
-        if cfg.backend == "sim":
-            total_bytes = steps * (n_slots + 1) * K * itemsize * pg.n_parts
-        else:
-            n_edge = int(np.prod([self.mesh.shape[a]
-                                  for a in cfg.edge_axes])) \
-                if cfg.edge_axes else 1
-            total_bytes = steps * _exchange_bytes_per_step(
-                cfg, n_slots, K, program.dtype, pg.n_parts, n_edge)
-        lay = pg.edge_layouts
-        sweeps64 = sweeps.astype(np.int64)
-        epp = pg.edges_per_part.astype(np.int64)
-        ns = self._n_edge_shards(cfg)
-        asg = self._resolve_assignment(program, cfg) if eb == "auto" \
-            else None
-        flops_pp = sweeps64 * _flops_per_sweep(program, eb, pg, lay,
-                                               assignment=asg,
-                                               n_edge_shards=ns)
-        tot_flops = int(flops_pp.sum())
+        st = execution_stats(
+            program, cfg, pg, self.slot_capacity, steps, msgs, sweeps,
+            n_edge_shards=self._n_edge_shards(cfg),
+            assignment=self._resolve_assignment(program, cfg)
+            if eb == "auto" else None)
+        st.wall_time, st.compile_time = wall, compile_time
         # per-shard sweep time: the launch wall time apportioned by each
         # shard's flops share (shards run lock-step supersteps, so the
         # flops skew IS the critical-path skew the monitor cares about)
-        share = (flops_pp / tot_flops if tot_flops
+        flops = np.asarray(st.partition_flops, np.float64)
+        share = (flops / st.backend_flops if st.backend_flops
                  else np.full(pg.n_parts, 1.0 / max(pg.n_parts, 1)))
-        st = ExecutionStats(
-            supersteps=steps, total_messages=msgs,
-            processed_edges=int((sweeps64 * epp).sum()),
-            total_bytes=total_bytes, wall_time=wall,
-            compile_time=compile_time, edge_backend=eb,
-            backend_flops=tot_flops,
-            partition_edge_counts=[int(x) for x in epp],
-            partition_flops=[int(x) for x in flops_pp],
-            partition_sweep_time=[float(x) for x in wall * share])
-        if eb in ("pallas_tiles", "auto") and lay is not None:
-            spec = program.sweep_spec
-            st.tile_density = lay.density(pg, spec.semiring,
-                                          spec.edge_values, program.dtype)
-            dens = lay.partition_density(pg, spec.semiring,
-                                         spec.edge_values, program.dtype)
-            st.partition_tile_density = [float(x) for x in dens]
+        st.partition_sweep_time = [float(x) for x in wall * share]
+        if st.partition_tile_density:
+            dens = np.asarray(st.partition_tile_density)
             self.stats.tile_density_min = float(dens.min())
             self.stats.tile_density_mean = float(dens.mean())
             self.stats.tile_density_max = float(dens.max())
-        if asg is not None:
-            st.partition_edge_backends = list(asg)
         # surface the load gauges on SessionStats (EWMA for the measured
         # signal) and feed the monitor's measured-work input
         self.stats.partition_edge_counts = list(st.partition_edge_counts)

@@ -152,8 +152,7 @@ pg = partition_and_build(g, 4)
 mesh = make_mesh((4,), ("sub",))
 cfg = EngineConfig(subgraph_axes=("sub",), backend="shard_map")
 params = {"source": np.int32(0)}
-go = make_bsp_runner(SSSP(), mesh, cfg, pg.n_slots, params=params,
-                     params_as_input=True)
+go = make_bsp_runner(SSSP(), mesh, cfg, pg.n_slots)
 sgs = _device_subgraph(pg, shard_placement(mesh, cfg))
 with mesh:
     lowered = jax.jit(go).lower(sgs, params)
@@ -320,8 +319,7 @@ pg = partition_and_build(g, 4)
 mesh = make_mesh((4,), ("sub",))
 cfg = EngineConfig(subgraph_axes=("sub",), backend="shard_map")
 params = {"source": np.int32(0)}
-go = make_bsp_runner(SSSP(), mesh, cfg, pg.n_slots, params=params,
-                     params_as_input=True)
+go = make_bsp_runner(SSSP(), mesh, cfg, pg.n_slots)
 sgs = _device_subgraph(pg, shard_placement(mesh, cfg))
 with mesh:
     text = jax.jit(go).lower(sgs, params).as_text(debug_info=True)

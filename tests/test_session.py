@@ -119,24 +119,34 @@ def test_multi_algorithm_cache_entries(graph, session):
 # --------------------------------------------------------------------------- #
 # query semantics: parity with the low-level layer, warm starts
 # --------------------------------------------------------------------------- #
-def test_query_matches_run_sim(graph):
-    # exact policy: bit-identical [P, v_max, K] layout + byte-accounting
-    # parity with the low-level one-shot layer (buckets pad differently)
-    session = GraphSession.from_graph(graph, 5, "cdbh",
+BILL = ("supersteps", "total_messages", "total_bytes", "processed_edges",
+        "backend_flops", "tile_density", "edge_backend",
+        "partition_edge_backends", "partition_tile_density",
+        "partition_flops", "partition_edge_counts")
+
+
+@pytest.mark.parametrize("edge_backend", ["coo", "pallas_windows", "auto"])
+def test_query_matches_run_sim(graph, edge_backend):
+    # exact policy: bit-identical [P, v_max, K] layout + bill parity with
+    # the low-level one-shot layer (buckets pad differently); the one-shot
+    # graph takes the session's layout policy, so 'auto' picks alike
+    cfg = EngineConfig(edge_backend=edge_backend)
+    session = GraphSession.from_graph(graph, 5, "cdbh", cfg=cfg,
                                       shape_policy=ShapePolicy.exact())
     pg = partition_and_build(graph, 5, "cdbh")
+    pg.ensure_edge_layouts(shape_policy=ShapePolicy.exact())
     for prog, params in ((SSSP(), {"source": 7}), (ConnectedComponents(),
                                                    None)):
         r_sess, s_sess = session.query(prog, params, warm=False)
-        r_ref, s_ref = run_sim(prog, pg, params, EngineConfig())
+        r_ref, s_ref = run_sim(prog, pg, params, cfg)
         np.testing.assert_array_equal(np.asarray(r_sess), np.asarray(r_ref))
-        assert s_sess.supersteps == s_ref.supersteps
-        assert s_sess.total_messages == s_ref.total_messages
-        assert s_sess.total_bytes == s_ref.total_bytes
+        for field in BILL:
+            assert getattr(s_sess, field) == getattr(s_ref, field), field
+        assert s_sess.processed_edges > 0
     r_pr, _ = session.query(PageRank(tol=1e-9),
                             {"n_vertices": graph.n_vertices})
     r_ref, _ = run_sim(PageRank(tol=1e-9), pg,
-                       {"n_vertices": graph.n_vertices}, EngineConfig())
+                       {"n_vertices": graph.n_vertices}, cfg)
     np.testing.assert_array_equal(np.asarray(r_pr), np.asarray(r_ref))
 
 

@@ -146,6 +146,28 @@ n_loc = -(-(ns + 1) // 2)
 r_ss, s_ss = run_shard_map(cc, pg, mesh, None, cfg_ss)
 assert s_ss.total_bytes == s_ss.supersteps * (n_loc + 1) * itm * 4 * 2, \
     "sharded bytes"
+
+# ---- a shard_map GraphSession query bills what run_shard_map bills -------- #
+import dataclasses
+from repro.core import ShapePolicy
+from repro.session import GraphSession
+BILL = ("supersteps", "total_messages", "total_bytes", "processed_edges",
+        "backend_flops", "tile_density", "edge_backend",
+        "partition_edge_backends", "partition_tile_density",
+        "partition_flops", "partition_edge_counts")
+for prog, params, c in ((cc, None, cfg_shard), (cc, None, cfg_ss),
+                        (SSSP(), {"source": 0},
+                         dataclasses.replace(cfg_shard,
+                                             edge_backend="pallas_windows"))):
+    pgs = partition_and_build(g, 4, "cdbh")
+    sess = GraphSession(pgs, mesh=mesh, cfg=c,
+                        shape_policy=ShapePolicy.exact())
+    r_q, s_q = sess.query(prog, params, warm=False)
+    r_r, s_r = run_shard_map(prog, sess.pg, mesh, params, c)
+    assert (np.asarray(r_q) == np.asarray(r_r)).all(), "session != one-shot"
+    for field in BILL:
+        assert getattr(s_q, field) == getattr(s_r, field), \
+            (field, getattr(s_q, field), getattr(s_r, field))
 print("SHARD_BACKEND_OK")
 """
 

@@ -102,12 +102,10 @@ def test_segment_combine_compiles(one_chip, combiner, dtype, K):
     assert _has_kernel(compiled)
 
 
-@pytest.mark.parametrize("program,params,edge_backend", [
-    (SSSP(), {"source": 0}, "pallas_windows"),
-    (ConnectedComponents(), None, "pallas_tiles")])
-def test_sim_runner_compiles(one_chip, program, params, edge_backend):
-    """The whole served runner — superstep loop, SBS exchange, kernel
-    sweeps — as ``GraphSession`` builds it, over a Kronecker graph."""
+def _sim_runner(sharding, program, params, edge_backend):
+    """The whole served simulator runner — superstep loop, SBS exchange,
+    kernel sweeps — as ``GraphSession`` builds it, over a Kronecker graph,
+    compiled."""
     g = kronecker_graph(10, seed=0, weighted=True)
     sess = GraphSession.from_graph(g, n_parts=4, partitioner="cdbh")
     eb, cfg = normalize_edge_backend(
@@ -115,10 +113,48 @@ def test_sim_runner_compiles(one_chip, program, params, edge_backend):
     host_args = (sess.device_graph(cfg), sess._layout_arg(program, eb, cfg),
                  canonical_params(params),
                  sess._warm_arg(program, None, False, cfg))
-    specs = jax.tree.map(lambda x: _spec(x, one_chip), host_args)
+    specs = jax.tree.map(lambda x: _spec(x, sharding), host_args)
     runner = make_sim_runner(program, cfg, sess.slot_capacity,
                              warm_start=True)
-    compiled = jax.jit(runner).lower(*specs).compile()
+    return jax.jit(runner).lower(*specs).compile()
+
+
+def _shard_runner():
+    """The served shard_map runner on a described 2x2 v5e, one partition a
+    chip, as the four-chip cell runs it (``pallas_windows``, warm input,
+    traced params), compiled."""
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import make_bsp_runner
+    from repro.core.engine import shard_specs
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices), ("sub",))
+    g = kronecker_graph(10, seed=0, weighted=True)
+    sess = GraphSession.from_graph(g, n_parts=4, partitioner="cdbh")
+    program, params = SSSP(), canonical_params({"source": 0})
+    eb, cfg = normalize_edge_backend(program, EngineConfig(
+        backend="shard_map", edge_backend="pallas_windows"))
+    specs = shard_specs(cfg)
+    args = (sess.device_graph(), sess._layout_arg(program, eb, cfg), params,
+            sess._warm_arg(program, None, False, cfg))
+    places = (specs.graph, specs.windows,
+              jax.tree.map(lambda _: P(), params), specs.warm)
+    sds = jax.tree.map(lambda x, sp: _spec(x, NamedSharding(mesh, sp)),
+                       args, places, is_leaf=lambda x: isinstance(x, P))
+    runner = make_bsp_runner(program, mesh, cfg, sess.slot_capacity,
+                             warm_start=True)
+    return jax.jit(runner).lower(*sds).compile()
+
+
+@pytest.mark.parametrize("program,params,edge_backend", [
+    (SSSP(), {"source": 0}, "pallas_windows"),
+    (ConnectedComponents(), None, "pallas_tiles")])
+def test_sim_runner_compiles(one_chip, program, params, edge_backend):
+    """The whole served runner — superstep loop, SBS exchange, kernel
+    sweeps — as ``GraphSession`` builds it, over a Kronecker graph."""
+    compiled = _sim_runner(one_chip, program, params, edge_backend)
     assert _has_kernel(compiled)
     assert compiled.memory_analysis() is not None
 
@@ -178,29 +214,38 @@ def test_shard_runner_compiles_on_four_chips(one_chip):
     chip, as the four-chip cell runs it (``pallas_windows``, warm input,
     traced params): the per-partition local bound and the termination vote
     lower to one program per chip with its all-reduces."""
-    from jax.experimental import topologies
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from repro.core import make_bsp_runner
-    from repro.core.engine import shard_specs
-    topo = topologies.get_topology_desc(platform="tpu",
-                                        topology_name="v5e:2x2")
-    mesh = Mesh(np.array(topo.devices), ("sub",))
-    g = kronecker_graph(10, seed=0, weighted=True)
-    sess = GraphSession.from_graph(g, n_parts=4, partitioner="cdbh")
-    program, params = SSSP(), canonical_params({"source": 0})
-    eb, cfg = normalize_edge_backend(program, EngineConfig(
-        backend="shard_map", edge_backend="pallas_windows"))
-    specs = shard_specs(cfg)
-    args = (sess.device_graph(), sess._layout_arg(program, eb, cfg),
-            sess._warm_arg(program, None, False, cfg), params)
-    places = (specs.graph, specs.windows, specs.warm,
-              jax.tree.map(lambda _: P(), params))
-    sds = jax.tree.map(lambda x, sp: _spec(x, NamedSharding(mesh, sp)),
-                       args, places, is_leaf=lambda x: isinstance(x, P))
-    runner = make_bsp_runner(program, mesh, cfg, sess.slot_capacity,
-                             params=params, warm_start=True,
-                             params_as_input=True)
-    compiled = jax.jit(runner).lower(*sds).compile()
+    compiled = _shard_runner()
     assert _has_kernel(compiled)
     assert "all-reduce" in compiled.as_text()
+
+
+#: instructions of each kind in the compiled text of the two served runners
+#: above, as commit 38c2779 compiles them: the simulator's (SSSP on
+#: ``pallas_windows``, the one-chip cells' form) launches the windowed
+#: kernel in the first sweep and in the local loop, under a ``lax.map`` over
+#: the partitions; the shard_map runner's the same kernels per chip, with
+#: the boundary ``pmin`` and the vote's two psums as all-reduces
+OP_PATTERNS = {"kernel": r'custom_call_target="tpu_custom_call"',
+               "all-reduce": r" all-reduce(?:-start)?\(",
+               "scatter": r" scatter\(", "while": r" while\(",
+               "sort": r" sort\("}
+OP_COUNTS = {
+    "sim": {"kernel": 2, "all-reduce": 0, "scatter": 3, "while": 4,
+            "sort": 0},
+    "shard": {"kernel": 2, "all-reduce": 2, "scatter": 3, "while": 2,
+              "sort": 0}}
+
+
+@pytest.mark.parametrize("runner", ["sim", "shard"])
+def test_runner_op_counts(one_chip, runner):
+    """Both runners run the one superstep and loop of ``core/engine.py``:
+    their compiled programs hold as many kernel launches, all-reduces,
+    scatters and while loops as the runners of commit 38c2779, and no
+    sort."""
+    import re
+    compiled = _sim_runner(one_chip, SSSP(), {"source": 0},
+                           "pallas_windows") if runner == "sim" \
+        else _shard_runner()
+    text = compiled.as_text()
+    assert {k: len(re.findall(p, text))
+            for k, p in OP_PATTERNS.items()} == OP_COUNTS[runner]
